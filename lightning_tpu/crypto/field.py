@@ -138,14 +138,19 @@ def _carry_once(cols, out_limbs: int):
     hi = cols >> LIMB_BITS
     n = cols.shape[-1]
     total = max(out_limbs, n + 1)
-    lo = jnp.pad(lo, [(0, 0)] * (cols.ndim - 1) + [(0, total - n)])
-    hi = jnp.pad(hi, [(0, 0)] * (cols.ndim - 1) + [(1, total - n - 1)])
+    lo = _pad_last(lo, 0, total)
+    hi = _pad_last(hi, 1, total)
     return (lo + hi)[..., :out_limbs]
 
 
 def _pad_last(x, before: int, total: int):
-    pad = [(0, 0)] * (x.ndim - 1) + [(before, total - before - x.shape[-1])]
-    return jnp.pad(x, pad)
+    """Zero-pad the last axis to ``total`` with ``before`` leading zeros.
+    The lax primitive directly: jnp.pad wraps the same op in a nested
+    jit, and this helper is called ~28k times per EC-program trace —
+    the wrapper alone was ~40% of the trace time."""
+    pad = [(0, 0, 0)] * (x.ndim - 1) + [
+        (before, total - before - x.shape[-1], 0)]
+    return lax.pad(x, jnp.zeros((), x.dtype), pad)
 
 
 def _mul_cols(a, b, na: int, nb: int):

@@ -295,6 +295,11 @@ def main(argv=None) -> int:
     if args.mainnet:
         channels = max(1, int(MAINNET_CHANNELS * args.scale))
         nodes = max(2, int(MAINNET_NODES * args.scale))
+    if not args.no_sign:
+        # the sign and derive programs compile for minutes cold
+        from ..utils.jaxcfg import setup_cache
+
+        setup_cache()
     info = make_network_store(
         args.path, channels, nodes,
         updates_per_channel=args.updates_per_channel, seed=args.seed,

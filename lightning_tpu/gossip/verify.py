@@ -573,21 +573,6 @@ def _cached_mesh(n_devices: int):
     return pmesh.make_mesh(jax.devices()[:n_devices])
 
 
-def _mesh_compiler_opts() -> tuple:
-    """Compiler options for the sharded EC program.  Defaults to cheap
-    LLVM options on the CPU backend (a virtual CPU mesh is a sharding
-    rig, not a perf rig; full opt quadruples its multi-minute compile)
-    and full optimization elsewhere.  LIGHTNING_TPU_MESH_COMPILE=
-    cheap|full overrides."""
-    from ..utils.jaxcfg import CHEAP_COMPILE_OPTS
-
-    mode = _os.environ.get("LIGHTNING_TPU_MESH_COMPILE", "")
-    if not mode:
-        mode = "cheap" if jax.default_backend() == "cpu" else "full"
-    return tuple(sorted(CHEAP_COMPILE_OPTS.items())) if mode == "cheap" \
-        else ()
-
-
 def _mesh_device_fn(bucket: int, count_metrics: bool = True):
     """Multi-device path: hash + local z gather stay single-device jit
     programs, the EC verify — ~99% of the device FLOPs — runs batch-
@@ -607,7 +592,7 @@ def _mesh_device_fn(bucket: int, count_metrics: bool = True):
     if n < 2:
         return None
     mesh = _cached_mesh(n)
-    vfn = pmesh.sharded_verify_fn(mesh, _mesh_compiler_opts())
+    vfn = pmesh.sharded_verify_fn(mesh)
 
     def mesh_dispatch(pb: _PreparedBucket):
         _note_shape("hash", (bucket, pb.mb))

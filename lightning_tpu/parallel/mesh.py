@@ -23,13 +23,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-# jax < 0.6 ships shard_map under experimental only; the top-level alias
-# this module was written against does not exist on the pinned 0.4.x.
-# Public on purpose: __graft_entry__.py shares this compat shim.
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map
+# public on purpose: __graft_entry__.py wraps its own programs with it
+shard_map = jax.shard_map
 
 BATCH_AXIS = "batch"
 
@@ -90,7 +85,7 @@ def shard_batch(mesh: Mesh, *arrays):
 
 
 @functools.lru_cache(maxsize=16)
-def sharded_verify_fn(mesh: Mesh, compiler_options: tuple = ()):
+def sharded_verify_fn(mesh: Mesh):
     """jit-compiled ECDSA verify step sharded over the mesh's batch axis.
 
     Inputs: z, r, s, qx (B, NLIMBS) uint32 limb planes; parity (B,)
@@ -118,4 +113,4 @@ def sharded_verify_fn(mesh: Mesh, compiler_options: tuple = ()):
     sm = shard_map(step, mesh=mesh,
                    in_specs=(P(BATCH_AXIS),) * 5,
                    out_specs=(P(BATCH_AXIS), P()))
-    return jax.jit(sm, compiler_options=dict(compiler_options) or None)
+    return jax.jit(sm)

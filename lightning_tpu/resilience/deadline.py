@@ -1,7 +1,7 @@
 """Dispatch deadlines and supervised-loop restart backoff.
 
 Two failure shapes the breakers can't see: a dispatch that HANGS (a
-wedged device runtime, a dead TPU tunnel — the call never returns, so
+wedged device runtime — the call never returns, so
 there is no exception to count) and a flush loop that DIES (an escaped
 exception kills the asyncio task; every later submit queues forever).
 This module covers both:

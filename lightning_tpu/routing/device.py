@@ -67,7 +67,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
+from jax import enable_x64
 
 from ..obs import attribution as _attr
 from ..obs import families as _families
@@ -148,15 +148,24 @@ def _make_single(n_nodes: int, max_hops: int):
     def single(edge_src, edge_dst, base, ppm, cd, hmin, hmax,
                edge_ok, src, dst, amount, final_cltv, riskfactor):
         E = edge_src.shape[0]
-        dist0 = jnp.full((n_nodes,), INF_COST, jnp.int64).at[dst].set(0)
+        # labels start at the destination (a select; the same values
+        # as zeros.at[dst].set(x) without a per-query scatter)
+        at_dst = jnp.arange(n_nodes, dtype=jnp.int32) == dst
+        dist0 = jnp.where(at_dst, jnp.int64(0), jnp.int64(INF_COST))
         if dist0.dtype != jnp.int64:
             raise RuntimeError(
                 "route kernel traced outside an x64 scope — msat math "
                 "would silently truncate to int32")
-        amt0 = jnp.zeros((n_nodes,), jnp.int64).at[dst].set(amount)
-        dly0 = jnp.zeros((n_nodes,), jnp.int64).at[dst].set(final_cltv)
+        amt0 = jnp.where(at_dst, amount, jnp.int64(0))
+        dly0 = jnp.where(at_dst, final_cltv, jnp.int64(0))
         via0 = jnp.full((n_nodes,), -1, jnp.int32)
-        eidx = jnp.arange(E, dtype=jnp.int32)
+        # edge indices ride the tie-break min as int64, like the costs:
+        # on the TPU (PR 23, v5e) this program's int32 scatter-min
+        # returned indices that were not the winners', so amt/dly/via
+        # followed a padding edge, every later sweep priced a zero
+        # amount and no reconstruction agreed with its label.  Label for
+        # label the int64 form equals the CPU backend there.
+        eidx = jnp.arange(E, dtype=jnp.int64)
         # per-edge safe-amount ceiling: both int64 products stay < 2^61
         cdr = cd * riskfactor
         thr = jnp.minimum(OVF_LIMIT // jnp.maximum(ppm, 1),
@@ -183,7 +192,7 @@ def _make_single(n_nodes: int, max_hops: int):
             e_cand = jnp.where(ok & (cand == best[edge_src]), eidx, E)
             best_e = jax.ops.segment_min(e_cand, edge_src,
                                          num_segments=n_nodes)
-            e_star = jnp.minimum(best_e, E - 1)
+            e_star = jnp.minimum(best_e, E - 1).astype(jnp.int32)
             v_star = edge_dst[e_star]
             dist = jnp.where(improved, best, dist)
             amt = jnp.where(improved, amt[v_star] + fee[e_star], amt)

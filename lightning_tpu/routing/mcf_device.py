@@ -74,7 +74,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
+from jax import enable_x64
 
 from ..obs import attribution as _attr
 from ..obs import families as _families

@@ -7,6 +7,8 @@ lazily with the system toolchain so the package stays pip-less.
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
 import subprocess
 import threading
@@ -15,19 +17,52 @@ import numpy as np
 
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _SRC_DIR = os.path.join(_ROOT, "native")
-_LIB_PATH = os.path.join(_SRC_DIR, "_lightning_native.so")
 _SOURCES = ["crc32c.c", "gossip_native.c"]
 _lock = threading.Lock()
 _lib = None
 
 
-def _build() -> str:
-    srcs = [os.path.join(_SRC_DIR, s) for s in _SOURCES]
-    newest_src = max(os.path.getmtime(s) for s in srcs)
-    if not os.path.exists(_LIB_PATH) or os.path.getmtime(_LIB_PATH) < newest_src:
-        cmd = ["cc", "-O3", "-shared", "-fPIC", "-o", _LIB_PATH, *srcs]
-        subprocess.run(cmd, check=True, capture_output=True)
-    return _LIB_PATH
+class NativeBuildError(RuntimeError):
+    """The C helpers did not compile; carries the compiler's stderr."""
+
+
+def _build(src_dir: str = _SRC_DIR, out_dir: str | None = None) -> str:
+    """Compile the helpers unless a library built from exactly these
+    sources is already there.  The library's name records the hash of
+    its sources (``_lightning_native-<hash>.so``, git-ignored), so a
+    fresh checkout builds on first use and an edited ``.c`` rebuilds —
+    mtimes say nothing after a checkout."""
+    out_dir = out_dir or src_dir
+    srcs = [os.path.join(src_dir, s) for s in _SOURCES]
+    h = hashlib.sha256()
+    for s in srcs:
+        with open(s, "rb") as f:
+            h.update(f.read())
+    lib_path = os.path.join(out_dir,
+                            f"_lightning_native-{h.hexdigest()[:16]}.so")
+    if os.path.exists(lib_path):
+        return lib_path
+    # build beside the target and rename: concurrent first users
+    # (xdist workers, subdaemons) never load a half-written file
+    tmp = f"{lib_path}.{os.getpid()}.tmp"
+    cmd = ["cc", "-O3", "-shared", "-fPIC", "-o", tmp, *srcs]
+    try:
+        proc = subprocess.run(cmd, capture_output=True, text=True)
+    except OSError as e:
+        raise NativeBuildError(f"cannot run {cmd[0]!r}: {e}") from e
+    if proc.returncode != 0:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
+        raise NativeBuildError(
+            f"{' '.join(cmd)} exited {proc.returncode}:\n{proc.stderr}")
+    os.replace(tmp, lib_path)
+    for old in glob.glob(os.path.join(out_dir, "_lightning_native*.so")):
+        if old != lib_path:
+            try:
+                os.unlink(old)
+            except OSError:
+                pass
+    return lib_path
 
 
 def get_lib() -> ctypes.CDLL:

@@ -5,7 +5,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
+from jax import enable_x64
 
 
 def fee_kernel(amounts, rates):

@@ -3,7 +3,7 @@ msat/int64 staging crosses jnp.asarray inside enable_x64; host numpy
 is always 64-bit and exempt."""
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
+from jax import enable_x64
 
 
 def stage_query(amount_msat, fee_base, n):

@@ -101,10 +101,12 @@ def test_verify_kernel_with_glv_impl():
     s = F.from_bytes_be(sigs[:, 32:])
     qx = F.from_bytes_be(pubs[:, 1:])
     par = (pubs[:, 0] & 1).astype(np.uint32)
-    base = np.asarray(jax.jit(S.ecdsa_verify_kernel)(z, r, s, qx, par))
-    got = np.asarray(jax.jit(
-        lambda *a: S.ecdsa_verify_kernel(*a, dual_mul_impl=glv.dual_mul_glv)
-    )(z, r, s, qx, par))
+    # the production wrappers of the two engines: the same programs a
+    # private jax.jit would build, compiled once per process instead of
+    # once more here (minutes each on the CPU)
+    assert S.resolve_dual_mul("glv") is glv.dual_mul_glv
+    base = np.asarray(S._jit_verify("xla", "xla")(z, r, s, qx, par))
+    got = np.asarray(S._jit_verify("glv", "xla")(z, r, s, qx, par))
     expect = np.ones(B, bool)
     expect[3] = expect[5] = False
     assert np.array_equal(base, expect)

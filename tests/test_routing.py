@@ -90,7 +90,7 @@ def test_unknown_node_raises(tmp_path):
 
 
 def test_25k_channel_routing_performance(tmp_path):
-    """SURVEY §7.2 / VERDICT task 6 target: route across the 25k-channel
+    """SURVEY §7.2 target: route across the 25k-channel
     synthetic network, warm, well under a second (goal <100ms)."""
     g = _net(tmp_path, 25_000, 3_000)
     assert g.n_channels >= 25_000

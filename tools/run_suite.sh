@@ -1,31 +1,22 @@
 #!/bin/bash
-# Full test suite in TWO pytest slices, with crash-retry.
+# Full test suite in TWO pytest slices, with crash-retry, plus the
+# lint / selfcheck / fault-matrix passes.  (The driver's tier-1 gate is
+# the plain pytest command in /root/TESTS_LAST_RUN.json; this runner is
+# the fuller local pass.)
 #
-# jax 0.9's persistent compilation cache sometimes dies INSIDE
-# XLA:CPU executable serialize/deserialize (SIGABRT on write, SIGSEGV
-# on read) while storing one of this repo's large EC programs — only
-# in long-running processes (every file passes in a fresh process, and
-# a minimal compile+write of the same program succeeds).  Round 3's
-# review already ran the suite in two slices for related reasons.
+# The suite runs WITHOUT jax's persistent compile cache
+# (tests/conftest.py pins LIGHTNING_TPU_JAX_CACHE_MODE=off): on jax
+# 0.9.0 / XLA:CPU a long-running test process can die with SIGSEGV
+# deserializing a cache entry (re-established in PR 23: one of six
+# xdist workers sharing a read-write cache died inside
+# compilation_cache.get_executable_and_time; the same read succeeds in
+# a fresh process).  Every process therefore cold-compiles the EC
+# programs it touches — ~30 s of tracing and ~2 min of XLA:CPU compile
+# each — which is what the slice and per-file timeouts below allow for.
 #
-# The mitigation exploits cache monotonicity: every entry written
-# BEFORE a crash persists, so rerunning a crashed slice starts warmer
-# and ratchets past the crash point; a fully-warm run performs no
-# writes at all and cannot hit the bug.  Test FAILURES (rc 1) are
-# never retried — only crash exits (≥128) and slice timeouts (124,
-# which a cold cache can cause legitimately).
-#
-# Since ISSUE 2 the conftest forces the cache READ-ONLY under pytest
-# (LIGHTNING_TPU_JAX_CACHE_MODE=ro): the crash lived in the
-# serialize/deserialize write path, and a run that never writes
-# cannot corrupt entries for concurrent readers either.  New program
-# shapes must be warmed out-of-band (doc/replay_pipeline.md §testing);
-# a shape missing from the cache recompiles in-process every slice
-# attempt instead of ratcheting — keep warmup() coverage complete.
-#
-# NOTE: do NOT run anything else that touches the jax compilation
-# cache concurrently — concurrent writers corrupt entries (readers
-# then segfault).  Side processes: LIGHTNING_TPU_JAX_CACHE=/tmp/...
+# Crash exits (>=128) and slice timeouts (124) are retried once, then
+# the slice finishes file-per-process; test FAILURES (rc 1) are never
+# retried.
 set -u
 cd "$(dirname "$0")/.."
 export JAX_PLATFORMS=cpu
@@ -36,29 +27,21 @@ run_slice() {
   local attempt rc f
   for attempt in 1 2; do
     # slice-level hang guard: a test blocking on a silent daemon must
-    # never stall the suite for hours; a timeout (rc 124) retries like
-    # a crash because a cold cache can legitimately blow the budget
+    # never stall the suite for hours
     timeout 3600 python -m pytest "$@" -x -q && return 0
     rc=$?
-    # 124 (slice timeout) retries like a crash: a COLD cache can
-    # legitimately blow the budget, and entries written before the
-    # timeout persist, so the retry runs warmer; a true hang just
-    # falls through to the per-file loop with its own timeouts
     if [ "$rc" -ne 124 ] && [ "$rc" -lt 128 ]; then
       echo "slice $name failed rc=$rc (test failure, not retried)"
       return "$rc"
     fi
     echo "slice $name crashed/timed out rc=$rc (attempt $attempt) —" \
-         "retrying with the now-warmer cache"
+         "retrying"
   done
-  # an executable whose WRITE crashes re-crashes on every whole-slice
-  # retry; every file is known to pass in a fresh process, so finish
-  # the slice file-per-process (slower: ~20 s jax startup per file).
+  # every file is known to pass in a fresh process, so finish the
+  # slice file-per-process (slower: every file pays its own compiles).
   # Per-file timeout: one hanging test (e.g. a readline on a silent
   # daemon) must never stall the whole suite for hours.  3000 s: the
-  # pallas interpret-mode file legitimately needs >900 s with three
-  # fused engines (measured round 5) and blew an 1800 s budget cold
-  # once the fourth (pallas_fbj) joined the oracle matrix.
+  # pallas interpret-mode file needs >900 s with four fused engines.
   echo "slice $name: falling back to file-per-process"
   for f in "$@"; do
     timeout 3000 python -m pytest "$f" -x -q || { rc=$?;
