@@ -60,6 +60,9 @@ import traceback
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 BASELINE_CPU_OPS = 50_000.0
+# platform labels of CPU rounds: "cpu" is what jax.default_backend()
+# says; "cpu-fallback" is how BENCH_HISTORY.jsonl's older records say it
+CPU_LABELS = ("cpu", "cpu-fallback")
 METRIC = "gossip_store_replay_sig_verify_throughput"
 UNIT = "sig_verifies_per_sec"
 # `bench.py route` workload (PR-3): batched device pathfinding vs the
@@ -411,11 +414,6 @@ def record_tpu_measurement(rec: dict) -> None:
         os.replace(tmp, LAST_TPU_PATH)
     except Exception:
         pass
-
-
-# platform labels of CPU rounds: "cpu" is what jax.default_backend()
-# says; "cpu-fallback" is how BENCH_HISTORY.jsonl's older records say it
-CPU_LABELS = ("cpu", "cpu-fallback")
 
 
 def acquire_backend() -> str:

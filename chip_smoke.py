@@ -38,13 +38,13 @@ from __future__ import annotations
 import json
 import os
 import random
+import shlex
 import shutil
 import signal
 import socket
 import subprocess
 import sys
 import tempfile
-import threading
 import time
 from concurrent.futures import ThreadPoolExecutor
 
@@ -54,9 +54,11 @@ PLATFORM = "tpu"
 SCALE = 0.1                 # of gossip/synth.py's --mainnet preset
 SEED = 7
 N_GETROUTE = 320            # ≥ 256, in waves that fill ROUTE_BATCH (64)
-N_GETROUTES = 16            # two MCF_BATCH (8) dispatches
+N_GETROUTES = 8             # one full MCF_BATCH (8) dispatch: ~135 s on a
+                            # v5e at this graph; two did not fit a cold run
 WAVE = 128                  # concurrent clients: below ROUTE_HIGH_WM (256)
-BUDGET_S = 1150             # the whole script, compilation included
+BUDGET_S = 1175             # the whole script, compilation included
+FINAL_CLTV = 18             # getroute's `cltv` / getroutes' `final_cltv` default
 # a flush that holds too few queries to be worth a dispatch is solved on
 # the host by design (HOST_ROUTE_MAX / MCF_HOST_MAX); every other
 # fallback reason means the device path failed
@@ -340,8 +342,6 @@ class Daemon:
 
 def _parse_kv(line: str) -> dict:
     """'backend: platform=tpu kind='TPU v5 lite' count=1' → dict."""
-    import shlex
-
     return dict(tok.split("=", 1) for tok in shlex.split(line)
                 if "=" in tok)
 
@@ -398,6 +398,8 @@ def wait_warm(d: Daemon) -> dict:
 # -- phase 3: queries against the oracle ------------------------------------
 
 def drive_getroute(d: Daemon, g, routes) -> None:
+    from lightning_tpu.gossip.gossmap import scid_parse
+
     t0 = time.monotonic()
     answers = []
     for i in range(0, len(routes), WAVE):
@@ -409,13 +411,11 @@ def drive_getroute(d: Daemon, g, routes) -> None:
     for (src, dst, amt, want), resp in zip(routes, answers):
         if "error" in resp:
             raise SmokeFailed("getroute", json.dumps(resp["error"])[:500])
-        from lightning_tpu.gossip.gossmap import scid_parse
-
         hops = [(bytes.fromhex(h["id"]), scid_parse(h["channel"]),
                  h["direction"], h["amount_msat"], h["delay"])
                 for h in resp["result"]["route"]]
         try:
-            _check_path(g, src, dst, amt, 18, hops)
+            _check_path(g, src, dst, amt, FINAL_CLTV, hops)
         except (ValueError, KeyError) as e:
             raise SmokeFailed("getroute", f"{src.hex()[:8]}→"
                               f"{dst.hex()[:8]} {amt}: {e}")
@@ -544,19 +544,14 @@ def run(workdir: str) -> dict:
 
 def boot_and_drive(d: Daemon, g, synth_info: dict) -> dict:
     # the host answers are computed while the daemon replays
-    oracle: dict = {}
-    th = threading.Thread(
-        target=lambda: oracle.update(zip(("routes", "flows"), pick_queries(
-            g, N_GETROUTE, N_GETROUTES))), daemon=True)
+    pool = ThreadPoolExecutor(max_workers=1)
+    oracle = pool.submit(pick_queries, g, N_GETROUTE, N_GETROUTES)
     try:
-        th.start()
         device = boot(d, synth_info)
         warm = wait_warm(d)
-        th.join(max(1.0, _left()))
-        if "flows" not in oracle:
-            raise SmokeFailed("oracle", "host solvers did not finish")
-        drive_getroute(d, g, oracle["routes"])
-        drive_getroutes(d, g, oracle["flows"])
+        routes, flows = oracle.result(max(1.0, _left()))
+        drive_getroute(d, g, routes)
+        drive_getroutes(d, g, flows)
         check_metrics(d, synth_info, warm)
         rpc(d.rpc_path, "stop")
         try:
@@ -570,6 +565,7 @@ def boot_and_drive(d: Daemon, g, synth_info: dict) -> dict:
               seconds_total=round(time.monotonic() - _T0, 1))
     finally:
         d.kill()
+        pool.shutdown(wait=False, cancel_futures=True)
     return device
 
 

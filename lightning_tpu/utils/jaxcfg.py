@@ -36,10 +36,8 @@ def setup_cache() -> None:
       ro           — read-only: warm programs still load, nothing new is
                      serialized (child daemons of a harness that must
                      not grow the cache they share)
-      off          — no persistent cache at all: every process
-                     cold-compiles.  The test suite runs this way
-                     (tests/conftest.py): deserializing an XLA:CPU
-                     entry has crashed long-running test workers.
+      off          — no persistent cache at all (cold compiles every
+                     process; only for debugging the cache itself).
     """
     mode = os.environ.get("LIGHTNING_TPU_JAX_CACHE_MODE", "rw")
     if mode == "off":

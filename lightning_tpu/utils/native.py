@@ -26,19 +26,18 @@ class NativeBuildError(RuntimeError):
     """The C helpers did not compile; carries the compiler's stderr."""
 
 
-def _build(src_dir: str = _SRC_DIR, out_dir: str | None = None) -> str:
+def _build(src_dir: str = _SRC_DIR) -> str:
     """Compile the helpers unless a library built from exactly these
     sources is already there.  The library's name records the hash of
     its sources (``_lightning_native-<hash>.so``, git-ignored), so a
     fresh checkout builds on first use and an edited ``.c`` rebuilds —
     mtimes say nothing after a checkout."""
-    out_dir = out_dir or src_dir
     srcs = [os.path.join(src_dir, s) for s in _SOURCES]
     h = hashlib.sha256()
     for s in srcs:
         with open(s, "rb") as f:
             h.update(f.read())
-    lib_path = os.path.join(out_dir,
+    lib_path = os.path.join(src_dir,
                             f"_lightning_native-{h.hexdigest()[:16]}.so")
     if os.path.exists(lib_path):
         return lib_path
@@ -56,7 +55,7 @@ def _build(src_dir: str = _SRC_DIR, out_dir: str | None = None) -> str:
         raise NativeBuildError(
             f"{' '.join(cmd)} exited {proc.returncode}:\n{proc.stderr}")
     os.replace(tmp, lib_path)
-    for old in glob.glob(os.path.join(out_dir, "_lightning_native*.so")):
+    for old in glob.glob(os.path.join(src_dir, "_lightning_native*.so")):
         if old != lib_path:
             try:
                 os.unlink(old)

@@ -16,15 +16,12 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 os.environ.setdefault("LIGHTNING_TPU_VERIFY_BUCKET", "8")
 os.environ.setdefault("LIGHTNING_TPU_SIGN_BUCKET", "8")
 
-# The suite runs WITHOUT the persistent compile cache.  Re-established
-# on jax 0.9.0 / XLA:CPU (PR 23): with six xdist workers sharing a
-# read-write cache, a worker died with SIGSEGV inside
-# compilation_cache.get_executable_and_time (deserializing a complete,
-# untruncated entry another worker had written) — once in one run of
-# ~50 cache hits; the same read succeeds in a fresh process.  A dead
-# worker costs more than the compiles the cache saves, so no test
-# process reads or writes it (children inherit the setting).
-os.environ.setdefault("LIGHTNING_TPU_JAX_CACHE_MODE", "off")
+# The suite reads AND writes the persistent compile cache
+# (jaxcfg.setup_cache: $JAX_COMPILATION_CACHE_DIR, else
+# <checkout>/.jax_cache).  A cold EC-program compile is ~2 minutes of
+# XLA:CPU time; the xdist workers share the directory, so a program one
+# worker compiled is a load for every worker that needs it later.  (A
+# reader that meets a half-written entry warns and compiles, jax 0.9.0.)
 
 # The virtual 8-device mesh exists to exercise sharding CORRECTNESS,
 # not to route every little verify through shard_map: the suite pins
@@ -37,6 +34,7 @@ from lightning_tpu.utils.jaxcfg import force_cpu, setup_cache
 force_cpu(n_devices=8)
 
 import jax
+import pytest
 
 assert jax.default_backend() == "cpu", "tests must run on the CPU mesh"
 assert jax.device_count() >= 8, "expected virtual 8-device CPU mesh"
@@ -51,6 +49,9 @@ setup_cache()
 # run, one worker grinding through a compile while five sat idle.
 # Handing them out first lets the short files fill the gaps.
 _LONGEST_FIRST = (
+    # (seconds-long, but asserts on the process-wide flight ring: it
+    # must be a worker's first file, as alphabetical order made it)
+    "test_attribution.py",
     "test_pallas_verify.py", "test_obs.py", "test_glv.py",
     "test_secp256k1.py", "test_zz_mesh_parity.py", "test_seeker.py",
     "test_gossip_origination.py", "test_pallas_engines.py",
@@ -60,6 +61,29 @@ _LONGEST_FIRST = (
     "test_fault_matrix.py", "test_mpp.py", "test_gossipd.py",
     "test_channeld.py", "test_field.py",
 )
+
+
+# XLA:CPU maps every compiled kernel's code, rodata and data separately:
+# one EC program is 3,000-8,000 memory maps, a worker that has compiled
+# a dozen of them nears the kernel's vm.max_map_count (65,530), and the
+# next compile or cache load then dies with SIGABRT / SIGSEGV — the
+# "crash in long-running processes" this suite has always had (PR 23
+# found a dying worker at 60,501 maps).  Past this many maps a module's
+# teardown drops jax's executable caches; programs needed again are
+# reloaded from the persistent cache.
+_MAPS_HIGH_WATER = 40_000
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _stay_under_max_map_count():
+    yield
+    try:
+        with open("/proc/self/maps") as f:
+            n_maps = sum(1 for _ in f)
+    except OSError:          # no procfs: nothing to go by
+        return
+    if n_maps > _MAPS_HIGH_WATER:
+        jax.clear_caches()
 
 
 def pytest_configure(config):

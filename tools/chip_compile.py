@@ -50,14 +50,16 @@ def _verify_args(bucket, mb, sh):
             _sds((bucket, 64), jnp.uint8, sh), _sds((bucket, 33), jnp.uint8, sh))
 
 
-def _fused(mb, impl="glv", prep="xla", bucket=None):
+def _fused(mb):
     def build(sh):
+        from lightning_tpu.crypto import secp256k1 as S
         from lightning_tpu.gossip import verify
 
-        b = bucket or verify.DEFAULT_BUCKET
-        # donate=True: the program the daemon builds off-CPU
-        return (verify._jit_fused_resolved(impl, prep, True),
-                _verify_args(b, mb, sh))
+        # the daemon's default engines, donate=True: the program it
+        # builds off-CPU
+        return (verify._jit_fused_resolved(
+            *S._resolve_engine_names(None, None), True),
+            _verify_args(verify.DEFAULT_BUCKET, mb, sh))
     return build
 
 

@@ -4,15 +4,14 @@
 # the plain pytest command in /root/TESTS_LAST_RUN.json; this runner is
 # the fuller local pass.)
 #
-# The suite runs WITHOUT jax's persistent compile cache
-# (tests/conftest.py pins LIGHTNING_TPU_JAX_CACHE_MODE=off): on jax
-# 0.9.0 / XLA:CPU a long-running test process can die with SIGSEGV
-# deserializing a cache entry (re-established in PR 23: one of six
-# xdist workers sharing a read-write cache died inside
-# compilation_cache.get_executable_and_time; the same read succeeds in
-# a fresh process).  Every process therefore cold-compiles the EC
-# programs it touches — ~30 s of tracing and ~2 min of XLA:CPU compile
-# each — which is what the slice and per-file timeouts below allow for.
+# XLA:CPU maps every compiled kernel separately: one EC program is
+# 3,000-8,000 memory maps, and a long-running test process that has
+# compiled a dozen of them reaches the kernel's vm.max_map_count
+# (65,530) — the next compile or cache load then dies with SIGABRT or
+# SIGSEGV.  tests/conftest.py drops jax's executable caches at a
+# module's teardown once a process is past 40,000 maps (PR 23), and the
+# suite shares one read-write persistent cache (.jax_cache/), so what
+# is needed again is a load.  The retry below stays as a belt.
 #
 # Crash exits (>=128) and slice timeouts (124) are retried once, then
 # the slice finishes file-per-process; test FAILURES (rc 1) are never
