@@ -18,8 +18,7 @@ import ast
 from ..core import FileContext, Pass
 
 # call sites whose FIRST argument names a span/topic/family
-NAMED_SITES = {"span", "device_span", "annotation", "emit",
-               "dispatch", "begin"}
+NAMED_SITES = {"span", "emit", "dispatch", "begin"}
 # modules the attr must hang off for NAMED_SITES to apply (so a
 # dataclass's own `begin()` or an unrelated `emit` is not flagged)
 NAMED_BASES = {"trace", "_trace", "events", "_ev", "_nev", "flight",

@@ -370,28 +370,34 @@ def ecdsa_verify_kernel(z, r, s, qx, q_parity, dual_mul_impl=None,
     (qx, parity, s) -> (qy, on_curve, w); default = XLA decompress +
     Montgomery inv_batch.
     """
-    r_ok = F.lt_const(r, N_INT) & _nonzero(r)
-    # libsecp256k1's secp256k1_ecdsa_verify (bitcoin/signature.c:174 path)
-    # rejects high-S outright: accept only s ≤ (n-1)/2
-    s_ok = F.lt_const(s, (N_INT + 1) // 2) & _nonzero(s)
-    q_ok = F.lt_const(qx, P_INT)
-    if prep_impl is not None:
-        qy, on_curve, w = prep_impl(qx, q_parity, s)
-    else:
-        qy, on_curve = decompress(qx, q_parity)
-        w = F.inv_batch(FN, s)
-    u1 = F.normalize(FN, F.mul(FN, z, w))
-    u2 = F.normalize(FN, F.mul(FN, r, w))
-    R = (dual_mul_impl or dual_mul)(u1, u2, qx, qy)
-    Rx, _, Rz = R
-    not_inf = ~F.is_zero(FP, Rz)
-    # projective x(R) ≡ r (mod n) check without inversion:
-    # x(R) = Rx/Rz; candidates r' ∈ {r, r+n} with r' < p
-    chk1 = F.eq(FP, Rx, _mul(r, Rz))
-    r_plus_n = _add(r, F.from_const(N_INT, r.shape[:-1]))
-    small_r = F.lt_const(r, P_INT - N_INT)
-    chk2 = small_r & F.eq(FP, Rx, _mul(r_plus_n, Rz))
-    return r_ok & s_ok & q_ok & on_curve & not_inf & (chk1 | chk2)
+    # the phases carry jax.named_scope names (doc/tracing.md): they ride
+    # the ops' metadata into a device trace and survive a refactor that
+    # renumbers `%while.18`
+    with jax.named_scope("verify_scalar_prep"):
+        r_ok = F.lt_const(r, N_INT) & _nonzero(r)
+        # libsecp256k1's secp256k1_ecdsa_verify (bitcoin/signature.c:174
+        # path) rejects high-S outright: accept only s ≤ (n-1)/2
+        s_ok = F.lt_const(s, (N_INT + 1) // 2) & _nonzero(s)
+        q_ok = F.lt_const(qx, P_INT)
+        if prep_impl is not None:
+            qy, on_curve, w = prep_impl(qx, q_parity, s)
+        else:
+            qy, on_curve = decompress(qx, q_parity)
+            w = F.inv_batch(FN, s)
+        u1 = F.normalize(FN, F.mul(FN, z, w))
+        u2 = F.normalize(FN, F.mul(FN, r, w))
+    with jax.named_scope("verify_dual_mul"):
+        R = (dual_mul_impl or dual_mul)(u1, u2, qx, qy)
+    with jax.named_scope("verify_compare"):
+        Rx, _, Rz = R
+        not_inf = ~F.is_zero(FP, Rz)
+        # projective x(R) ≡ r (mod n) check without inversion:
+        # x(R) = Rx/Rz; candidates r' ∈ {r, r+n} with r' < p
+        chk1 = F.eq(FP, Rx, _mul(r, Rz))
+        r_plus_n = _add(r, F.from_const(N_INT, r.shape[:-1]))
+        small_r = F.lt_const(r, P_INT - N_INT)
+        chk2 = small_r & F.eq(FP, Rx, _mul(r_plus_n, Rz))
+        return r_ok & s_ok & q_ok & on_curve & not_inf & (chk1 | chk2)
 
 
 GRIND_CANDIDATES = 4

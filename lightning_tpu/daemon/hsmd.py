@@ -79,9 +79,7 @@ def _sign_batch_resilient(op: str, msg_hashes: np.ndarray,
                           breaker_state=brk.state) as rec:
         with trace.span("sign/dispatch", corr=corr, op=op,
                         dispatch_id=rec["dispatch_id"]):
-            with trace.annotation("sign/dispatch"):
-                return _sign_dispatch(op, msg_hashes, seckeys, brk, rec,
-                                      B)
+            return _sign_dispatch(op, msg_hashes, seckeys, brk, rec, B)
 
 
 def _sign_dispatch(op: str, msg_hashes: np.ndarray, seckeys: list[int],

@@ -34,7 +34,7 @@ import re
 import time
 
 from ..obs import families as _f
-from ..utils import events
+from ..utils import events, trace
 
 log = logging.getLogger("lightning_tpu.daemon.recovery")
 
@@ -268,7 +268,19 @@ def boot_recover(data_dir: str, *, store_path: str | None = None,
 
     LIGHTNING_TPU_RECOVERY_DISABLE=1 skips everything except the marker
     write; LIGHTNING_TPU_RECOVERY_VERIFY=0 skips the store verify
-    replay on crash boots (`verify=` overrides the knob)."""
+    replay on crash boots (`verify=` overrides the knob).
+
+    The whole phase is the span `recovery/boot`; the store recovery
+    inside it is `recovery/store`, and the verify replay brings its own
+    (`gossip/extract`, `replay/sort`, `replay/stream`,
+    `replay/readback`: doc/tracing.md)."""
+    with trace.span("recovery/boot"):
+        return _boot_recover(data_dir, store_path=store_path, db=db,
+                             replica=replica, verify=verify, now=now)
+
+
+def _boot_recover(data_dir: str, *, store_path: str | None, db, replica,
+                  verify: bool | None, now: float | None) -> dict:
     t0 = time.perf_counter()
     state = read_marker(data_dir)
     report: dict = {"state": state, "incidents": [], "store": None,
@@ -306,8 +318,9 @@ def boot_recover(data_dir: str, *, store_path: str | None = None,
         # shutdown fsynced everything it appended, and the native scan
         # (always run, via load_store inside) still catches torn files
         check_sigs = host_sig_checker() if crashed else None
-        idx, srep = gstore.recover_store(
-            store_path, check_crc=crashed, check_sigs=check_sigs)
+        with trace.span("recovery/store"):
+            idx, srep = gstore.recover_store(
+                store_path, check_crc=crashed, check_sigs=check_sigs)
         report["store"] = {
             "bootstrapped": srep.bootstrapped, "records": srep.records,
             "size": srep.size, "truncated_bytes": srep.truncated_bytes,

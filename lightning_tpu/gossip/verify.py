@@ -95,7 +95,10 @@ _M_COMPILE = obs.counter(
 # in serial mode stall == prep by definition), "dispatch" is upload +
 # program enqueue, "readback" is the single end-of-replay block on the
 # device booleans.  overlap_ratio = 1 - stall/prep: the fraction of host
-# prep wall time hidden behind device compute.  Families are DECLARED
+# prep wall time hidden behind device compute.  The stage counters, the
+# lane, byte and signature counters move bucket by bucket, so a window
+# cut mid-replay reads them whole; the per-replay histograms (batch
+# sigs, occupancy, overlap, flush seconds) observe once.  Families are DECLARED
 # in obs/families.py (jax-free) so the attribution model and capture
 # tools see them without this module's crypto-stack import.
 _M_R_PREP = _families.REPLAY_PREP
@@ -105,6 +108,7 @@ _M_R_READBACK = _families.REPLAY_READBACK
 _M_R_OVERLAP = _families.REPLAY_OVERLAP
 _M_R_QDEPTH = _families.REPLAY_QDEPTH
 _M_R_BUCKETS = _families.REPLAY_BUCKETS
+_M_R_SIGS = _families.REPLAY_SIGS
 _M_TRANSFER = _families.TRANSFER_BYTES
 
 # every (program, shape) jax compiles exactly once per process; tracking
@@ -154,12 +158,15 @@ def fused_verify_kernel(blocks, n_blocks, roi, sig_bytes, pub_bytes,
     program takes ~4 min at full opt — warmup() covers both quantized
     block widths, and the persistent cache serves every later process.
     """
-    z_rows = H.digest_words_to_limbs(H.sha256d_blocks(blocks, n_blocks))
-    z = jnp.take(z_rows, roi, axis=0)
-    r = F.from_bytes_be_dev(sig_bytes[:, :32])
-    s = F.from_bytes_be_dev(sig_bytes[:, 32:])
-    qx = F.from_bytes_be_dev(pub_bytes[:, 1:])
-    parity = (pub_bytes[:, 0] & 1).astype(jnp.uint32)
+    with jax.named_scope("verify_sha256d"):
+        z_rows = H.digest_words_to_limbs(
+            H.sha256d_blocks(blocks, n_blocks))
+        z = jnp.take(z_rows, roi, axis=0)
+    with jax.named_scope("verify_unpack"):
+        r = F.from_bytes_be_dev(sig_bytes[:, :32])
+        s = F.from_bytes_be_dev(sig_bytes[:, 32:])
+        qx = F.from_bytes_be_dev(pub_bytes[:, 1:])
+        parity = (pub_bytes[:, 0] & 1).astype(jnp.uint32)
     return S.ecdsa_verify_kernel(z, r, s, qx, parity,
                                  dual_mul_impl=dual_mul_impl,
                                  prep_impl=prep_impl)
@@ -648,8 +655,7 @@ def _mesh_device_fn(bucket: int, count_metrics: bool = True):
                               n_real=pb.n_real, lanes=bucket) as rec:
             with trace.span("mesh/dispatch",
                             dispatch_id=rec["dispatch_id"]):
-                with trace.annotation("mesh/dispatch"):
-                    return _supervised(pb, rec)
+                return _supervised(pb, rec)
 
     return dispatch
 
@@ -837,8 +843,7 @@ def _wrap_resilient(device_fn, items: VerifyItems, roi: np.ndarray,
         try:
             with trace.span("verify/dispatch", corr=corrs,
                             dispatch_id=rec["dispatch_id"]):
-                with trace.annotation("verify/dispatch"):
-                    ok = _dispatch_inner(pb, rec)
+                ok = _dispatch_inner(pb, rec)
         except BaseException as e:
             if rec["outcome"] is None:
                 rec["outcome"] = "error"
@@ -864,9 +869,10 @@ def _run_pipeline(items: VerifyItems, roi: np.ndarray, bucket: int,
     (prep inline on the dispatch thread — the measured baseline the
     overlap metrics are asserted against).  Returns (out, n_buckets)."""
     N = len(items)
-    order = np.argsort(roi, kind="stable")
-    roi_sorted = roi[order]
-    chunks = _plan_buckets(roi_sorted, bucket)
+    with trace.span("replay/sort", corr=corrs, sigs=N):
+        order = np.argsort(roi, kind="stable")
+        roi_sorted = roi[order]
+        chunks = _plan_buckets(roi_sorted, bucket)
     if depth is None:
         depth = int(_os.environ.get("LIGHTNING_TPU_REPLAY_DEPTH", "2"))
     if device_fn is None:
@@ -890,126 +896,136 @@ def _run_pipeline(items: VerifyItems, roi: np.ndarray, bucket: int,
     # _PreparedBucket would pin every bucket's packed host arrays (≈ the
     # re-packed store) in memory until the final readback
     pending: list[tuple[np.ndarray, int, object]] = []
-    t_prep = t_stall = t_dispatch = 0.0
-    staged_bytes = 0
+    t_prep = t_stall = 0.0       # this replay's, for the overlap ratio
     # dispatch-deadline on the prepared-bucket queue: a producer that
     # hangs (or dies without surfacing) must not park the replay forever
     prod_deadline = _deadline.deadline_for("verify")
-    n_done = 0          # buckets dispatched from the producer stream
     timed_out = False
+    lanes_verify = _M_LANES.labels("verify")
+    lanes_hash = _M_LANES.labels("hash")
 
-    if depth > 0 and len(chunks) > 1:
-        q: _queue.Queue = _queue.Queue(maxsize=depth)
-        stop = threading.Event()  # dispatch failed: stop prepping
+    def dispatch_one(pb: _PreparedBucket, stall: float,
+                     queue_wait: float = 0.0) -> None:
+        """One bucket down the device queue; the stage, lane, byte and
+        signature counters move here, bucket by bucket.  `stall` is the
+        prep time the dispatch thread saw: the queue wait when
+        streaming, the whole prep when prepping inline."""
+        nonlocal t_prep, t_stall
+        t0 = time.perf_counter()
+        ok = device_fn(pb, queue_wait=queue_wait)
+        _M_R_DISPATCH.inc(time.perf_counter() - t0)
+        _M_R_PREP.inc(pb.prep_seconds)
+        _M_R_STALL.inc(stall)
+        _M_R_SIGS.inc(pb.n_real)
+        lanes_verify.inc(bucket)
+        lanes_hash.inc(bucket)
+        _M_DEVICE_BYTES.inc(pb.staged_bytes)
+        t_prep += pb.prep_seconds
+        t_stall += stall
+        pending.append((pb.sel, pb.n_real, ok))
 
-        def _put(item) -> bool:
-            # stop-aware put: a producer abandoned by the deadline path
-            # (or raced by a dispatch failure) must never block forever
-            # on a full queue nobody drains — at ANY depth
-            while not stop.is_set():
+    # replay/stream: the dispatch section, from the producer's start (a
+    # bucket's prep before the first dispatch) to the last dispatch
+    # returned
+    with trace.span("replay/stream", corr=corrs, buckets=len(chunks)):
+        if depth > 0 and len(chunks) > 1:
+            q: _queue.Queue = _queue.Queue(maxsize=depth)
+            stop = threading.Event()  # dispatch failed: stop prepping
+
+            def _put(item) -> bool:
+                # stop-aware put: a producer abandoned by the deadline path
+                # (or raced by a dispatch failure) must never block forever
+                # on a full queue nobody drains — at ANY depth
+                while not stop.is_set():
+                    try:
+                        q.put(item, timeout=0.05)
+                        return True
+                    except _queue.Full:
+                        pass
+                return False
+
+            def _producer():
                 try:
-                    q.put(item, timeout=0.05)
-                    return True
-                except _queue.Full:
-                    pass
-            return False
+                    for c in chunks:
+                        if stop.is_set():
+                            return
+                        _fault.fire("producer", "verify")
+                        if not _put(prep(c)):
+                            return
+                    _put(_DONE)
+                except BaseException as e:  # surface on the dispatch thread
+                    _put(e)
 
-        def _producer():
+            th = threading.Thread(target=_producer, name="replay-prep",
+                                  daemon=True)
+            th.start()
             try:
-                for c in chunks:
-                    if stop.is_set():
-                        return
-                    _fault.fire("producer", "verify")
-                    if not _put(prep(c)):
-                        return
-                _put(_DONE)
-            except BaseException as e:  # surface on the dispatch thread
-                _put(e)
-
-        th = threading.Thread(target=_producer, name="replay-prep",
-                              daemon=True)
-        th.start()
-        try:
-            while True:
-                t0 = time.perf_counter()
-                try:
-                    pb = q.get(timeout=prod_deadline)
-                except _queue.Empty:
-                    _deadline.note_exceeded("verify", "producer",
-                                            prod_deadline)
-                    timed_out = True
-                    break
-                wait = time.perf_counter() - t0
-                t_stall += wait
-                if pb is _DONE:
-                    break
-                if isinstance(pb, BaseException):
-                    raise pb
-                _M_R_QDEPTH.observe(q.qsize() + 1)
-                t0 = time.perf_counter()
-                ok = device_fn(pb, queue_wait=wait)
-                t_dispatch += time.perf_counter() - t0
-                t_prep += pb.prep_seconds
-                staged_bytes += pb.staged_bytes
-                pending.append((pb.sel, pb.n_real, ok))
-                n_done += 1
-        finally:
-            # the producer may be parked on a full queue if the
-            # dispatch loop raised — tell it to stop and drain until it
-            # exits (its puts are stop-aware, so it unparks on its
-            # own).  A HUNG producer (deadline path) is abandoned
-            # instead — a daemon thread stuck in prep that the join
-            # below would wait on; when (if) its prep ever returns, the
-            # stop-aware put lets it exit without a consumer.
-            stop.set()
-            while th.is_alive() and not timed_out:
-                try:
-                    q.get_nowait()
-                except _queue.Empty:
-                    pass
-                th.join(timeout=0.005)
-            if timed_out:
                 while True:
+                    t0 = time.perf_counter()
+                    try:
+                        pb = q.get(timeout=prod_deadline)
+                    except _queue.Empty:
+                        _deadline.note_exceeded("verify", "producer",
+                                                prod_deadline)
+                        timed_out = True
+                        break
+                    wait = time.perf_counter() - t0
+                    if pb is _DONE:
+                        # (the wait for the end marker is stall time too)
+                        _M_R_STALL.inc(wait)
+                        t_stall += wait
+                        break
+                    if isinstance(pb, BaseException):
+                        raise pb
+                    _M_R_QDEPTH.observe(q.qsize() + 1)
+                    dispatch_one(pb, wait, queue_wait=wait)
+            finally:
+                # the producer may be parked on a full queue if the
+                # dispatch loop raised — tell it to stop and drain until it
+                # exits (its puts are stop-aware, so it unparks on its
+                # own).  A HUNG producer (deadline path) is abandoned
+                # instead — a daemon thread stuck in prep that the join
+                # below would wait on; when (if) its prep ever returns, the
+                # stop-aware put lets it exit without a consumer.
+                stop.set()
+                while th.is_alive() and not timed_out:
                     try:
                         q.get_nowait()
                     except _queue.Empty:
-                        break
-    else:
-        for c in chunks:
-            pb = prep(c)
-            t_prep += pb.prep_seconds
-            t_stall += pb.prep_seconds  # serial: all prep is visible
-            t0 = time.perf_counter()
-            ok = device_fn(pb)
-            t_dispatch += time.perf_counter() - t0
-            staged_bytes += pb.staged_bytes
-            pending.append((pb.sel, pb.n_real, ok))
-            n_done += 1
+                        pass
+                    th.join(timeout=0.005)
+                if timed_out:
+                    while True:
+                        try:
+                            q.get_nowait()
+                        except _queue.Empty:
+                            break
+        else:
+            for c in chunks:
+                pb = prep(c)
+                dispatch_one(pb, pb.prep_seconds)  # serial: all prep visible
 
-    if timed_out:
-        # restart semantics for the replay: abandon the wedged producer
-        # and prep the remaining buckets inline on this thread.  A
-        # bucket the producer managed to deliver concurrently is simply
-        # verified twice (idempotent) — never skipped, never hung.
-        log.warning("replay producer missed its %.3fs deadline after "
-                    "%d/%d buckets; prepping the rest inline",
-                    prod_deadline, n_done, len(chunks))
-        for c in chunks[n_done:]:
-            pb = prep(c)
-            t_prep += pb.prep_seconds
-            t_stall += pb.prep_seconds
-            t0 = time.perf_counter()
-            ok = device_fn(pb)
-            t_dispatch += time.perf_counter() - t0
-            staged_bytes += pb.staged_bytes
-            pending.append((pb.sel, pb.n_real, ok))
+        if timed_out:
+            # restart semantics for the replay: abandon the wedged producer
+            # and prep the remaining buckets inline on this thread.  A
+            # bucket the producer managed to deliver concurrently is simply
+            # verified twice (idempotent) — never skipped, never hung.
+            log.warning("replay producer missed its %.3fs deadline after "
+                        "%d/%d buckets; prepping the rest inline",
+                        prod_deadline, len(pending), len(chunks))
+            for c in chunks[len(pending):]:
+                pb = prep(c)
+                dispatch_one(pb, pb.prep_seconds)
 
     # the ONLY device→host transfer of the replay: drain the enqueued
     # booleans in dispatch order.  A readback failure (an enqueued
     # program that died after dispatch) diverts just that bucket's rows
     # to the host oracle instead of failing the replay.
-    t0 = time.perf_counter()
     brk = _breaker.get("verify")
+    # the readback counter moves bucket by bucket, each increment from
+    # where the last one ended, so a replay's increments add up to the
+    # whole loop's wall time
+    t_mark = time.perf_counter()
     try:
         with trace.span("replay/readback", corr=corrs,
                         buckets=len(pending)):
@@ -1035,8 +1051,10 @@ def _run_pipeline(items: VerifyItems, roi: np.ndarray, bucket: int,
                     log.warning("replay readback failed (%s); re-checking "
                                 "%d rows on the host", e, n_real)
                     out[idx] = _host_verify_selected(items, roi, idx)
-                rec["readback_ms"] = round(
-                    (time.perf_counter() - t0b) * 1e3, 3)
+                now = time.perf_counter()
+                rec["readback_ms"] = round((now - t0b) * 1e3, 3)
+                _M_R_READBACK.inc(now - t_mark)
+                t_mark = now
                 # the deferred seal: the final outcome (ok / bisect /
                 # host_breaker from dispatch, or readback_host above) is
                 # only known now, so the ring insert + counter + watchdog
@@ -1049,17 +1067,9 @@ def _run_pipeline(items: VerifyItems, roi: np.ndarray, bucket: int,
         # idempotent, so already-sealed ones are untouched)
         for rec in flight_recs:
             _flight.finish(rec)
-    _M_R_READBACK.inc(time.perf_counter() - t0)
 
-    _M_R_PREP.inc(t_prep)
-    _M_R_STALL.inc(t_stall)
-    _M_R_DISPATCH.inc(t_dispatch)
     if t_prep > 0:
         _M_R_OVERLAP.observe(max(0.0, 1.0 - t_stall / t_prep))
-    lanes = len(chunks) * bucket
-    _M_LANES.labels("verify").inc(lanes)
-    _M_LANES.labels("hash").inc(lanes)
-    _M_DEVICE_BYTES.inc(staged_bytes)
     return out, len(chunks)
 
 
@@ -1160,7 +1170,7 @@ def verify_items(items: VerifyItems, bucket: int = DEFAULT_BUCKET, *,
     exported timeline links each bucket back to its enqueue span
     across the producer/dispatch threads (doc/tracing.md).  When
     LIGHTNING_TPU_PROFILE=<dir> is set the whole replay runs inside a
-    jax.profiler session with per-dispatch TraceAnnotations.
+    jax.profiler session, in which every span is a TraceAnnotation.
 
     ``dispatch_map`` (caller-allocated int64 (N,), conventionally
     filled with -1) receives, per SIGNATURE index, the dispatch_id of

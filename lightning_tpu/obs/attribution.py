@@ -237,6 +237,7 @@ def _speedup(critical_s: float, stage_s: float) -> float | None:
 def attribute_family(family: str, records: list[dict], *,
                      stage_totals_s: dict | None = None,
                      ring_complete: bool = True,
+                     lifetime_items: float | None = None,
                      kernel_rate: float | None = None,
                      epsilon: float = EPSILON) -> dict:
     """Attribute one dispatch family's wall time across the pipeline
@@ -250,7 +251,10 @@ def attribute_family(family: str, records: list[dict], *,
     critical path, so critical = stall + dispatch + readback.  Without
     it the family is modeled serial (route flushes, sign batches):
     every stage is on the critical path and critical = queue_wait +
-    prep + dispatch + readback.
+    prep + dispatch + readback.  ``lifetime_items`` (the verify family
+    passes clntpu_replay_sigs_total, which moves with the stage
+    counters) adds the process-lifetime rate beside the ring's: items
+    over the counters' critical path, whatever the ring still holds.
 
     Returns the per-family report section (doc/perf.md for the shape):
     stages, critical-path membership, overlap ratio, bottleneck,
@@ -343,6 +347,12 @@ def attribute_family(family: str, records: list[dict], *,
             }
     else:
         section["throughput_per_s"] = None
+    if overlapped and lifetime_items and critical_s > 0:
+        section["lifetime"] = {
+            "items": int(lifetime_items),
+            "critical_path_s": round(critical_s, 6),
+            "throughput_per_s": round(lifetime_items / critical_s, 1),
+        }
     if overlapped:
         # the two timing sources must agree on the same dispatches:
         # counters are process-lifetime, the ring is bounded, so only a
@@ -412,6 +422,13 @@ def replay_stage_totals(metrics: dict) -> dict | None:
     return totals
 
 
+def replay_sigs_total(metrics: dict) -> float:
+    """Signatures the replay has dispatched, process-lifetime: the
+    counter moves bucket by bucket with the stage totals above, so the
+    two divide into a rate that a wrapped ring cannot give."""
+    return _counter_value(metrics, "clntpu_replay_sigs_total")
+
+
 def report_local(kernel_rate: float | None = None,
                  families: list[str] | None = None,
                  metrics: dict | None = None,
@@ -443,6 +460,8 @@ def report_local(kernel_rate: float | None = None,
         report["families"][fam] = attribute_family(
             fam, records, stage_totals_s=totals,
             ring_complete=summ[fam]["total"] == len(records),
+            lifetime_items=(replay_sigs_total(metrics)
+                            if fam == "verify" else None),
             kernel_rate=kernel_rate if fam == "verify" else None)
     return report
 
@@ -474,6 +493,8 @@ def report_from_snapshot(snap: dict,
         report["families"][fam] = attribute_family(
             fam, records, stage_totals_s=totals,
             ring_complete=len(records) >= lifetime,
+            lifetime_items=(replay_sigs_total(metrics)
+                            if fam == "verify" else None),
             kernel_rate=kernel_rate if fam == "verify" else None)
     return report
 

@@ -43,6 +43,11 @@ ROUTE_FALLBACK = REGISTRY.counter(
 ROUTE_QUEUE = REGISTRY.gauge(
     "clntpu_route_queue_queries",
     "Route queries currently queued awaiting a flush")
+ROUTE_QUEUE_WAIT = REGISTRY.histogram(
+    "clntpu_route_queue_wait_seconds",
+    "Time a route query waited in the queue, from its enqueue to the "
+    "start of the flush that took it (one observation per query)",
+    buckets=DURATION_BUCKETS)
 # owner: daemon/jsonrpc.py's getroute command.  ANSWERED queries only
 # (ok or no-route) — TRY_AGAIN admission rejections are excluded, so
 # this is the same population tools/loadgen.py's post-hoc p99 and the
@@ -204,6 +209,10 @@ REPLAY_BUCKETS = REGISTRY.counter(
     "clntpu_replay_buckets_total",
     "Fused bucket dispatches, by device path",
     labelnames=("path",))
+REPLAY_SIGS = REGISTRY.counter(
+    "clntpu_replay_sigs_total",
+    "Signatures carried by the replay's bucket dispatches (real lanes; "
+    "moves at every dispatch, as the stage counters above do)")
 
 # -- obs/attribution.py: the perf observatory (doc/perf.md) ----------------
 TRANSFER_BYTES = REGISTRY.counter(

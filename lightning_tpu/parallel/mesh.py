@@ -78,9 +78,9 @@ def shard_batch(mesh: Mesh, *arrays):
 
     _fault.fire("mesh", "mesh")
     sh = batch_sharding(mesh)
-    # under LIGHTNING_TPU_PROFILE the reshard cost shows up as its own
-    # host-lane slice next to the shard_map program (doc/tracing.md)
-    with trace.annotation("mesh/reshard"):
+    # the reshard cost is a span of its own: in a profile session it
+    # lies next to the shard_map program (doc/tracing.md)
+    with trace.span("mesh/reshard"):
         return tuple(jax.device_put(a, sh) for a in arrays)
 
 

@@ -118,6 +118,7 @@ _M_OCCUPANCY = _families.ROUTE_OCCUPANCY
 _M_QUERIES = _families.ROUTE_QUERIES
 _M_FALLBACK = _families.ROUTE_FALLBACK
 _M_QUEUE = _families.ROUTE_QUEUE
+_M_QUEUE_WAIT = _families.ROUTE_QUEUE_WAIT
 
 # fallback reasons (label values — observable in tests/doc/routing.md)
 R_BELOW_OCCUPANCY = "below_occupancy"
@@ -200,10 +201,15 @@ def _make_single(n_nodes: int, max_hops: int):
             via = jnp.where(improved, e_star, via)
             return (dist, amt, dly, via, ovf), None
 
+        # the phases carry jax.named_scope names (doc/tracing.md): they
+        # ride the ops' metadata into a device trace, whatever number
+        # XLA gives the while loop
         init = (dist0, amt0, dly0, via0, jnp.asarray(False))
-        (dist, amt, dly, via, ovf), _ = jax.lax.scan(
-            sweep, init, None, length=max_hops)
-        return dist[src], via, ovf
+        with jax.named_scope("route_relax"):
+            (dist, amt, dly, via, ovf), _ = jax.lax.scan(
+                sweep, init, None, length=max_hops)
+        with jax.named_scope("route_extract"):
+            return dist[src], via, ovf
 
     return single
 
@@ -281,6 +287,9 @@ class RouteQuery:
     # correlation carrier minted in getroute's enqueue span — links the
     # caller's span to the coalesced flush dispatch (doc/tracing.md)
     corr: object = None
+    # time.perf_counter() when the query joined the service's queue;
+    # the flush that takes it observes the wait (doc/tracing.md)
+    t_enqueue: float = 0.0
 
 
 def _reconstruct(planes: RoutePlanes, via: np.ndarray, src: int, dst: int,
@@ -349,11 +358,19 @@ def solve_batch(planes: RoutePlanes, queries: list[RouteQuery],
       ("noroute", message)                    — provably unreachable
       ("fallback", reason)                    — solve on the host instead
 
+    Each dispatch is three stage spans on the calling thread
+    (doc/tracing.md): ``route/pack`` (index lookup, masks, operand
+    arrays), ``route/device`` (upload, execute and the readback of the
+    labels, as one stage: nothing waits in between) and
+    ``route/reconstruct``.  A plane refresh after a graph change runs
+    before the first of them.
+
     ``io_acct`` (when given) accumulates the host<->device operand
-    bytes this call staged under keys ``h2d_bytes``/``d2h_bytes`` —
-    RouteService folds them into the flush's flight record; the
-    clntpu_transfer_bytes_total{family="route"} counters are metered
-    here either way (doc/perf.md).
+    bytes this call staged under keys ``h2d_bytes``/``d2h_bytes``, and
+    the pack and reconstruct stages' seconds under ``pack_s`` /
+    ``reconstruct_s`` — RouteService folds them into the flush's flight
+    record; the clntpu_transfer_bytes_total{family="route"} counters
+    are metered here either way (doc/perf.md).
     """
     g = planes.g
     out: list[tuple] = [None] * len(queries)
@@ -376,49 +393,53 @@ def solve_batch(planes: RoutePlanes, queries: list[RouteQuery],
     _attr.note_program("route",
                        (planes.n_pad, planes.e_pad, batch, max_hops))
     kern = _jit_route(planes.n_pad, max_hops)
+    pack_ns = reconstruct_ns = 0
     for start in range(0, len(queries), batch):
         chunk = queries[start:start + batch]
-        B = len(chunk)
-        ok_mat = np.zeros((batch, planes.e_pad), bool)
-        src = np.zeros(batch, np.int32)
-        dst = np.zeros(batch, np.int32)
-        amount = np.ones(batch, np.int64)
-        cltv = np.zeros(batch, np.int64)
-        rf = np.ones(batch, np.int64)
-        for i, q in enumerate(chunk):
-            try:
-                src[i] = node_idx(q.source)
-                dst[i] = node_idx(q.destination)
-            except KeyError as e:
-                # unknown node: this query's error, not the batch's —
-                # its lanes stay masked-off padding
-                out[start + i] = ("error", e)
-                continue
-            if src[i] == dst[i]:
-                # dijkstra raises NoRoute here; a dst-initialized label
-                # would otherwise read as a zero-cost empty route
-                out[start + i] = ("noroute", "source is destination")
-                continue
-            # belts for direct solve_batch callers (the service screens
-            # these before dispatch): values outside [0, cap] wrap the
-            # kernel's own int64 guard products, and the compiled sweep
-            # count is static so a per-query hop cap can't be honored
-            if not 0 <= q.amount_msat <= ROUTE_MAX_AMOUNT_MSAT:
-                out[start + i] = ("fallback", R_AMOUNT_CAP)
-                continue
-            if not 0 <= q.riskfactor <= ROUTE_MAX_RISKFACTOR:
-                out[start + i] = ("fallback", R_RISKFACTOR_CAP)
-                continue
-            if q.max_hops != max_hops:
-                out[start + i] = ("fallback", R_MAX_HOPS)
-                continue
-            amount[i] = q.amount_msat
-            cltv[i] = q.final_cltv
-            rf[i] = q.riskfactor
-            ok_mat[i] = planes.edge_ok_mask(q.excluded_scids)
+        with trace.span("route/pack", queries=len(chunk)) as sp:
+            ok_mat = np.zeros((batch, planes.e_pad), bool)
+            src = np.zeros(batch, np.int32)
+            dst = np.zeros(batch, np.int32)
+            amount = np.ones(batch, np.int64)
+            cltv = np.zeros(batch, np.int64)
+            rf = np.ones(batch, np.int64)
+            for i, q in enumerate(chunk):
+                try:
+                    src[i] = node_idx(q.source)
+                    dst[i] = node_idx(q.destination)
+                except KeyError as e:
+                    # unknown node: this query's error, not the batch's —
+                    # its lanes stay masked-off padding
+                    out[start + i] = ("error", e)
+                    continue
+                if src[i] == dst[i]:
+                    # dijkstra raises NoRoute here; a dst-initialized
+                    # label would otherwise read as a zero-cost empty
+                    # route
+                    out[start + i] = ("noroute", "source is destination")
+                    continue
+                # belts for direct solve_batch callers (the service
+                # screens these before dispatch): values outside
+                # [0, cap] wrap the kernel's own int64 guard products,
+                # and the compiled sweep count is static so a per-query
+                # hop cap can't be honored
+                if not 0 <= q.amount_msat <= ROUTE_MAX_AMOUNT_MSAT:
+                    out[start + i] = ("fallback", R_AMOUNT_CAP)
+                    continue
+                if not 0 <= q.riskfactor <= ROUTE_MAX_RISKFACTOR:
+                    out[start + i] = ("fallback", R_RISKFACTOR_CAP)
+                    continue
+                if q.max_hops != max_hops:
+                    out[start + i] = ("fallback", R_MAX_HOPS)
+                    continue
+                amount[i] = q.amount_msat
+                cltv[i] = q.final_cltv
+                rf[i] = q.riskfactor
+                ok_mat[i] = planes.edge_ok_mask(q.excluded_scids)
+        pack_ns += sp.duration_ns
         h2d += (ok_mat.nbytes + src.nbytes + dst.nbytes
                 + amount.nbytes + cltv.nbytes + rf.nbytes)
-        with enable_x64():
+        with trace.span("route/device"), enable_x64():
             dist_src, via, ovf = kern(
                 *plane_args, jnp.asarray(ok_mat), jnp.asarray(src),
                 jnp.asarray(dst), jnp.asarray(amount), jnp.asarray(cltv),
@@ -427,31 +448,37 @@ def solve_batch(planes: RoutePlanes, queries: list[RouteQuery],
             via = np.asarray(via)
             ovf = np.asarray(ovf)
         d2h += dist_src.nbytes + via.nbytes + ovf.nbytes
-        for i, q in enumerate(chunk):
-            if out[start + i] is not None:
-                continue       # resolved as an error above
-            if ovf[i]:
-                # int64 headroom exceeded somewhere reachable: the host
-                # bigint solver owns this query (exactness over speed)
-                out[start + i] = ("fallback", R_OVERFLOW)
-            elif dist_src[i] >= INF_COST:
-                out[start + i] = ("noroute", _noroute_msg(q))
-            else:
-                try:
-                    route, src_info = _reconstruct(
-                        planes, via[i], int(src[i]), int(dst[i]),
-                        q.amount_msat, q.final_cltv, q.riskfactor,
-                        int(dist_src[i]), max_hops)
-                    out[start + i] = ("ok", route, src_info)
-                except Exception as e:
-                    log.warning("route reconstruction diverged (%s); "
-                                "host re-solves", e)
-                    out[start + i] = ("fallback", R_RECONSTRUCT)
+        with trace.span("route/reconstruct") as sp:
+            for i, q in enumerate(chunk):
+                if out[start + i] is not None:
+                    continue       # resolved as an error above
+                if ovf[i]:
+                    # int64 headroom exceeded somewhere reachable: the
+                    # host bigint solver owns this query (exactness over
+                    # speed)
+                    out[start + i] = ("fallback", R_OVERFLOW)
+                elif dist_src[i] >= INF_COST:
+                    out[start + i] = ("noroute", _noroute_msg(q))
+                else:
+                    try:
+                        route, src_info = _reconstruct(
+                            planes, via[i], int(src[i]), int(dst[i]),
+                            q.amount_msat, q.final_cltv, q.riskfactor,
+                            int(dist_src[i]), max_hops)
+                        out[start + i] = ("ok", route, src_info)
+                    except Exception as e:
+                        log.warning("route reconstruction diverged (%s); "
+                                    "host re-solves", e)
+                        out[start + i] = ("fallback", R_RECONSTRUCT)
+        reconstruct_ns += sp.duration_ns
     _families.TRANSFER_BYTES.labels("route", "h2d").inc(h2d)
     _families.TRANSFER_BYTES.labels("route", "d2h").inc(d2h)
     if io_acct is not None:
         io_acct["h2d_bytes"] = io_acct.get("h2d_bytes", 0) + h2d
         io_acct["d2h_bytes"] = io_acct.get("d2h_bytes", 0) + d2h
+        io_acct["pack_s"] = io_acct.get("pack_s", 0.0) + pack_ns / 1e9
+        io_acct["reconstruct_s"] = (io_acct.get("reconstruct_s", 0.0)
+                                    + reconstruct_ns / 1e9)
     return out
 
 
@@ -531,6 +558,7 @@ class RouteService:
         self._planes: RoutePlanes | None = None
         self._queue: list[RouteQuery] = []
         self._inflight = 0               # queries inside a running flush
+        self._t_idle = 0.0               # perf_counter() at the last flush's end
         self._flush_due: float | None = None
         self._wakeup = asyncio.Event()
         self._task: asyncio.Task | None = None
@@ -586,8 +614,8 @@ class RouteService:
                 # shutdown teardown ordering, or a crashed task): behave
                 # like the plain host dijkstra instead of queueing forever
                 _M_FALLBACK.labels(R_NOT_RUNNING).inc()
-                res = self._host_solve(g, q)
-                self._resolve(q, "host", res)
+                self._resolve(q, "host",
+                              self._host_solve(g, q, R_NOT_RUNNING))
                 route, src_info = await q.future
                 return (route, src_info) if with_source else route
             # admission control (doc/overload.md): past the high
@@ -598,6 +626,7 @@ class RouteService:
             if not self.overload.admit(_overload.PRIO_QUERY):
                 self.overload.shed(_overload.PRIO_QUERY, "admission")
                 raise self.overload.overloaded()
+            q.t_enqueue = time.perf_counter()
             self._queue.append(q)
             self._note_backlog()
             if self._flush_due is None:
@@ -698,7 +727,8 @@ class RouteService:
                     q.future.set_exception(
                         RuntimeError(f"route flush failed: {e}"))
         finally:
-            dt = time.perf_counter() - t0
+            self._t_idle = time.perf_counter()
+            dt = self._t_idle - t0
             _M_FLUSH_SECONDS.observe(dt)
             self._inflight = 0
             self.overload.note_drain(len(batch), dt)
@@ -711,19 +741,35 @@ class RouteService:
         # the flush span flow-links back to each route/enqueue span
         corrs = trace.as_carriers(q.corr for q in batch)
         brk = _breaker.get("route")
+        # where a query's queue wait ends: one observation per query.
+        # The flight record takes only the flusher's own coalescing
+        # wait, from the later of the oldest query's arrival and the
+        # last flush's end: a query's wait overlaps the flush before
+        # it, and a serial family's stages lie end to end (doc/perf.md)
+        t_flush = time.perf_counter()
+        for q in batch:
+            _M_QUEUE_WAIT.observe(t_flush - q.t_enqueue)
+        own_wait = t_flush - max(batch[0].t_enqueue, self._t_idle)
         with _flight.dispatch(
                 "route", corr_ids=_flight.corr_ids(corrs),
                 n_real=len(batch), lanes=len(batch),
+                queue_wait_ms=1e3 * own_wait,
                 breaker_state=brk.state) as rec:
             with trace.span("route/flush", corr=corrs,
                             dispatch_id=rec["dispatch_id"],
-                            queries=len(batch)):
+                            queries=len(batch)) as sp:
                 await self._flush_batch_inner(batch, brk, rec)
             # a flush that completed without a device dispatch ran the
             # host path; only set on success so a crashed flush seals
             # as "error", not "host"
             if rec["outcome"] is None:
                 rec["outcome"] = "host"
+            # sealed here with the flush span's own clock: pack and
+            # reconstruct are the record's prep_ms and readback_ms, so
+            # dispatch_ms is the rest and the three add up to the flush
+            _flight.finish(rec, dispatch_ms=max(
+                0.0, sp.duration_ns / 1e6 - rec["prep_ms"]
+                - (rec["readback_ms"] or 0.0)))
 
     async def _flush_batch_inner(self, batch: list[RouteQuery], brk,
                                  rec: dict) -> None:
@@ -778,7 +824,10 @@ class RouteService:
                 # deadline (LIGHTNING_TPU_DEADLINE_ROUTE_S, off by
                 # default): a hung solver thread fails THIS batch to the
                 # host path instead of wedging every future getroute
-                with trace.annotation("route/dispatch"):
+                # (the worker inherits this span as its context, so
+                # solve_batch's stage spans are its children)
+                with trace.span("route/dispatch",
+                                dispatch_id=rec["dispatch_id"]):
                     results = await _deadline.guard(
                         asyncio.to_thread(solve_batch, self._planes,
                                           device, self.batch,
@@ -789,6 +838,12 @@ class RouteService:
                 rec["outcome"] = "ok"
                 rec["h2d_bytes"] = io_acct.get("h2d_bytes", 0)
                 rec["d2h_bytes"] = io_acct.get("d2h_bytes", 0)
+                # the stage clocks of solve_batch's spans: what is left
+                # of the flush's wall time stays under dispatch_ms
+                rec["prep_ms"] = round(
+                    1e3 * io_acct.get("pack_s", 0.0), 3)
+                rec["readback_ms"] = round(
+                    1e3 * io_acct.get("reconstruct_s", 0.0), 3)
             except _deadline.DeadlineExceeded:
                 brk.record_failure()
                 rec["outcome"] = "deadline"
@@ -806,11 +861,12 @@ class RouteService:
                               "falling back to host dijkstra")
                 host.extend((q, R_DEVICE_ERROR) for q in device)
                 results, device = [], []
-            for q, res in zip(device, results):
-                if res[0] == "fallback":
-                    host.append((q, res[1]))
-                else:
-                    self._resolve(q, "device", res)
+            with trace.span("route/resolve", queries=len(device)):
+                for q, res in zip(device, results):
+                    if res[0] == "fallback":
+                        host.append((q, res[1]))
+                    else:
+                        self._resolve(q, "device", res)
         if host:
             for _, reason in host:
                 _M_FALLBACK.labels(reason).inc()
@@ -822,26 +878,30 @@ class RouteService:
             # device path is immune (planes are immutable snapshots);
             # the host path keeps the same on-loop contract the inline
             # jsonrpc dijkstra always had.
-            for q, _ in host:
-                self._resolve(q, "host", self._host_solve(g, q))
+            for q, reason in host:
+                self._resolve(q, "host", self._host_solve(g, q, reason))
                 # each solve must run ON the loop (torn-graph race with
                 # apply_channel_update), but a 64-query host batch must
                 # not stall every other callback for its full duration
                 await asyncio.sleep(0)
 
     @staticmethod
-    def _host_solve(g, q: RouteQuery) -> tuple:
-        try:
-            route, src_info = DJ.getroute(
-                g, q.source, q.destination, q.amount_msat,
-                final_cltv=q.final_cltv, riskfactor=q.riskfactor,
-                max_hops=q.max_hops, excluded_scids=q.excluded_scids,
-                with_source=True)
-            return ("ok", route, src_info)
-        except NoRoute as e:
-            return ("noroute", str(e))
-        except Exception as e:
-            return ("error", e)
+    def _host_solve(g, q: RouteQuery, reason: str) -> tuple:
+        """One query on the host dijkstra, as a span of its own: a host
+        solve holds the event loop, and `reason` (an R_* constant) says
+        why it was not the device's."""
+        with trace.span("route/host_solve", reason=reason):
+            try:
+                route, src_info = DJ.getroute(
+                    g, q.source, q.destination, q.amount_msat,
+                    final_cltv=q.final_cltv, riskfactor=q.riskfactor,
+                    max_hops=q.max_hops, excluded_scids=q.excluded_scids,
+                    with_source=True)
+                return ("ok", route, src_info)
+            except NoRoute as e:
+                return ("noroute", str(e))
+            except Exception as e:
+                return ("error", e)
 
     def _resolve(self, q: RouteQuery, path: str, res: tuple) -> None:
         fut = q.future

@@ -1123,7 +1123,7 @@ class McfService:
                 # accounting, which read the live gossmap — runs back
                 # ON the loop between rounds; deadline guards each
                 # dispatch round (LIGHTNING_TPU_DEADLINE_MCF_S)
-                with trace.annotation("mcf/dispatch"):
+                with trace.span("mcf/dispatch"):
                     rb = await _deadline.guard(
                         asyncio.to_thread(
                             _solve_indices, planes, device,
@@ -1143,7 +1143,7 @@ class McfService:
                     retry = _judge_round(planes, device, rb, results,
                                          final_attempt=False)
                 if retry:
-                    with trace.annotation("mcf/dispatch"):
+                    with trace.span("mcf/dispatch"):
                         rb2 = await _deadline.guard(
                             asyncio.to_thread(
                                 _solve_indices, planes, device, retry,

@@ -3,8 +3,9 @@
 Functional parity target: common/trace.c (trace_span_start/end/
 suspend/resume emitting USDT probes consumed by contrib/cln-tracer) —
 re-targeted: spans emit JSON lines (one object per completed span) to a
-sink, and — the TPU twist — a span can wrap a `jax.profiler` trace so
-host-side phases correlate with the device timeline.
+sink, and — the TPU twist — while a `jax.profiler` session is open
+(`profile_session()`) every span is also a TraceAnnotation, so
+host-side phases lie beside the device timeline.
 
 Usage:
     from lightning_tpu.utils import trace
@@ -84,12 +85,15 @@ class Carrier:
 
 
 class _Span:
-    __slots__ = ("name", "span_id", "corr_ids")
+    __slots__ = ("name", "span_id", "corr_ids", "duration_ns")
 
     def __init__(self, name: str, span_id: int):
         self.name = name
         self.span_id = span_id
         self.corr_ids: list[int] = []
+        # set when the span closes: a caller that keeps the object
+        # (`with span(...) as sp`) reads the same clock its record has
+        self.duration_ns = 0
 
 
 def new_corr() -> Carrier:
@@ -192,7 +196,8 @@ def span(name: str, corr=None, dispatch_id: int | None = None,
     ``corr`` (a Carrier or iterable of Carriers) stamps the record with
     the correlation ids so the exporter can draw cross-thread flow
     arrows; ``dispatch_id`` ties the span to its flight-recorder
-    DispatchRecord (obs/flight.py)."""
+    DispatchRecord (obs/flight.py).  Inside a profile_session() the
+    span is also a jax.profiler.TraceAnnotation of the same name."""
     parent = _current.get()
     sp = _Span(name, next(_span_ids))
     for c in as_carriers(corr):
@@ -200,6 +205,14 @@ def span(name: str, corr=None, dispatch_id: int | None = None,
             break
         sp.corr_ids.append(c.corr_id)
     token = _current.set(sp)
+    # a deliberately unlocked read: at worst one span around a
+    # session's start or stop goes without its annotation
+    ann = None
+    if _profile_active:
+        import jax
+
+        ann = jax.profiler.TraceAnnotation(name)
+        ann.__enter__()
     t0 = time.monotonic_ns()
     err = None
     try:
@@ -208,6 +221,9 @@ def span(name: str, corr=None, dispatch_id: int | None = None,
         err = type(e).__name__
         raise
     finally:
+        dur = sp.duration_ns = time.monotonic_ns() - t0
+        if ann is not None:
+            ann.__exit__(None, None, None)
         _current.reset(token)
         rec = {
             "name": name,
@@ -217,7 +233,7 @@ def span(name: str, corr=None, dispatch_id: int | None = None,
             "tid": threading.get_native_id(),
             "thread": threading.current_thread().name,
             "start_ns": t0,
-            "duration_ns": time.monotonic_ns() - t0,
+            "duration_ns": dur,
         }
         if sp.corr_ids:
             rec["corr_ids"] = list(sp.corr_ids)
@@ -231,29 +247,12 @@ def span(name: str, corr=None, dispatch_id: int | None = None,
         _emit(rec)
 
 
-@contextmanager
-def device_span(name: str, **attributes):
-    """A span that also captures the XLA device timeline when
-    LIGHTNING_TPU_PROFILE_DIR is set (jax.profiler trace) — the
-    correlation hook cln-tracer gets from USDT probes."""
-    profile_dir = os.environ.get("LIGHTNING_TPU_PROFILE_DIR")
-    if profile_dir:
-        import jax
-
-        with jax.profiler.trace(profile_dir):
-            with span(name, profiled=True, **attributes):
-                yield
-    else:
-        with span(name, **attributes):
-            yield
-
-
 # -- dispatch profiling (LIGHTNING_TPU_PROFILE, doc/tracing.md) ------------
 # One jax.profiler session brackets a whole workload (a replay, a bench
-# round) and every dispatch inside annotates itself, so the host lanes
-# of our Chrome-trace export line up with the XLA device timeline in
-# the same Perfetto UI.  Both are strict no-ops unless the env knob is
-# set AND a session is active — the live path never imports jax.profiler.
+# round); while it is active every span() also opens a TraceAnnotation
+# of the same name, so the program's spans lie on the profiler's clock
+# beside the XLA device lines.  With no session a span costs one
+# boolean test more and the live path never imports jax.profiler.
 
 _profile_active = False          # guarded-by: _lock
 
@@ -264,7 +263,12 @@ def profile_session():
     LIGHTNING_TPU_PROFILE=<dir> is set; nested or concurrent sessions
     no-op (the flag flips under the module lock — two replays racing
     here must not both call start_trace, which would raise into the
-    second one's verify path)."""
+    second one's verify path).  Python's own tracer stays off: at its
+    default level every Python call of the daemon is a host event in
+    the trace (some 200 times as many, PERF.md), which disturbs the
+    window being read; the TraceAnnotations that span() opens do not
+    need it.  stop_trace takes as long either way: its time is the
+    device's op events."""
     global _profile_active
     profile_dir = os.environ.get("LIGHTNING_TPU_PROFILE")
     if not profile_dir:
@@ -280,7 +284,9 @@ def profile_session():
     import jax
 
     try:
-        jax.profiler.start_trace(profile_dir)
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(profile_dir, profiler_options=opts)
     except BaseException:
         with _lock:
             _profile_active = False
@@ -291,19 +297,6 @@ def profile_session():
         with _lock:
             _profile_active = False
         jax.profiler.stop_trace()
-
-
-@contextmanager
-def annotation(name: str):
-    """jax.profiler.TraceAnnotation around one dispatch — visible as a
-    host-lane slice in the XLA profile; no-op outside a session."""
-    if not _profile_active:
-        yield
-        return
-    import jax
-
-    with jax.profiler.TraceAnnotation(name):
-        yield
 
 
 def summarize() -> dict:

@@ -189,6 +189,42 @@ def test_report_local_uses_live_ring_and_counters():
     assert c["families"]["verify"]["bottleneck"] == "dispatch"
 
 
+def test_verify_lifetime_rate_reads_replay_sigs_total():
+    """getperf's verify section divides clntpu_replay_sigs_total by
+    the stage counters' critical path: a rate that outlives the ring
+    (two records left of a replay that dispatched 50 buckets)."""
+    for _ in range(2):
+        rec = flight.begin("verify", n_real=8, lanes=8,
+                           queue_wait_ms=2.0, prep_ms=4.0)
+        rec["readback_ms"] = 1.0
+        flight.finish(rec, "ok", dispatch_ms=3.0)
+    # the registry is the process's: read what earlier cases left
+    from lightning_tpu.obs import REGISTRY
+
+    metrics = REGISTRY.snapshot()["metrics"]
+    totals = attribution.replay_stage_totals(metrics) or {}
+    before = {"items": attribution.replay_sigs_total(metrics),
+              "critical_path_s": sum(totals.get(k, 0.0) for k in (
+                  "stall", "dispatch", "readback"))}
+    families.REPLAY_PREP.inc(50 * 0.004)
+    families.REPLAY_STALL.inc(50 * 0.002)
+    families.REPLAY_DISPATCH.inc(50 * 0.003)
+    families.REPLAY_READBACK.inc(50 * 0.001)
+    families.REPLAY_SIGS.inc(50 * 8)
+    fam = attribution.report_local()["families"]["verify"]
+    assert fam["items"] == 16                    # the ring's two
+    life = fam["lifetime"]
+    assert life["items"] == before["items"] + 400
+    assert life["critical_path_s"] == pytest.approx(
+        before["critical_path_s"] + 0.3)
+    assert life["throughput_per_s"] == pytest.approx(
+        life["items"] / life["critical_path_s"], rel=1e-3)
+    # no counter, no block: the serial families and a process that
+    # never replayed report the ring alone
+    assert "lifetime" not in attribution.attribute_family(
+        "route", [_rec(family="route", n=8)])
+
+
 def test_report_from_snapshot_offline():
     snap = {
         "metrics": {},

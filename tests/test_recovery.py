@@ -344,3 +344,53 @@ def test_crash_action_parse_and_armed():
     assert not fault.crash_armed("append", "store")
     with fault.arm("*:*:crash:1"):
         assert fault.crash_armed("commit", "db")
+
+
+def test_boot_recover_stage_spans(tmp_path, monkeypatch):
+    """A crash boot is the span recovery/boot; recovery/store and the
+    verify replay's stages (gossip/extract, replay/sort, replay/stream,
+    replay/readback) lie inside it, each once, and their parent chains
+    end at it (doc/tracing.md)."""
+    from lightning_tpu.utils import trace
+
+    monkeypatch.delenv("LIGHTNING_TPU_INCIDENT_DIR", raising=False)
+    monkeypatch.setenv("LIGHTNING_TPU_VERIFY_DEVICE", "off")
+    d = str(tmp_path)
+    store = os.path.join(d, "gossip_store")
+    _signed_store(store)
+    R.mark_running(d)
+    spans: list[dict] = []
+    trace.add_tap(spans.append)
+    try:
+        rep = R.boot_recover(d, store_path=store)
+    finally:
+        trace.remove_tap(spans.append)
+    assert rep["state"] == "crash" and rep["verify"]["sigs"] == 6
+    by = {n: [r for r in spans if r["name"] == n]
+          for n in ("recovery/boot", "recovery/store", "gossip/extract",
+                    "replay/sort", "replay/stream", "replay/readback")}
+    assert {n: len(v) for n, v in by.items()} == dict.fromkeys(by, 1)
+    boot = by["recovery/boot"][0]
+    b0, b1 = boot["start_ns"], boot["start_ns"] + boot["duration_ns"]
+    by_id = {r["span_id"]: r for r in spans}
+    for name in by:
+        if name == "recovery/boot":
+            continue
+        r = by[name][0]
+        assert b0 <= r["start_ns"] \
+            and r["start_ns"] + r["duration_ns"] <= b1, name
+        up = r
+        while up["parent_id"] is not None:
+            up = by_id[up["parent_id"]]
+        assert up is boot, name
+    assert by["recovery/store"][0]["parent"] == "recovery/boot"
+    # a clean boot has the same two outer spans and no replay
+    R.mark_clean(d)
+    spans.clear()
+    trace.add_tap(spans.append)
+    try:
+        R.boot_recover(d, store_path=store)
+    finally:
+        trace.remove_tap(spans.append)
+    assert sorted(r["name"] for r in spans) == ["recovery/boot",
+                                                "recovery/store"]

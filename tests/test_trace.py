@@ -168,3 +168,85 @@ def test_instrumented_paths_emit():
     hsm.sign_htlc_batch(client, [b"\xab" * 32], point)
     names = [r["name"] for r in trace.records()]
     assert "hsmd/sign_htlc_batch" in names
+
+
+def _host_events(trace_dir):
+    """Every event on the host planes of the newest xplane under
+    trace_dir: [(name, start_ns, duration_ns)]."""
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+
+    paths = sorted(glob.glob(os.path.join(
+        trace_dir, "plugins", "profile", "*", "*.xplane.pb")))
+    assert paths, "the profile session wrote no xplane"
+    return [(ev.name, int(ev.start_ns), int(ev.duration_ns))
+            for plane in ProfileData.from_file(paths[-1]).planes
+            if not plane.name.startswith("/device:")
+            for line in plane.lines for ev in line.events]
+
+
+def test_span_is_a_trace_annotation_inside_a_profile_session(
+        tmp_path, monkeypatch):
+    """One primitive: inside profile_session() a span is also a
+    TraceAnnotation of the same name, once, on the host plane, and the
+    session runs without Python's own tracer (no event for the Python
+    function the span wraps)."""
+    def marker_fn_for_python_tracer():
+        return sum(range(10))
+
+    monkeypatch.setenv("LIGHTNING_TPU_PROFILE", str(tmp_path))
+    with trace.profile_session():
+        assert trace._profile_active
+        with trace.span("test/in_session", n=1):
+            marker_fn_for_python_tracer()
+            time.sleep(0.002)
+    assert not trace._profile_active
+    with trace.span("test/after_session"):
+        pass
+    events = _host_events(str(tmp_path))
+    mine = [e for e in events if e[0] == "test/in_session"]
+    assert len(mine) == 1
+    rec = next(r for r in trace.records()
+               if r["name"] == "test/in_session")
+    # the annotation lies inside the span's own clock readings' reach
+    assert 0 < mine[0][2] <= rec["duration_ns"] + 1_000_000
+    names = {e[0] for e in events}
+    assert "test/after_session" not in names
+    assert not any("marker_fn_for_python_tracer" in n for n in names)
+
+
+def test_span_outside_a_session_never_touches_the_profiler():
+    """With no session a span costs one boolean test: a fresh
+    interpreter that opens spans has imported neither jax nor its
+    profiler, and profile_session() without the knob is a no-op."""
+    import subprocess
+    import sys
+
+    code = (
+        "import sys\n"
+        "from lightning_tpu.utils import trace\n"
+        "with trace.profile_session():\n"
+        "    with trace.span('a/b'):\n"
+        "        pass\n"
+        "assert trace.records()[0]['name'] == 'a/b'\n"
+        "assert not trace._profile_active\n"
+        "assert 'jax' not in sys.modules, 'jax imported'\n"
+        "assert 'jax.profiler' not in sys.modules\n"
+        "assert not hasattr(trace, 'annotation')\n"
+        "assert not hasattr(trace, 'device_span')\n")
+    import os
+    env = {k: v for k, v in os.environ.items()
+           if k != "LIGHTNING_TPU_PROFILE"}
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    subprocess.run([sys.executable, "-c", code], check=True, cwd=root,
+                   env=env)
+
+
+def test_span_object_carries_its_duration():
+    """`with span(...) as sp` reads the clock its record has (the
+    route flush fills the flight record's stage fields from it)."""
+    with trace.span("timed") as sp:
+        time.sleep(0.005)
+    assert sp.duration_ns == trace.records()[0]["duration_ns"] >= 5_000_000

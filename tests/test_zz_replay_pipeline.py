@@ -286,3 +286,123 @@ def test_fused_matches_unfused(monkeypatch):
     expected = np.ones(n, bool)
     expected[5] = False
     assert (ok_fused == expected).all()
+
+
+# ---------------------------------------------------------------------------
+# stage counters move bucket by bucket (doc/replay_pipeline.md)
+
+_STAGE_COUNTERS = ("clntpu_replay_prep_seconds_total",
+                   "clntpu_replay_prep_stall_seconds_total",
+                   "clntpu_replay_dispatch_seconds_total",
+                   "clntpu_replay_sigs_total",
+                   "clntpu_verify_device_bytes_total")
+
+
+def _lanes(snap: dict, kind: str) -> float:
+    fam = snap["metrics"].get("clntpu_verify_lanes_total", {"samples": []})
+    return sum(s["value"] for s in fam["samples"]
+               if s["labels"].get("kind") == kind)
+
+
+def test_stage_counters_move_between_buckets_two_passes():
+    """Two passes over one set of items: inside a pass every stage
+    counter, the lane and byte counters and clntpu_replay_sigs_total
+    have moved by the time the next bucket is dispatched (a window cut
+    mid-pass reads them whole), and each pass's sums are what the
+    once-a-pass bookkeeping gave: lanes = buckets x bucket, bytes = the
+    buckets' staged bytes, signatures = N, one clntpu_verify_batch_sigs
+    observation of N."""
+    n, bucket = 1000, 128
+    items = _synthetic_items(n)
+    n_buckets = len(verify._plan_buckets(np.arange(n, dtype=np.int64),
+                                         bucket))
+    assert n_buckets == 8
+
+    for depth in (2, 0):                  # streamed, then serial
+        seen: list[dict] = []             # the counters at each dispatch
+        staged: list[int] = []
+
+        def device(pb):
+            seen.append(obs.snapshot())
+            staged.append(pb.staged_bytes)
+            return np.ones(pb.blocks.shape[0], bool)
+
+        before = obs.snapshot()
+        ok = verify.verify_items(items, bucket=bucket, depth=depth,
+                                 device_fn=device)
+        after = obs.snapshot()
+        assert ok.all() and len(seen) == n_buckets
+
+        # at the k-th dispatch the k buckets before it are counted
+        for k, snap in enumerate(seen):
+            assert _counter(snap, "clntpu_replay_sigs_total") \
+                - _counter(before, "clntpu_replay_sigs_total") \
+                == k * bucket
+            assert _lanes(snap, "verify") - _lanes(before, "verify") \
+                == k * bucket
+            assert _counter(snap, "clntpu_verify_device_bytes_total") \
+                - _counter(before, "clntpu_verify_device_bytes_total") \
+                == sum(staged[:k])
+        mid = seen[n_buckets // 2]
+        for name in ("clntpu_replay_prep_seconds_total",
+                     "clntpu_replay_dispatch_seconds_total"):
+            assert _counter(before, name) < _counter(mid, name) \
+                < _counter(after, name), name
+        # the per-replay histograms still observe once, at the end
+        assert _hist(mid, "clntpu_verify_batch_sigs") \
+            == _hist(before, "clntpu_verify_batch_sigs")
+
+        # the pass's sums, as the parent's end-of-pass increments gave
+        assert _counter(after, "clntpu_replay_sigs_total") \
+            - _counter(before, "clntpu_replay_sigs_total") == n
+        for kind in ("verify", "hash"):
+            assert _lanes(after, kind) - _lanes(before, kind) \
+                == n_buckets * bucket
+        assert _counter(after, "clntpu_verify_device_bytes_total") \
+            - _counter(before, "clntpu_verify_device_bytes_total") \
+            == sum(staged)
+        c0, s0 = _hist(before, "clntpu_verify_batch_sigs")
+        c1, s1 = _hist(after, "clntpu_verify_batch_sigs")
+        assert (c1 - c0, s1 - s0) == (1, n)
+        oc0, _ = _hist(before, "clntpu_replay_overlap_ratio")
+        oc1, _ = _hist(after, "clntpu_replay_overlap_ratio")
+        assert oc1 - oc0 == 1
+        assert _counter(after, "clntpu_replay_readback_seconds_total") \
+            > _counter(before, "clntpu_replay_readback_seconds_total")
+        if depth == 0:        # serial: all prep is visible as stall
+            d_prep = _counter(after, _STAGE_COUNTERS[0]) \
+                - _counter(before, _STAGE_COUNTERS[0])
+            d_stall = _counter(after, _STAGE_COUNTERS[1]) \
+                - _counter(before, _STAGE_COUNTERS[1])
+            assert abs(d_prep - d_stall) < 1e-9
+
+
+def test_replay_sort_and_stream_spans():
+    """replay/sort covers the planning before the first bucket;
+    replay/stream is the dispatch section, from the producer's start to
+    the last dispatch returned, so it holds every verify/dispatch and
+    no replay/sort or replay/readback."""
+    from lightning_tpu.utils import trace
+
+    records: list[dict] = []
+    trace.add_tap(records.append)
+    try:
+        verify.verify_items(_synthetic_items(1000), bucket=128, depth=2,
+                            device_fn=_stub_device(0.001))
+    finally:
+        trace.remove_tap(records.append)
+    one = {n: [r for r in records if r["name"] == n]
+           for n in ("replay/sort", "replay/stream", "replay/readback",
+                     "verify/dispatch")}
+    assert [len(one[n]) for n in ("replay/sort", "replay/stream",
+                                  "replay/readback")] == [1, 1, 1]
+    stream = one["replay/stream"][0]
+    s0, s1 = stream["start_ns"], stream["start_ns"] + stream["duration_ns"]
+    assert len(one["verify/dispatch"]) == 8
+    for d in one["verify/dispatch"]:
+        assert d["parent"] == "replay/stream"
+        assert s0 <= d["start_ns"] and d["start_ns"] + d["duration_ns"] <= s1
+    sort = one["replay/sort"][0]
+    assert sort["start_ns"] + sort["duration_ns"] <= s0
+    assert one["replay/readback"][0]["start_ns"] >= s1
+    assert stream["attributes"] == {"buckets": 8}

@@ -314,3 +314,223 @@ def test_route_service_coalesces_and_falls_back(tmp_path):
                         reason=RD.R_NOT_RUNNING) == n0 + 1
 
     _run(scenario())
+
+
+def _hist_count(name: str) -> float:
+    from lightning_tpu import obs
+
+    fam = obs.snapshot()["metrics"].get(name, {})
+    return sum(s.get("count", 0) for s in fam.get("samples", ()))
+
+
+def _hist_sum(name: str) -> float:
+    from lightning_tpu import obs
+
+    fam = obs.snapshot()["metrics"].get(name, {})
+    return sum(s.get("sum", 0.0) for s in fam.get("samples", ()))
+
+
+def _flush_stage_scenario(g, n_queries: int):
+    """n_queries concurrent getroute calls through one device flush;
+    returns (span records, the route flight records)."""
+    from lightning_tpu.obs import flight
+    from lightning_tpu.utils import trace
+
+    rng = np.random.default_rng(2)
+    records: list[dict] = []
+    trace.add_tap(records.append)
+    flight.reset_for_tests()
+
+    async def scenario():
+        svc = RD.RouteService(lambda: g, flush_ms=20.0, batch=Q,
+                              host_max=1)
+        svc.start()
+        try:
+            pairs = []
+            for _ in range(n_queries):
+                a, b = rng.integers(0, g.n_nodes, 2)
+                if a == b:
+                    b = (b + 1) % g.n_nodes
+                pairs.append((bytes(g.node_ids[a]), bytes(g.node_ids[b])))
+            return await asyncio.gather(
+                *(svc.getroute(a, b, 1_000_000) for a, b in pairs),
+                return_exceptions=True)
+        finally:
+            await svc.close()
+
+    try:
+        answers = _run(scenario())
+    finally:
+        trace.remove_tap(records.append)
+    return records, flight.recent("route"), answers
+
+
+def test_route_flush_stage_spans_and_clocks(tmp_path):
+    """A device flush of Q queries leaves route/pack, route/device,
+    route/reconstruct (worker thread) and route/resolve (loop) whose
+    parent chains end at route/flush, one queue-wait observation per
+    query, and a flight record with its stage fields filled."""
+    g = _net(tmp_path, 60, 16, seed=21)
+    waits0 = _hist_count("clntpu_route_queue_wait_seconds")
+    records, flights, _ = _flush_stage_scenario(g, Q)
+    assert _hist_count("clntpu_route_queue_wait_seconds") == waits0 + Q
+
+    by_id = {r["span_id"]: r for r in records}
+
+    def chain(rec):
+        names = []
+        while rec is not None:
+            names.append(rec["name"])
+            rec = by_id.get(rec["parent_id"])
+        return names
+
+    flush = [r for r in records if r["name"] == "route/flush"]
+    assert len(flush) == 1
+    for name in ("route/pack", "route/device", "route/reconstruct"):
+        recs = [r for r in records if r["name"] == name]
+        assert len(recs) == 1, name
+        assert chain(recs[0]) == [name, "route/dispatch", "route/flush"]
+        assert recs[0]["tid"] != flush[0]["tid"]       # worker thread
+    resolve = [r for r in records if r["name"] == "route/resolve"]
+    assert len(resolve) == 1
+    assert chain(resolve[0]) == ["route/resolve", "route/flush"]
+    assert resolve[0]["tid"] == flush[0]["tid"]        # on the loop
+    disp = [r for r in records if r["name"] == "route/dispatch"]
+    assert len(disp) == 1                 # each dispatch shows once
+    stage_ns = sum(r["duration_ns"] for r in records if r["name"] in (
+        "route/pack", "route/device", "route/reconstruct"))
+    assert stage_ns <= disp[0]["duration_ns"] <= flush[0]["duration_ns"]
+
+    assert len(flights) == 1
+    rec = flights[0]
+    assert rec["outcome"] == "ok" and rec["n_real"] == Q
+    assert rec["dispatch_id"] == disp[0]["dispatch_id"]
+    pack = next(r for r in records if r["name"] == "route/pack")
+    recon = next(r for r in records if r["name"] == "route/reconstruct")
+    assert rec["prep_ms"] == pytest.approx(pack["duration_ns"] / 1e6,
+                                           abs=2e-3)
+    assert rec["readback_ms"] == pytest.approx(
+        recon["duration_ns"] / 1e6, abs=2e-3)
+    assert rec["prep_ms"] > 0 and rec["readback_ms"] > 0
+    # the flusher's own coalescing wait: at most the 20 ms flush window
+    # (the batch fills at once here), never a query's wait
+    assert 0 <= rec["queue_wait_ms"] < 1_000
+    # serial family: the stages add up to the flush's wall time
+    total = rec["prep_ms"] + rec["dispatch_ms"] + rec["readback_ms"]
+    assert total == pytest.approx(flush[0]["duration_ns"] / 1e6, abs=0.01)
+
+
+def test_route_perf_window_fits_wall_span_back_to_back(
+        tmp_path, monkeypatch):
+    """Flushes back to back, each query waiting out the flush before
+    its own: that wait goes to the histogram, not onto the flusher's
+    serial path, so getperf's route section keeps window_s within
+    wall_span_s and its rate is the one the callers saw."""
+    import time
+
+    from lightning_tpu.obs import attribution, flight
+
+    g = _net(tmp_path, 60, 16, seed=21)
+    real = RD.solve_batch
+
+    def slow(*a, **kw):
+        time.sleep(0.05)
+        return real(*a, **kw)
+
+    monkeypatch.setattr(RD, "solve_batch", slow)
+    rng = np.random.default_rng(3)
+    pairs = []
+    for _ in range(2 * Q):
+        a, b = rng.integers(0, g.n_nodes, 2)
+        if a == b:
+            b = (b + 1) % g.n_nodes
+        pairs.append((bytes(g.node_ids[a]), bytes(g.node_ids[b])))
+    flight.reset_for_tests()
+    waits0 = _hist_count("clntpu_route_queue_wait_seconds")
+    sum0 = _hist_sum("clntpu_route_queue_wait_seconds")
+
+    async def scenario():
+        svc = RD.RouteService(lambda: g, flush_ms=1.0, batch=Q,
+                              host_max=1)
+        svc.start()
+
+        async def caller(i, pair):
+            # the second half arrives while the first flush runs
+            await asyncio.sleep(0.01 * (i // Q))
+            for _ in range(3):
+                try:
+                    await svc.getroute(*pair, 1_000_000)
+                except DJ.NoRoute:
+                    pass
+
+        t0 = time.perf_counter()
+        try:
+            await asyncio.gather(*(caller(i, p)
+                                   for i, p in enumerate(pairs)))
+            return time.perf_counter() - t0
+        finally:
+            await svc.close()
+
+    wall = _run(scenario())
+    records = flight.recent("route")
+    assert len(records) >= 3
+    n = _hist_count("clntpu_route_queue_wait_seconds") - waits0
+    assert n == 6 * Q
+    mean_wait = (_hist_sum("clntpu_route_queue_wait_seconds") - sum0) / n
+    assert mean_wait > 0.02            # queries did wait under a flush
+    sec = attribution.attribute_family("route", records)
+    assert sec["items"] == 6 * Q
+    # ... and the flusher did not: its own wait is a sliver of that
+    assert sec["stages"]["queue_wait_s"] / len(records) < mean_wait / 2
+    assert sec["window_s"] <= sec["wall_span_s"] + 1e-3
+    assert sec["wall_span_s"] <= wall + 1e-3
+    assert sec["throughput_per_s"] >= 6 * Q / wall
+
+
+def test_forced_reconstruct_fallback_leaves_host_solve_span(
+        tmp_path, monkeypatch):
+    """A device route that fails its own reconstruction is re-solved on
+    the loop inside a route/host_solve span that names the reason."""
+    g = _net(tmp_path, 60, 16, seed=21)
+    real = RD._reconstruct
+    calls = {"n": 0}
+
+    def flaky(*a, **kw):
+        calls["n"] += 1
+        if calls["n"] == 1:
+            raise RuntimeError("predecessor walk diverged")
+        return real(*a, **kw)
+
+    monkeypatch.setattr(RD, "_reconstruct", flaky)
+    records, flights, answers = _flush_stage_scenario(g, Q)
+    assert calls["n"] >= 1
+    host = [r for r in records if r["name"] == "route/host_solve"]
+    assert len(host) == 1
+    assert host[0]["attributes"] == {"reason": RD.R_RECONSTRUCT}
+    assert host[0]["parent"] == "route/flush"
+    flush = next(r for r in records if r["name"] == "route/flush")
+    assert host[0]["tid"] == flush["tid"]              # on the loop
+    # every caller still got its answer
+    assert not any(isinstance(a, BaseException)
+                   and not isinstance(a, DJ.NoRoute) for a in answers)
+
+
+def test_route_program_carries_named_scopes():
+    """The route program's phases ride the ops' metadata under stable
+    names (a device trace shows them whatever number XLA gives the
+    while loop); the module keeps the name the benchmark matches."""
+    import jax.numpy as jnp
+    from jax import enable_x64
+
+    e_pad, n_pad = 256, 64
+    with enable_x64():
+        zeros = jnp.zeros((e_pad,), jnp.int64)
+        lowered = RD._jit_route(n_pad, RD.DEFAULT_MAX_HOPS).lower(
+            jnp.zeros((e_pad,), jnp.int32), jnp.zeros((e_pad,), jnp.int32),
+            zeros, zeros, zeros, zeros, zeros,
+            jnp.zeros((Q, e_pad), bool), jnp.zeros((Q,), jnp.int32),
+            jnp.zeros((Q,), jnp.int32), jnp.ones((Q,), jnp.int64),
+            jnp.zeros((Q,), jnp.int64), jnp.ones((Q,), jnp.int64))
+    text = lowered.as_text(debug_info=True)
+    assert "route_relax" in text and "route_extract" in text
+    assert "module @jit_single" in text

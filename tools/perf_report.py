@@ -118,6 +118,12 @@ def render(report: dict) -> str:
         thr = sec.get("throughput_per_s")
         if thr:
             lines.append(f"  throughput {thr:.1f} items/s")
+        life = sec.get("lifetime")
+        if life:
+            lines.append(
+                f"  lifetime {life['items']} items over "
+                f"{_fmt_s(life['critical_path_s'])}: "
+                f"{life['throughput_per_s']:.1f} items/s")
         roof = sec.get("roofline")
         if roof:
             lines.append(
