@@ -1,27 +1,50 @@
-"""Device-batched pathfinding: a vmapped backward Bellman-Ford sweep
-over RoutePlanes, plus the micro-batching RouteService front-end.
+"""Device-batched pathfinding: a backward Bellman-Ford sweep over
+RoutePlanes for a whole batch of queries at once, plus the
+micro-batching RouteService front-end.
 
 The host dijkstra (routing/dijkstra.py) solves one query at a time with
 a heapq; its SoA layout was always "device-shaped for a later jax
 bellman-ford sweep" — this is that sweep.  The same move the paper
 makes for signatures applies to routing: serial per-request work
-becomes ONE vmapped XLA program over Q concurrent queries.
+becomes ONE XLA program over Q concurrent queries.
 
-Kernel shape: ``max_hops`` Jacobi relaxation sweeps in a ``lax.scan``.
-Each sweep gathers the previous sweep's (cost, amount, delay) labels at
-every edge's RECEIVING node, prices the edge with the exact integer
-cost model of dijkstra.py (compounding msat fees + CLN risk cost), and
-folds candidates per FORWARDING node with two segment-mins (cost, then
-lowest-edge-index among cost ties).  After k sweeps a node's label is
-the cheapest ≤k-hop path to the destination — identical to dijkstra's
-settled labels whenever the hop cap doesn't bind (LN paths are ~5 hops
-against a cap of 20).
+Kernel shape: ``max_hops`` Jacobi relaxation sweeps in a ``lax.scan``,
+written batch-first (no ``vmap``).  The labels are one
+``[n_pad, 3, lanes]`` table of (cost, amount, delay), the lanes
+(queries) its minor axis.  Each sweep fetches, for every edge, the row
+of its RECEIVING node — all lanes' labels of the sweep before, one
+indexed row per edge, the only indexed operation over edges — prices
+the edge in dense ``[lanes, edges]`` arrays with the exact integer
+cost model of dijkstra.py (compounding msat fees + CLN risk cost; the
+two divisions by a constant as an exact multiply-high, ``_div_const``),
+and folds the candidates per FORWARDING node with a dense segmented
+minimum: the edges stand on the device in source-major order
+(``edge_order``: a stable sort of the RoutePlanes edges by
+``edge_src``, padding rows last), so a node's out-edges are one run of
+rows and the minimum is ``steps`` doubling passes
+``x[i] = min(x[i], x[i - 2^k])`` inside a run, read at each run's last
+row.  ``steps`` is the largest out-degree's power of two (5 on a
+uniform 25,000-channel graph, 11 on a hub of 2,000 channels), a static
+size of the program read off the planes.  The winner's amount, delay
+and edge ride the same selects; no scatter of any width is in the
+program.  After k sweeps a node's label is the cheapest ≤k-hop path to
+the destination — identical to dijkstra's settled labels whenever the
+hop cap doesn't bind (LN paths are ~5 hops against a cap of 20).
+
+What ``via`` indexes: the program returns, per lane and node, the
+winning edge's index in RoutePlanes order (the ``orig`` plane carries
+it through the device order), so ``_reconstruct`` and
+``RoutePlanes.edge_ok_mask`` never see the device order.  The
+per-flush edge mask is packed in device order on the host.
 
 Tie-break rule (stated, tested): among equal-cost candidate edges for
 a node within one sweep, the LOWEST edge index in the destination-keyed
 CSR wins; an existing label is only replaced by a STRICTLY cheaper one.
 Total cost is tie-break-independent; the chosen hops may differ from
-dijkstra's when distinct paths price identically.
+dijkstra's when distinct paths price identically.  The program before
+PR 26 (two ``segment_min`` scatters under ``vmap``) is frozen as
+tests/route_scatter_oracle.py; tests/test_zz_route_parity.py holds this
+one equal to it label for label.
 
 Exactness: all msat math runs in int64 under a scoped x64 context (the
 crypto kernels' uint32-limb world is untouched).  Per-edge overflow
@@ -142,113 +165,191 @@ def _device_enabled() -> bool:
 # The kernel
 
 
-def _make_single(n_nodes: int, max_hops: int):
-    """One query's backward sweep; closed over the static node count
-    (segment-min needs it) and the sweep budget."""
+def _div_const(x, d: int):
+    """floor(x / d) for int64 ``x`` in [0, 2^62) and a constant ``d``,
+    exactly, without a division: the high half of x·m for the 64-bit
+    magic m = floor(2^(62+l) / d) + 1, l = ceil(log2 d), shifted down.
+    m·d exceeds 2^(62+l) by at most d ≤ 2^l, so x·m / 2^(62+l) lies
+    less than 1/d above x/d for every x < 2^62 and their floors agree
+    (Granlund & Montgomery 1994).  The 128-bit product is four 32×32
+    partial products in uint64.  XLA's own int64 division is bit-serial
+    on a TPU: the sweep's two quotients were its largest single cost
+    (PERF.md §6, PR 26).  A wrapped (masked-out) ``x`` gives a defined
+    quotient that nothing reads."""
+    shift = 62 + (d - 1).bit_length()
+    m = (1 << shift) // d + 1
+    m0, m1 = jnp.uint64(m & 0xFFFFFFFF), jnp.uint64(m >> 32)
+    low = jnp.uint64(0xFFFFFFFF)
+    x = x.astype(jnp.uint64)
+    x0, x1 = x & low, x >> 32
+    p01, p10 = x0 * m1, x1 * m0
+    mid = ((x0 * m0) >> 32) + (p01 & low) + (p10 & low)
+    high = x1 * m1 + (p01 >> 32) + (p10 >> 32) + (mid >> 32)
+    return (high >> (shift - 64)).astype(jnp.int64)
 
-    def single(edge_src, edge_dst, base, ppm, cd, hmin, hmax,
-               edge_ok, src, dst, amount, final_cltv, riskfactor):
-        E = edge_src.shape[0]
-        # labels start at the destination (a select; the same values
-        # as zeros.at[dst].set(x) without a per-query scatter)
-        at_dst = jnp.arange(n_nodes, dtype=jnp.int32) == dst
+
+def _make_single(n_nodes: int, max_hops: int, steps: int):
+    """The whole batch's backward sweep; closed over the static node
+    count, the sweep budget and the number of doubling steps the
+    segmented minimum takes (`EdgeOrder.steps`)."""
+
+    def single(edge_src, edge_dst, base, ppm, cd, hmin, hmax, orig,
+               seg_last, edge_ok, src, dst, amount, final_cltv,
+               riskfactor):
+        # labels start at the destination (a select, not a scatter):
+        # one [n_nodes, 3, lanes] table of (cost, amount, delay), a row
+        # of it all lanes' labels of one node
+        at_dst = jnp.arange(n_nodes, dtype=jnp.int32)[:, None] == dst
         dist0 = jnp.where(at_dst, jnp.int64(0), jnp.int64(INF_COST))
         if dist0.dtype != jnp.int64:
             raise RuntimeError(
                 "route kernel traced outside an x64 scope — msat math "
                 "would silently truncate to int32")
-        amt0 = jnp.where(at_dst, amount, jnp.int64(0))
-        dly0 = jnp.where(at_dst, final_cltv, jnp.int64(0))
-        via0 = jnp.full((n_nodes,), -1, jnp.int32)
-        # edge indices ride the tie-break min as int64, like the costs:
-        # on the TPU (PR 23, v5e) this program's int32 scatter-min
-        # returned indices that were not the winners', so amt/dly/via
-        # followed a padding edge, every later sweep priced a zero
-        # amount and no reconstruction agreed with its label.  Label for
-        # label the int64 form equals the CPU backend there.
-        eidx = jnp.arange(E, dtype=jnp.int64)
-        # per-edge safe-amount ceiling: both int64 products stay < 2^61
-        cdr = cd * riskfactor
+        table0 = jnp.stack(
+            [dist0, jnp.where(at_dst, amount, jnp.int64(0)),
+             jnp.where(at_dst, final_cltv, jnp.int64(0))], axis=1)
+        via0 = jnp.full((n_nodes, src.shape[0]), -1, jnp.int32)
+        # the dense edge-level arrays are [lanes, edges], the edge axis
+        # minor (a TPU pads a minor axis of 64 lanes to 128: measured,
+        # PERF.md §6 PR 26), so an edge plane broadcasts as it stands.
+        # Per-edge safe-amount ceiling: both int64 products stay < 2^61
+        cdr = cd * riskfactor[:, None]
         thr = jnp.minimum(OVF_LIMIT // jnp.maximum(ppm, 1),
                           OVF_LIMIT // jnp.maximum(cdr, 1))
+        # edge i and edge i - 2^k leave the same node: static per
+        # topology revision, so it stays outside the sweeps
+        at = jnp.arange(edge_src.shape[0])
+        same = [(at >= 1 << k) & (edge_src == jnp.roll(edge_src, 1 << k))
+                for k in range(steps)]
+        has_out = (seg_last >= 0)[:, None]
+        seg_at = jnp.maximum(seg_last, 0)
+        idx0 = jnp.broadcast_to(orig, edge_ok.shape)
 
         def sweep(carry, _):
-            dist, amt, dly, via, ovf = carry
-            d_v = dist[edge_dst]
-            a_v = amt[edge_dst]
+            table, via, ovf = carry
+            # the sweep's one indexed operation over edges: all lanes'
+            # labels of each edge's receiving node, one row per edge
+            d_v, a_v, y_v = table[edge_dst].transpose(1, 2, 0)
             ok = edge_ok & (d_v < INF_COST)
             # the HTLC carried over u→v is a_v (what v receives) —
             # channel_update limits apply to it (dijkstra.py:107)
             ok &= (a_v >= hmin) & ((hmax == 0) | (a_v <= hmax))
             unsafe = a_v > thr
-            ovf |= jnp.any(ok & unsafe)
+            ovf |= jnp.any(ok & unsafe, axis=1)
             ok &= ~unsafe
-            fee = base + (a_v * ppm) // 1_000_000
-            risk = 1 + (a_v * cdr) // _RISK_DENOM
-            cand = jnp.where(ok, d_v + fee + risk, INF_COST)
-            best = jax.ops.segment_min(cand, edge_src,
-                                       num_segments=n_nodes)
-            improved = best < dist
-            # tie-break: lowest edge index among the winning cost
-            e_cand = jnp.where(ok & (cand == best[edge_src]), eidx, E)
-            best_e = jax.ops.segment_min(e_cand, edge_src,
-                                         num_segments=n_nodes)
-            e_star = jnp.minimum(best_e, E - 1).astype(jnp.int32)
-            v_star = edge_dst[e_star]
-            dist = jnp.where(improved, best, dist)
-            amt = jnp.where(improved, amt[v_star] + fee[e_star], amt)
-            dly = jnp.where(improved, dly[v_star] + cd[e_star], dly)
-            via = jnp.where(improved, e_star, via)
-            return (dist, amt, dly, via, ovf), None
+            fee = base + _div_const(a_v * ppm, 1_000_000)
+            risk = 1 + _div_const(a_v * cdr, _RISK_DENOM)
+            # what the forwarding node's labels become if this edge wins
+            new = jnp.stack([jnp.where(ok, d_v + fee + risk, INF_COST),
+                             a_v + fee, y_v + cd])
+            idx = idx0
+            # per-source minimum as a prefix minimum inside each run of
+            # equal edge_src; amount, delay and edge ride the same
+            # selects.  Tie-break: lowest RoutePlanes edge index among
+            # the winning cost — the order is a stable sort, so inside
+            # a run that is the earlier row, which `<=` keeps
+            for k in range(steps):
+                new_k = jnp.roll(new, 1 << k, axis=2)
+                take = same[k] & (new_k[0] <= new[0])
+                new = jnp.where(take, new_k, new)
+                idx = jnp.where(take, jnp.roll(idx, 1 << k, axis=1), idx)
+            # each node's winner stands at the last of its edges
+            win = new.transpose(2, 0, 1)[seg_at]
+            improved = has_out & (win[:, 0] < table[:, 0])
+            table = jnp.where(improved[:, None], win, table)
+            via = jnp.where(improved, idx.T[seg_at], via)
+            return (table, via, ovf), None
 
         # the phases carry jax.named_scope names (doc/tracing.md): they
         # ride the ops' metadata into a device trace, whatever number
         # XLA gives the while loop
-        init = (dist0, amt0, dly0, via0, jnp.asarray(False))
+        init = (table0, via0, jnp.zeros(src.shape, bool))
         with jax.named_scope("route_relax"):
-            (dist, amt, dly, via, ovf), _ = jax.lax.scan(
+            (table, via, ovf), _ = jax.lax.scan(
                 sweep, init, None, length=max_hops)
         with jax.named_scope("route_extract"):
-            return dist[src], via, ovf
+            dist_src = jnp.take_along_axis(table[:, 0], src[None], axis=0)
+            return dist_src[0], via.T, ovf
 
     return single
 
 
 @functools.lru_cache(maxsize=8)
-def _jit_route(n_nodes: int, max_hops: int):
-    single = _make_single(n_nodes, max_hops)
-    return jax.jit(jax.vmap(single, in_axes=(None,) * 7 + (0,) * 6))
+def _jit_route(n_nodes: int, max_hops: int, steps: int):
+    return jax.jit(_make_single(n_nodes, max_hops, steps))
+
+
+# ---------------------------------------------------------------------------
+# The device-side edge order
+
+
+@dataclass(frozen=True)
+class EdgeOrder:
+    """Where each RoutePlanes edge stands on the device: real edges in
+    a stable sort by forwarding node, padding rows last."""
+
+    perm: np.ndarray      # (e_pad,) int32 device position → RoutePlanes
+    #                       index; on the device, the kernel's `orig`
+    inv: np.ndarray       # (e_pad,) RoutePlanes index → device position
+    seg_last: np.ndarray  # (n_pad,) int32 device position of a node's
+    #                       last out-edge, -1 where it has none
+    steps: int            # doubling steps: 2^steps ≥ largest out-degree
+
+
+def edge_order(planes: RoutePlanes) -> EdgeOrder:
+    """The planes' device order, made once per topology revision (it
+    rides `planes.dev` with the topology uploads)."""
+    order = planes.dev.get("order")
+    if order is None:
+        key = planes.edge_src.astype(np.int64)
+        key[planes.e_real:] = planes.n_pad
+        perm = np.argsort(key, kind="stable").astype(np.int32)
+        inv = np.empty(planes.e_pad, np.int64)
+        inv[perm] = np.arange(planes.e_pad)
+        degree = np.bincount(key[:planes.e_real], minlength=planes.n_pad)
+        seg_last = np.where(degree > 0, np.cumsum(degree) - 1,
+                            -1).astype(np.int32)
+        steps = max(int(degree.max(initial=1)) - 1, 0).bit_length()
+        order = planes.dev["order"] = EdgeOrder(perm, inv, seg_last, steps)
+    return order
 
 
 _PLANE_ORDER = ("edge_src", "edge_dst", "edge_base", "edge_ppm",
-                "edge_cltv", "edge_hmin", "edge_hmax")
+                "edge_cltv", "edge_hmin", "edge_hmax", "perm", "seg_last")
 
 
 # parameter planes a channel_update can change (patchable in place on
-# device); src/dst are topology and only ever full-upload
+# device); the others are topology and only ever full-upload
 _PARAM_PLANES = ("edge_base", "edge_ppm", "edge_cltv", "edge_hmin",
                  "edge_hmax")
 
 
 def _device_plane_args(planes: RoutePlanes) -> tuple:
-    """Upload (once per planes revision) and return (operands,
-    staged_bytes) — the shared device planes plus how many host bytes
-    this call actually staged (zero when every plane was carried over;
-    the perf-attribution transfer accounting, doc/perf.md).
+    """Upload (once per planes revision) and return (operands, edge_ok,
+    staged_bytes): the shared device planes in `edge_order`, the host's
+    enabled mask in the same order, and how many host bytes this call
+    actually staged (zero when every plane was carried over; the
+    perf-attribution transfer accounting, doc/perf.md).
     A param-refresh revision arrives with the topology uploads carried
     over, so only the missing planes stage; an incremental revision
     (planes.patch_idx set by with_patched_params) scatters JUST the
-    touched lanes into the carried device planes — a channel_update
-    burst costs O(changed) device traffic, not a full re-upload.
+    touched lanes into the carried device planes, at the positions the
+    order gives them — a channel_update burst costs O(changed) device
+    traffic, not a full re-upload.
     int64 planes must cross jnp.asarray inside the x64 scope or they
     silently truncate to int32."""
     staged = 0
+    order = edge_order(planes)
     patch = planes.patch_idx
     if patch is not None and len(patch):
+        at = order.inv[patch]
+        if "edge_ok" in planes.dev:
+            edge_ok = planes.dev["edge_ok"].copy()
+            edge_ok[at] = planes.edge_enabled[patch]
+            planes.dev["edge_ok"] = edge_ok
         with enable_x64():
-            ji = jnp.asarray(patch)
-            staged += patch.nbytes if hasattr(patch, "nbytes") \
-                else len(patch) * 8
+            ji = jnp.asarray(at)
+            staged += at.nbytes
             for name in _PARAM_PLANES:
                 if name in planes.dev:
                     host_vals = getattr(planes, name)[patch]
@@ -256,14 +357,20 @@ def _device_plane_args(planes: RoutePlanes) -> tuple:
                     vals = jnp.asarray(host_vals)
                     planes.dev[name] = planes.dev[name].at[ji].set(vals)
     planes.patch_idx = None
+    if "edge_ok" not in planes.dev:
+        planes.dev["edge_ok"] = planes.edge_enabled[order.perm]
     missing = [n for n in _PLANE_ORDER if n not in planes.dev]
     if missing:
         with enable_x64():
             for name in missing:
-                host_plane = getattr(planes, name)
+                # the order's own planes as they are, the edge planes
+                # permuted into it
+                host_plane = (getattr(order, name) if hasattr(order, name)
+                              else getattr(planes, name)[order.perm])
                 staged += host_plane.nbytes
                 planes.dev[name] = jnp.asarray(host_plane)
-    return tuple(planes.dev[n] for n in _PLANE_ORDER), staged
+    return (tuple(planes.dev[n] for n in _PLANE_ORDER),
+            planes.dev["edge_ok"], staged)
 
 
 # ---------------------------------------------------------------------------
@@ -368,9 +475,10 @@ def solve_batch(planes: RoutePlanes, queries: list[RouteQuery],
     ``io_acct`` (when given) accumulates the host<->device operand
     bytes this call staged under keys ``h2d_bytes``/``d2h_bytes``, and
     the pack and reconstruct stages' seconds under ``pack_s`` /
-    ``reconstruct_s`` — RouteService folds them into the flush's flight
-    record; the clntpu_transfer_bytes_total{family="route"} counters
-    are metered here either way (doc/perf.md).
+    ``reconstruct_s``, and the program's step count under ``steps`` —
+    RouteService folds them into the flush's flight record; the
+    clntpu_transfer_bytes_total{family="route"} counters are metered
+    here either way (doc/perf.md).
     """
     g = planes.g
     out: list[tuple] = [None] * len(queries)
@@ -382,17 +490,20 @@ def solve_batch(planes: RoutePlanes, queries: list[RouteQuery],
             i = idx_cache[nid] = g.node_index(nid)
         return i
 
-    plane_args, h2d = _device_plane_args(planes)
+    plane_args, edge_ok, h2d = _device_plane_args(planes)
+    order = edge_order(planes)
     d2h = 0
     # retrace detector: the traced program is keyed by EVERY static
     # operand shape — node pad, edge pad (e_pad grows independently of
     # n_pad on channel bursts and re-traces under the same lru_cache'd
-    # jit callable), the query batch width, and the sweep budget.  A
-    # first-sight of this full key after warmup means this flush is
-    # paying a compile (doc/perf.md)
-    _attr.note_program("route",
-                       (planes.n_pad, planes.e_pad, batch, max_hops))
-    kern = _jit_route(planes.n_pad, max_hops)
+    # jit callable), the query batch width, the sweep budget and the
+    # segmented minimum's step count (it grows when the largest
+    # out-degree crosses a power of two).  A first-sight of this full
+    # key after warmup means this flush is paying a compile
+    # (doc/perf.md)
+    _attr.note_program("route", (planes.n_pad, planes.e_pad, batch,
+                                 max_hops, order.steps))
+    kern = _jit_route(planes.n_pad, max_hops, order.steps)
     pack_ns = reconstruct_ns = 0
     for start in range(0, len(queries), batch):
         chunk = queries[start:start + batch]
@@ -435,7 +546,10 @@ def solve_batch(planes: RoutePlanes, queries: list[RouteQuery],
                 amount[i] = q.amount_msat
                 cltv[i] = q.final_cltv
                 rf[i] = q.riskfactor
-                ok_mat[i] = planes.edge_ok_mask(q.excluded_scids)
+                # rows in the device's edge order (edge_order)
+                ok_mat[i] = (
+                    planes.edge_ok_mask(q.excluded_scids)[order.perm]
+                    if q.excluded_scids else edge_ok)
         pack_ns += sp.duration_ns
         h2d += (ok_mat.nbytes + src.nbytes + dst.nbytes
                 + amount.nbytes + cltv.nbytes + rf.nbytes)
@@ -479,6 +593,7 @@ def solve_batch(planes: RoutePlanes, queries: list[RouteQuery],
         io_acct["pack_s"] = io_acct.get("pack_s", 0.0) + pack_ns / 1e9
         io_acct["reconstruct_s"] = (io_acct.get("reconstruct_s", 0.0)
                                     + reconstruct_ns / 1e9)
+        io_acct["steps"] = order.steps
     return out
 
 
@@ -501,26 +616,31 @@ def route_cost_msat(g, route: list[RouteHop], riskfactor: int) -> int:
     return cost
 
 
+def program_operands(batch: int, n_pad: int, e_pad: int, make) -> tuple:
+    """The route program's operands at one quantized shape, in call
+    order, each as `make(shape, dtype)` — zeros for a warm-up, shape
+    structs for a compile without the chip (tools/chip_compile.py)."""
+    e32, e64 = make((e_pad,), jnp.int32), make((e_pad,), jnp.int64)
+    b32, b64 = make((batch,), jnp.int32), make((batch,), jnp.int64)
+    return (e32, e32, e64, e64, e64, e64, e64, e32,
+            make((n_pad,), jnp.int32), make((batch, e_pad), jnp.bool_),
+            b32, b32, b64, b64, b64)
+
+
 def warmup(batch: int = ROUTE_BATCH, n_pad: int = 64, e_pad: int = 256,
-           max_hops: int = DEFAULT_MAX_HOPS) -> None:
+           max_hops: int = DEFAULT_MAX_HOPS, steps: int = 4) -> None:
     """Compile (or load from the persistent cache) the route program at
     the given quantized shape, off the live path — same contract as
     gossip.verify.warmup.  Daemons call RouteService.warmup() instead,
-    which passes the live planes' actual padded shape.
+    which passes the live planes' actual padded shape and step count.
 
     Wrapped in attribution.warmup_scope(): this first-sight is the
-    expected one; a LATER first-sight of a different (n_pad, max_hops)
-    fires clntpu_retrace_total{program="route"} (doc/perf.md)."""
+    expected one; a LATER first-sight of a different key fires
+    clntpu_retrace_total{program="route"} (doc/perf.md)."""
     with _attr.warmup_scope(), enable_x64():
-        _attr.note_program("route", (n_pad, e_pad, batch, max_hops))
-        zeros_i64 = jnp.zeros((e_pad,), jnp.int64)
-        np.asarray(_jit_route(n_pad, max_hops)(
-            jnp.zeros((e_pad,), jnp.int32), jnp.zeros((e_pad,), jnp.int32),
-            zeros_i64, zeros_i64, zeros_i64, zeros_i64, zeros_i64,
-            jnp.zeros((batch, e_pad), bool), jnp.zeros((batch,), jnp.int32),
-            jnp.zeros((batch,), jnp.int32), jnp.ones((batch,), jnp.int64),
-            jnp.zeros((batch,), jnp.int64), jnp.ones((batch,), jnp.int64),
-        )[0])
+        _attr.note_program("route", (n_pad, e_pad, batch, max_hops, steps))
+        np.asarray(_jit_route(n_pad, max_hops, steps)(
+            *program_operands(batch, n_pad, e_pad, jnp.zeros))[0])
 
 
 # ---------------------------------------------------------------------------
@@ -578,7 +698,8 @@ class RouteService:
             return
         self._planes = RoutePlanes.current(g, self._planes)
         p = self._planes
-        await asyncio.to_thread(warmup, self.batch, p.n_pad, p.e_pad)
+        await asyncio.to_thread(warmup, self.batch, p.n_pad, p.e_pad,
+                                steps=edge_order(p).steps)
 
     async def close(self) -> None:
         self._closed = True
@@ -838,6 +959,8 @@ class RouteService:
                 rec["outcome"] = "ok"
                 rec["h2d_bytes"] = io_acct.get("h2d_bytes", 0)
                 rec["d2h_bytes"] = io_acct.get("d2h_bytes", 0)
+                # the program's third static size, beside the lanes
+                rec["steps"] = io_acct.get("steps")
                 # the stage clocks of solve_batch's spans: what is left
                 # of the flush's wall time stays under dispatch_ms
                 rec["prep_ms"] = round(

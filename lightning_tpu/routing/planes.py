@@ -32,6 +32,10 @@ _I64_CLAMP = (1 << 62) - 1
 _MIN_NODE_PAD = 64
 _MIN_EDGE_PAD = 256
 
+# `dev` entries that only a topology change invalidates (routing.device
+# fills them: the edge order and the planes that no channel_update moves)
+TOPO_DEV = ("order", "edge_src", "edge_dst", "perm", "seg_last")
+
 
 def _pow2_pad(n: int, floor: int) -> int:
     p = floor
@@ -171,10 +175,10 @@ class RoutePlanes:
                 np.minimum(g.htlc_max_msat[d, c], _I64_CLAMP), np.int64),
             edge_enabled=_padded(g.enabled[d, c], bool),
             # parameter planes re-upload lazily; the topology uploads
-            # are shared by construction and carry over — a param-only
-            # gossip bump must not re-stage the unchanged src/dst planes
-            dev={k: v for k, v in self.dev.items()
-                 if k in ("edge_src", "edge_dst")},
+            # (and the device-side edge order they stand in) are shared
+            # by construction and carry over — a param-only gossip bump
+            # must not re-stage the unchanged src/dst planes
+            dev={k: v for k, v in self.dev.items() if k in TOPO_DEV},
         )
 
     # touched-lane patching threshold: bursts touching more than this

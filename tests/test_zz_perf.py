@@ -146,10 +146,13 @@ def test_note_shape_seam_fires_retrace_after_warmup():
     assert len(got) == 1 and got[0]["key"] == [8, 999]
 
 
-def test_forced_post_warmup_route_compile_fires_retrace(tmp_path):
+@pytest.mark.parametrize("differs", ["shape", "steps"])
+def test_forced_post_warmup_route_compile_fires_retrace(tmp_path, differs):
     """The real thing: a route warmup arms the detector, then a solve
-    over planes of a DIFFERENT padded shape pays an actual XLA compile
-    — exactly the anomaly the detector exists for."""
+    over planes of a DIFFERENT padded shape — or of the warmed shape
+    with another step count, as after a graph change that carries the
+    largest out-degree across a power of two — pays an actual XLA
+    compile: exactly the anomaly the detector exists for."""
     from lightning_tpu.gossip import gossmap as GM
     from lightning_tpu.gossip import store as gstore
     from lightning_tpu.gossip import synth
@@ -162,10 +165,13 @@ def test_forced_post_warmup_route_compile_fires_retrace(tmp_path):
     g = GM.from_store(gstore.load_store(path))
     planes = RoutePlanes.build(g)
 
-    # warm a DIFFERENT (tiny) shape: the route program compiles in
-    # well under a second on CPU, so this is a real-compile test
-    small_n = planes.n_pad // 2
-    RD.warmup(4, small_n, 32)
+    # warm a DIFFERENT program: the route program compiles in about a
+    # second on CPU, so this is a real-compile test
+    steps = RD.edge_order(planes).steps
+    if differs == "shape":
+        RD.warmup(4, planes.n_pad // 2, 32)
+    else:
+        RD.warmup(8, planes.n_pad, planes.e_pad, steps=steps + 1)
     assert attribution.retrace_state()["armed"]
 
     got = []
@@ -180,9 +186,10 @@ def test_forced_post_warmup_route_compile_fires_retrace(tmp_path):
         _counter(s0, "clntpu_retrace_total", program="route") == 1
     assert got and got[0]["program"] == "route"
     # the key carries EVERY static operand shape: node pad, edge pad,
-    # batch width, sweep budget (an e_pad-only change re-traces too)
+    # batch width, sweep budget (an e_pad-only change re-traces too),
+    # and the segmented minimum's step count
     assert got[0]["key"] == [planes.n_pad, planes.e_pad, 8,
-                             RD.DEFAULT_MAX_HOPS]
+                             RD.DEFAULT_MAX_HOPS, steps]
     # route transfer accounting rode the same dispatch
     assert _counter(s1, "clntpu_transfer_bytes_total",
                     family="route", direction="h2d") > \

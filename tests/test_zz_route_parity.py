@@ -110,6 +110,215 @@ def test_randomized_corpus_parity(tmp_path):
         _assert_parity(g, queries, RD.solve_batch(planes, queries, batch=Q))
 
 
+# ---------------------------------------------------------------------------
+# The dense program against the frozen scatter form, label for label
+
+
+_EDGE_PLANES = ("edge_src", "edge_dst", "edge_base", "edge_ppm",
+                "edge_cltv", "edge_hmin", "edge_hmax")
+
+
+def _raw_planes(n_real, src, dst, *, rng, enabled=None, **params):
+    """RoutePlanes over bare arrays (no gossmap): edges in the CSR's
+    destination-major order, padded like `build` pads them."""
+    from lightning_tpu.routing.planes import (_MIN_EDGE_PAD,
+                                              _MIN_NODE_PAD, _pow2_pad)
+
+    by_dst = np.argsort(dst, kind="stable")
+    e_real = len(src)
+    n_pad = _pow2_pad(n_real, _MIN_NODE_PAD)
+    e_pad = _pow2_pad(e_real, _MIN_EDGE_PAD)
+
+    def plane(vals, dtype):
+        out = np.zeros(e_pad, dtype)
+        out[:e_real] = np.asarray(vals)[by_dst]
+        return out
+
+    draw = {"base": rng.integers(0, 5_000, e_real),
+            "ppm": rng.integers(0, 10_000, e_real),
+            "cltv": rng.integers(6, 145, e_real),
+            "hmin": np.zeros(e_real, np.int64),
+            "hmax": np.zeros(e_real, np.int64)}
+    draw.update(params)
+    return RoutePlanes(
+        g=None, topo_version=0, params_version=0, n_real=n_real,
+        n_pad=n_pad, e_real=e_real, e_pad=e_pad,
+        edge_src=plane(src, np.int32), edge_dst=plane(dst, np.int32),
+        edge_chan=plane(np.arange(e_real) // 2, np.int32),
+        edge_dir=plane(np.arange(e_real) % 2, np.int8),
+        edge_base=plane(draw["base"], np.int64),
+        edge_ppm=plane(draw["ppm"], np.int64),
+        edge_cltv=plane(draw["cltv"], np.int64),
+        edge_hmin=plane(draw["hmin"], np.int64),
+        edge_hmax=plane(draw["hmax"], np.int64),
+        edge_enabled=plane(np.ones(e_real, bool) if enabled is None
+                           else enabled, bool),
+        edge_cap_sat=np.zeros(e_pad, np.float32))
+
+
+def _both_ways(a, b):
+    """Channels a[i]—b[i] as directed edges, both directions."""
+    return np.concatenate([a, b]), np.concatenate([b, a])
+
+
+def _lanes(rng, n_real, lanes=Q, amount=None, rf=None):
+    src = rng.integers(0, n_real, lanes).astype(np.int32)
+    dst = ((src + 1 + rng.integers(0, n_real - 1, lanes))
+           % n_real).astype(np.int32)
+    if amount is None:
+        amount = (10 ** rng.uniform(3, 9, lanes)).astype(np.int64)
+    if rf is None:
+        rf = np.full(lanes, 10, np.int64)
+    return (src, dst, np.asarray(amount, np.int64),
+            rng.integers(9, 40, lanes).astype(np.int64),
+            np.asarray(rf, np.int64))
+
+
+def _case_corpus(seed):
+    """The randomized corpus's graphs and constraints, from gossmaps."""
+    def build(tmp_path):
+        rng = np.random.default_rng(42 + seed)
+        g = _net(tmp_path, 100, 40, seed)
+        nc = g.n_channels
+        g.enabled[:, rng.integers(0, nc, nc // 10)] = False
+        g.htlc_min_msat[:, rng.integers(0, nc, nc // 8)] = 50_000
+        g.htlc_max_msat[:, rng.integers(0, nc, nc // 8)] = 80_000
+        planes = RoutePlanes.build(g)
+        lanes = _lanes(rng, g.n_nodes,
+                       amount=rng.integers(1_000, 10_000_000, Q),
+                       rf=rng.choice([1, 10, 100], Q))
+        return planes, np.tile(planes.edge_enabled, (Q, 1)), lanes
+    return build
+
+
+def _case_hub(tmp_path):
+    """One node with 120 channels among nodes of degree 2-3: the
+    doubling steps follow the hub (7), the other segments are short."""
+    rng = np.random.default_rng(5)
+    n = 200
+    ring = np.arange(n)
+    chords = rng.choice(n, 60, replace=False)
+    a = np.concatenate([ring, chords, np.full(120, 7)])
+    b = np.concatenate([(ring + 1) % n, (chords + 17) % n,
+                        rng.choice(np.delete(ring, 7), 120, replace=False)])
+    planes = _raw_planes(n, *_both_ways(a, b), rng=rng)
+    assert RD.edge_order(planes).steps == 7
+    return planes, np.tile(planes.edge_enabled, (Q, 1)), _lanes(rng, n)
+
+
+def _case_ties(tmp_path):
+    """Uniform fees and every channel laid twice: masses of equal-cost
+    candidates that only the RoutePlanes edge index tells apart."""
+    rng = np.random.default_rng(6)
+    n = 24
+    a = rng.integers(0, n, 50)
+    b = (a + 1 + rng.integers(0, n - 1, 50)) % n
+    src, dst = _both_ways(np.tile(a, 2), np.tile(b, 2))
+    e = len(src)
+    planes = _raw_planes(n, src, dst, rng=rng, base=np.full(e, 1000),
+                         ppm=np.full(e, 100), cltv=np.full(e, 6))
+    return (planes, np.tile(planes.edge_enabled, (Q, 1)),
+            _lanes(rng, n, amount=np.full(Q, 123_456)))
+
+
+def _case_excluded(tmp_path):
+    """Each lane masks edges of its own (excluded scids, disabled
+    channels): the per-flush mask reaches the device in its order."""
+    rng = np.random.default_rng(7)
+    n = 40
+    a = rng.integers(0, n, 110)
+    b = (a + 1 + rng.integers(0, n - 1, 110)) % n
+    planes = _raw_planes(n, *_both_ways(a, b), rng=rng,
+                         enabled=rng.random(220) < 0.9)
+    ok = np.tile(planes.edge_enabled, (Q, 1))
+    ok &= rng.random(ok.shape) < 0.85
+    return planes, ok, _lanes(rng, n)
+
+
+def _case_padding(e_real):
+    """`e_real` edges in an `e_pad` of 256: none, one and many padding
+    rows behind the sorted edges."""
+    def build(tmp_path):
+        rng = np.random.default_rng(8)
+        n = 50
+        src = rng.integers(0, n, e_real)
+        dst = (src + 1 + rng.integers(0, n - 1, e_real)) % n
+        planes = _raw_planes(
+            n, src, dst, rng=rng,
+            hmin=rng.integers(0, 2_000, e_real),
+            hmax=rng.integers(0, 3, e_real) * rng.integers(
+                1, 1 << 40, e_real))
+        assert (planes.e_real, planes.e_pad) == (e_real, 256)
+        return planes, np.tile(planes.edge_enabled, (Q, 1)), _lanes(rng, n)
+    return build
+
+
+def _case_two_riskfactors(tmp_path):
+    rng = np.random.default_rng(9)
+    n = 40
+    a = rng.integers(0, n, 100)
+    b = (a + 1 + rng.integers(0, n - 1, 100)) % n
+    planes = _raw_planes(n, *_both_ways(a, b), rng=rng)
+    return (planes, np.tile(planes.edge_enabled, (Q, 1)),
+            _lanes(rng, n, rf=np.array([1, 1000] * (Q // 2))))
+
+
+def _case_overflow(tmp_path):
+    """One lane's amount passes the int64 guard on the first hop; its
+    flag rises and the other lanes' labels do not move for it."""
+    rng = np.random.default_rng(10)
+    n = 30
+    a = rng.integers(0, n, 80)
+    b = (a + 1 + rng.integers(0, n - 1, 80)) % n
+    planes = _raw_planes(n, *_both_ways(a, b), rng=rng,
+                         ppm=np.full(160, 10_000))
+    amount = (10 ** rng.uniform(3, 9, Q)).astype(np.int64)
+    amount[2] = RD.OVF_LIMIT // 10_000 + 1
+    return (planes, np.tile(planes.edge_enabled, (Q, 1)),
+            _lanes(rng, n, amount=amount))
+
+
+_LABEL_CASES = {
+    "corpus-3": _case_corpus(3), "corpus-11": _case_corpus(11),
+    "corpus-29": _case_corpus(29), "hub": _case_hub, "ties": _case_ties,
+    "excluded": _case_excluded, "no-padding": _case_padding(256),
+    "one-padding-row": _case_padding(255),
+    "padding": _case_padding(150),
+    "two-riskfactors": _case_two_riskfactors, "overflow": _case_overflow,
+}
+
+
+@pytest.mark.parametrize("case", list(_LABEL_CASES))
+def test_labels_equal_the_scatter_form(tmp_path, case):
+    """`(dist[src], via, ovf)` of the dense program, label for label
+    equal to the program it replaced (tests/route_scatter_oracle.py),
+    `via` in RoutePlanes edge indices for every node of every lane."""
+    import jax.numpy as jnp
+    from jax import enable_x64
+
+    import route_scatter_oracle as oracle
+
+    planes, ok, lanes = _LABEL_CASES[case](tmp_path)
+    order = RD.edge_order(planes)
+    with enable_x64():
+        want = oracle.jit_scatter_route(planes.n_pad, RD.DEFAULT_MAX_HOPS)(
+            *(jnp.asarray(getattr(planes, name)) for name in _EDGE_PLANES),
+            jnp.asarray(ok), *map(jnp.asarray, lanes))
+        plane_args, _, _ = RD._device_plane_args(planes)
+        got = RD._jit_route(planes.n_pad, RD.DEFAULT_MAX_HOPS, order.steps)(
+            *plane_args, jnp.asarray(ok[:, order.perm]),
+            *map(jnp.asarray, lanes))
+    for name, w, g in zip(("dist_src", "via", "ovf"), want, got):
+        w, g = np.asarray(w), np.asarray(g)
+        assert (w.shape, w.dtype) == (g.shape, g.dtype), name
+        assert np.array_equal(w, g), (name, np.argwhere(w != g)[:5])
+    dist_src, via, ovf = (np.asarray(x) for x in want)
+    # the case exercises what it names
+    assert (dist_src < RD.INF_COST).sum() >= Q // 2 or case == "overflow"
+    assert (via >= 0).any()
+    assert ovf.any() == (case == "overflow")
+
+
 def test_excluded_scids_and_unreachable(tmp_path):
     g = _net(tmp_path, 60, 16, seed=7)
     planes = RoutePlanes.build(g)
@@ -223,6 +432,31 @@ def test_planes_version_refresh(tmp_path):
         cltv_delta=6, htlc_min_msat=0, htlc_max_msat=0,
         fee_base_msat=1, fee_ppm=1)
     assert RoutePlanes.current(g, p3) is not p3
+    # a patch reaches the device planes at the rows the edge order
+    # gives the touched edges (the sort moved them), the enabled mask
+    # included
+    base = RoutePlanes.build(g)
+    q = [RD.RouteQuery(bytes(g.node_ids[0]),
+                       bytes(g.node_ids[g.n_nodes - 1]), 250_000)]
+    RD.solve_batch(base, q, batch=Q)                # uploads the planes
+    for c in (1, 2, 5):
+        assert g.apply_channel_update(
+            int(g.scids[c]), 0, timestamp=int(g.timestamps[0, c]) + 1,
+            disabled=(c == 2),
+            cltv_delta=40 + c, htlc_min_msat=c, htlc_max_msat=0,
+            fee_base_msat=1_000 + c, fee_ppm=c)
+    patched = RoutePlanes.current(g, base)
+    patch, order = patched.patch_idx, RD.edge_order(patched)
+    assert patch is not None and len(patch) == 3
+    assert (order.inv[patch] != patch).any()        # the sort moved them
+    plane_args, edge_ok, _ = RD._device_plane_args(patched)
+    assert patched.patch_idx is None
+    for name, dev in zip(RD._PLANE_ORDER[:7], plane_args):
+        assert np.array_equal(np.asarray(dev),
+                              getattr(patched, name)[order.perm]), name
+    assert np.array_equal(edge_ok, patched.edge_enabled[order.perm])
+    assert not np.array_equal(edge_ok, base.dev["edge_ok"])
+    _assert_parity(g, q, RD.solve_batch(patched, q, batch=Q))
     # the refreshed planes still price identically to the host
     g2 = g
     planes = RoutePlanes.current(g2, None)
@@ -515,22 +749,67 @@ def test_forced_reconstruct_fallback_leaves_host_solve_span(
                    and not isinstance(a, DJ.NoRoute) for a in answers)
 
 
-def test_route_program_carries_named_scopes():
-    """The route program's phases ride the ops' metadata under stable
-    names (a device trace shows them whatever number XLA gives the
-    while loop); the module keeps the name the benchmark matches."""
+def test_div_const_is_floor_division():
+    """The sweep's quotients by 10^6 and by the risk denominator are a
+    multiply-high by a magic number: exact on [0, 2^62), the range the
+    overflow guard leaves a priced edge's products in."""
+    import jax
     import jax.numpy as jnp
+    from jax import enable_x64
+
+    rng = np.random.default_rng(3)
+    for d in (1_000_000, RD._RISK_DENOM):
+        top = (1 << 62) - 1
+        vals = [0, 1, d - 1, d, d + 1, top, top - 1, RD.OVF_LIMIT,
+                RD.OVF_LIMIT - 1, RD.OVF_LIMIT + 1]
+        vals += [k * d + o for k in (2, 12_345, (1 << 41) - 3, top // d)
+                 for o in (-1, 0, 1) if k * d + o <= top]
+        vals += rng.integers(0, 1 << 62, 50_000).tolist()
+        vals += rng.integers(0, 1 << 40, 50_000).tolist()
+        with enable_x64():
+            got = np.asarray(jax.jit(lambda x, d=d: RD._div_const(x, d))(
+                jnp.asarray(np.array(vals, np.int64))))
+        assert got.dtype == np.int64
+        assert got.tolist() == [v // d for v in vals]
+
+
+def _lowered_toy_program(steps: int = 3):
+    import jax
     from jax import enable_x64
 
     e_pad, n_pad = 256, 64
     with enable_x64():
-        zeros = jnp.zeros((e_pad,), jnp.int64)
-        lowered = RD._jit_route(n_pad, RD.DEFAULT_MAX_HOPS).lower(
-            jnp.zeros((e_pad,), jnp.int32), jnp.zeros((e_pad,), jnp.int32),
-            zeros, zeros, zeros, zeros, zeros,
-            jnp.zeros((Q, e_pad), bool), jnp.zeros((Q,), jnp.int32),
-            jnp.zeros((Q,), jnp.int32), jnp.ones((Q,), jnp.int64),
-            jnp.zeros((Q,), jnp.int64), jnp.ones((Q,), jnp.int64))
-    text = lowered.as_text(debug_info=True)
+        return RD._jit_route(n_pad, RD.DEFAULT_MAX_HOPS, steps).lower(
+            *RD.program_operands(Q, n_pad, e_pad, jax.ShapeDtypeStruct))
+
+
+def test_route_program_carries_named_scopes():
+    """The route program's phases ride the ops' metadata under stable
+    names (a device trace shows them whatever number XLA gives the
+    while loop); the module keeps the name the benchmark matches."""
+    text = _lowered_toy_program().as_text(debug_info=True)
     assert "route_relax" in text and "route_extract" in text
     assert "module @jit_single" in text
+
+
+def test_route_program_holds_no_scatter():
+    """A sweep's only indexed operations are row fetches: the per-source
+    minimum is a dense segmented reduction, so nothing in the program
+    lowers to a scatter of any width (the frozen oracle does)."""
+    import jax
+    from jax import enable_x64
+
+    import route_scatter_oracle as oracle
+
+    text = _lowered_toy_program().as_text()
+    assert "scatter" not in text
+    assert "stablehlo.gather" in text
+    with enable_x64():
+        e32 = jax.ShapeDtypeStruct((256,), "int32")
+        e64 = jax.ShapeDtypeStruct((256,), "int64")
+        b32 = jax.ShapeDtypeStruct((Q,), "int32")
+        b64 = jax.ShapeDtypeStruct((Q,), "int64")
+        old = oracle.jit_scatter_route(64, RD.DEFAULT_MAX_HOPS).lower(
+            e32, e32, e64, e64, e64, e64, e64,
+            jax.ShapeDtypeStruct((Q, 256), "bool"), b32, b32, b64, b64, b64)
+    assert "stablehlo.scatter" in old.as_text()   # the check can see one

@@ -93,22 +93,21 @@ def _derive(sh):
     return S._jit_derive(), (_limbs(SMOKE_NODES, sh),)
 
 
-def _route(sh):
-    import jax.numpy as jnp
+# the smoke graph's doubling steps: uniform endpoints give a largest
+# out-degree of about 25, under 2^5
+SMOKE_ROUTE_STEPS = 5
 
+
+def _route(sh):
     from lightning_tpu.routing import device as RD
     from lightning_tpu.routing import planes as RP
 
     n_pad = RP._pow2_pad(SMOKE_NODES, RP._MIN_NODE_PAD)
     e_pad = RP._pow2_pad(2 * SMOKE_CHANNELS, RP._MIN_EDGE_PAD)
-    b = RD.ROUTE_BATCH
-    e32 = _sds((e_pad,), jnp.int32, sh)
-    e64 = _sds((e_pad,), jnp.int64, sh)
-    b32 = _sds((b,), jnp.int32, sh)
-    b64 = _sds((b,), jnp.int64, sh)
-    return (RD._jit_route(n_pad, RD.DEFAULT_MAX_HOPS),
-            (e32, e32, e64, e64, e64, e64, e64,
-             _sds((b, e_pad), jnp.bool_, sh), b32, b32, b64, b64, b64))
+    return (RD._jit_route(n_pad, RD.DEFAULT_MAX_HOPS, SMOKE_ROUTE_STEPS),
+            RD.program_operands(
+                RD.ROUTE_BATCH, n_pad, e_pad,
+                lambda shape, dtype: _sds(shape, dtype, sh)))
 
 
 def _mcf(sh):
