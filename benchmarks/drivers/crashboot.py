@@ -34,15 +34,28 @@ from __future__ import annotations
 import os
 import random
 import shutil
+import tempfile
 import threading
 import time
+import types
 
 from lib import counters
 from gen import store as gen_store
 from reference import storefile as ref_store
 
-MINI = {"channels": 96, "nodes": 24}     # the warm-up boot's store
+# the warm-up boot's store: a warm-up of the program, whatever the
+# configuration's graph is
+MINI = {"channels": 96, "nodes": 24}
 PASS_WAIT_S = 240.0                      # for the pass in flight
+# a cell of this shape at a size a CPU test run can hold
+# (tests/conftest.py)
+TINY = {
+    "graph": {"channels": 96, "nodes": 24},
+    "params": {"bad_records": 24, "sample_records": 9,
+               "open_after_s": 0.2, "trace_after_s": 0.2,
+               "trace_seconds": 0.5},
+    "env": {"LIGHTNING_TPU_VERIFY_BUCKET": "8"}, "argv": [],
+}
 
 
 def mark_crashed(data_dir: str) -> None:
@@ -79,6 +92,9 @@ def setup(run) -> dict:
     store, truth = gen_store.cached_store(
         run.cache_dir, run.cell["config"], run.config["graph"], run.seed,
         signed=True, bad_records=p["bad_records"])
+    run.note(inputs={
+        "endpoints": gen_store.endpoint_law(run.config["graph"]),
+        "store_sha256": gen_store.sha256_16(store)})
     run.phase("store", t)
 
     t = time.monotonic()
@@ -101,8 +117,8 @@ def setup(run) -> dict:
     mini_dir = os.path.join(run.work_dir, "mini")
     os.makedirs(mini_dir)
     mini = os.path.join(mini_dir, "gossip_store")
-    gen_store.make_store(mini, seed=run.seed, sign=True, bad_records=3,
-                         **MINI)
+    gen_store.make_store(mini, graph=MINI, seed=run.seed, sign=True,
+                         bad_records=3)
     mark_crashed(mini_dir)
     rep = recovery.boot_recover(mini_dir, store_path=mini)
     warm_gap = abs(((rep.get("verify") or {}).get("invalid", -1)) - 3)
@@ -270,6 +286,52 @@ def check(run, state) -> tuple[list, int, int]:
     # records found wrong, or the run itself where another number failed
     failed = mismatched or int(any(abs(v) > lim for _, v, lim in compared))
     return compared, max(checked, 1), failed
+
+
+def control_on(store: str, truth: dict, seed: int,
+               params: dict) -> tuple[int, list]:
+    """(records compared, the numbers `check` compares) with the
+    control's validity bits in the place of the program's: on the
+    records the check samples, a checker that looks at a
+    channel_announcement's first signature only; valid elsewhere."""
+    import numpy as np
+
+    msgs = ref_store.read_alive(store)
+    keys = dict(ref_store.ca_fields(m) for m in msgs["ca"])
+    rows = sample_rows(seed, {k: len(v) for k, v in msgs.items()},
+                       truth["bad"], params["sample_records"])
+    verdict = {"ca": lambda m: ref_store.ca_valid(m, skip=(1, 2, 3)),
+               "cu": lambda m: ref_store.cu_valid(m, keys),
+               "na": ref_store.na_valid}
+    bits = {k: np.ones(len(v), bool) for k, v in msgs.items()}
+    for kind, picked in rows.items():
+        for row in picked:
+            bits[kind][row] = verdict[kind](msgs[kind][row])
+    result = types.SimpleNamespace(ca_valid=bits["ca"], cu_valid=bits["cu"],
+                                   na_valid=bits["na"])
+    report = {"verify": {
+        "records": truth["records"], "sigs": truth["sigs"],
+        "invalid": sum(int((~b).sum()) for b in bits.values())}}
+    run = types.SimpleNamespace(
+        seed=seed, workload={"params": params},
+        delta=counters.Delta({}, {}), note=lambda **kw: None)
+    state = {"truth": truth, "store": store, "reports": [report],
+             "results": [result], "errors": [], "alive": False,
+             "warm_gap": 0, "compiles": 0}
+    compared, checked, _failed = check(run, state)
+    return checked, compared
+
+
+def control(workload: dict, config: dict, seed: int) -> tuple[int, list]:
+    """This shape's control at a cell's own size, on the cell's own
+    store (host work only; tests/controls.py)."""
+    p = workload["params"]
+    with tempfile.TemporaryDirectory() as tmp:
+        store = os.path.join(tmp, "gossip_store")
+        truth = gen_store.make_store(
+            store, graph=config["graph"], seed=seed, sign=True,
+            bad_records=p["bad_records"])
+        return control_on(store, truth, seed, p)
 
 
 def teardown(run, state) -> None:

@@ -34,13 +34,16 @@ the window.
 Parameters (`params`): `method`, `callers`, `think_mean_s` and
 `think_spread` (a caller's think times are evenly spaced over
 mean * (1 -+ spread), the same set for every caller and seed, in an
-order drawn from the seed), `start_spread_s`, `queries`,
-`amount_min_msat`, `amount_max_msat`, `ramp_seconds`, `ready_programs`
+order drawn from the seed), `start_spread_s`, `queries`, `pairs` (the
+law of who pays whom, gen/pairs_<law>.py; `uniform` where none is
+named) with `pairs_params`, `amount_min_msat`, `amount_max_msat`,
+`ramp_seconds`, `ready_programs`
 (how many programs the retrace detector must know before the warm-ups
 count as done; 0 waits for none), `sample`, `trace_seconds`.
 """
 from __future__ import annotations
 
+import hashlib
 import importlib
 import json
 import os
@@ -48,6 +51,7 @@ import random
 import shutil
 import subprocess
 import sys
+import tempfile
 import threading
 import time
 
@@ -68,6 +72,18 @@ BENIGN_FALLBACK = "below_occupancy"     # too few queries for a dispatch
 HOST_FALLBACK_LIMIT_PCT = 1.0
 BOOT_WAIT_S = 1100.0                    # a cold first run compiles
 CHILD_WAIT_S = 90.0
+# a cell of this shape at a size a CPU test run can hold
+# (tests/conftest.py): the host solvers serve it, so the rehearsal proves
+# the flow and the comparison, not the device path
+TINY = {
+    "graph": {"channels": 400, "nodes": 100},
+    "params": {"callers": 8, "think_mean_s": 0.05,
+               "start_spread_s": 0.2, "queries": 300,
+               "ramp_seconds": 0.5, "ready_programs": 0, "sample": 25,
+               "trace_seconds": 0.5},
+    "env": {}, "argv": ["--cpu", "--gossip-store", "gossip_store",
+                        "--rpc-file", "lightning-rpc"],
+}
 
 
 def _answers_module(method: str):
@@ -154,9 +170,13 @@ def setup(run) -> dict:
     # meanwhile, on the host: the reference's graph and the queries
     g = ref_graph.from_store("gossip_store")
     nodes = ref_graph.largest_component(g)
-    qs = gen_queries.pairs(nodes, p["queries"], run.seed,
-                           amount_min_msat=p["amount_min_msat"],
-                           amount_max_msat=p["amount_max_msat"])
+    qs = gen_queries.cell_pairs(g, nodes, p, run.seed)
+    run.note(inputs={
+        "endpoints": gen_store.endpoint_law(run.config["graph"]),
+        "pairs": gen_queries.pair_law(p),
+        "store_sha256": gen_store.sha256_16("gossip_store"),
+        "queries_sha256": hashlib.sha256(
+            json.dumps(qs).encode()).hexdigest()[:16]})
     answers = _answers_module(p["method"])
     think, start = gen_queries.pacing(
         p["callers"], run.seed, think_mean_s=p["think_mean_s"],
@@ -309,6 +329,38 @@ def check(run, state) -> tuple[list, int, int]:
     attempted = len(state["inside"]) + out["never_answered"]
     failed = state["not_answers"] + out["never_answered"] + wrong
     return compared, max(attempted, 1), failed
+
+
+def control_on(method: str, store: str, seed: int, params: dict,
+               sample: int) -> tuple[int, list]:
+    """(answers compared, [wrong_answers against its limit]) for the
+    control's replies to a seeded sample of the cell's queries: the
+    reference solver with compounding left out (every hop priced for
+    the amount the payee receives)."""
+    answers = _answers_module(method)
+    g = ref_graph.from_store(store)
+    qs = gen_queries.cell_pairs(g, ref_graph.largest_component(g), params,
+                                seed)
+    rng = random.Random(seed)
+    wrong = 0
+    picked = rng.sample(range(len(qs)), min(sample, len(qs)))
+    for qi in picked:
+        try:
+            answers.check(g, qs[qi], answers.control_reply(g, qs[qi]))
+        except ValueError:
+            wrong += 1
+    return len(picked), [("wrong_answers", wrong, 0)]
+
+
+def control(workload: dict, config: dict, seed: int) -> tuple[int, list]:
+    """This shape's control at a cell's own size, on the cell's own
+    store and queries (host work only; tests/controls.py)."""
+    p = workload["params"]
+    with tempfile.TemporaryDirectory() as tmp:
+        store = os.path.join(tmp, "gossip_store")
+        gen_store.make_store(store, graph=config["graph"], seed=seed,
+                             sign=False)
+        return control_on(p["method"], store, seed, p, p["sample"])
 
 
 def teardown(run, state) -> None:

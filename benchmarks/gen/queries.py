@@ -1,9 +1,14 @@
 """Query generators: who pays whom how much, from the seed.
 
-`pairs` draws (source, destination, amount_msat) with both ends in the
-graph's largest component (so nearly every query has a route; one that
-has none is answered "no route", and the reference must say the same),
-uniformly, and the amount log-uniform between two bounds.
+`cell_pairs` is a cell's query list by the pair law its file names:
+gen/pairs_<law>.py `pairs(g, nodes, n, seed, *, amount_min_msat,
+amount_max_msat, **params.pairs_params)`, `params.pairs` the law
+(default `uniform`).
+
+`pairs`, the uniform law, draws (source, destination, amount_msat) with
+both ends in the graph's largest component (so nearly every query has a
+route; one that has none is answered "no route", and the reference must
+say the same), uniformly, and the amount log-uniform between two bounds.
 
 `pacing` says when each caller of a closed loop asks: its think times
 after a reply and the delay before its first request.  Every caller of
@@ -14,8 +19,25 @@ So two seeds offer the same load, differently interleaved.
 """
 from __future__ import annotations
 
+import importlib
 import math
 import random
+
+
+def pair_law(params: dict) -> str:
+    """The law a cell's `params` name, `uniform` where none."""
+    return params.get("pairs", "uniform")
+
+
+def cell_pairs(g, nodes: list[int], params: dict, seed: int
+               ) -> list[tuple[int, int, int]]:
+    """`params.queries` queries over the reference graph `g`, whose
+    largest component is `nodes`."""
+    law = importlib.import_module(f".pairs_{pair_law(params)}", __package__)
+    return law.pairs(g, nodes, params["queries"], seed,
+                     amount_min_msat=params["amount_min_msat"],
+                     amount_max_msat=params["amount_max_msat"],
+                     **params.get("pairs_params", {}))
 
 
 def pairs(nodes: list[int], n: int, seed: int, *, amount_min_msat: int,

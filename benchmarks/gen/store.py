@@ -3,13 +3,16 @@
 A copy of what `lightning_tpu/gossip/synth.py` writes (the shapes of
 upstream's million-channels store: one `channel_announcement` with four
 signatures per channel, two `channel_update`s, one `node_announcement`
-per node; uniform random endpoints; `htlc_maximum`, fee base and ppm
-drawn as synth.py draws them), rebuilt for speed and independence:
+per node; `htlc_maximum`, fee base and ppm drawn as synth.py draws
+them), rebuilt for speed and independence:
 
 * messages are built as numpy byte matrices, not per-message objects;
 * keys are derived and messages signed by OpenSSL in worker processes
   (gen/signer.py), so nothing of the program under test makes the
   inputs and no sign kernel has to compile;
+* who is joined to whom is an endpoint law of its own,
+  gen/endpoints_<law>.py, named by the configuration's
+  `graph.endpoints` (default `uniform`, as synth.py draws them);
 * a seeded handful of records is made invalid on purpose (a bit of a
   signature, or of the signed region, flipped after signing) so that
   "a record with a bad signature is reported invalid" can be checked.
@@ -22,6 +25,7 @@ sample from the file alone.
 from __future__ import annotations
 
 import hashlib
+import importlib
 import json
 import os
 
@@ -89,10 +93,27 @@ def _records(msgs: np.ndarray, ts: np.ndarray) -> bytes:
     return rec.tobytes()
 
 
-def make_store(path: str, *, channels: int, nodes: int, seed: int,
-               sign: bool, bad_records: int = 0,
-               workers: int | None = None) -> dict:
-    """Write the store and return what the generator knows of it."""
+def endpoint_law(graph: dict) -> str:
+    """The law a configuration's `graph` names, `uniform` where none."""
+    return graph.get("endpoints", "uniform")
+
+
+def endpoints(rng: np.random.Generator, graph: dict
+              ) -> tuple[np.ndarray, np.ndarray]:
+    """The two ends of every channel, by the graph's endpoint law:
+    gen/endpoints_<law>.py `endpoints(rng, graph)`, which draws from
+    the generator's own stream and reads its parameters in `graph`."""
+    law = importlib.import_module(f".endpoints_{endpoint_law(graph)}",
+                                  __package__)
+    return law.endpoints(rng, graph)
+
+
+def make_store(path: str, *, graph: dict, seed: int, sign: bool,
+               bad_records: int = 0, workers: int | None = None) -> dict:
+    """Write the store and return what the generator knows of it.
+    `graph` is a configuration's `graph`, whole: `channels`, `nodes`
+    and, where it names an endpoint law, the law's own keys."""
+    channels, nodes = graph["channels"], graph["nodes"]
     rng = np.random.default_rng(seed)
     seckeys = [int.from_bytes(rng.bytes(32), "big") % (signer.N - 1) + 1
                for _ in range(nodes)]
@@ -107,8 +128,7 @@ def make_store(path: str, *, channels: int, nodes: int, seed: int,
     pubs = np.frombuffer(b"".join(parts), np.uint8).reshape(nodes, 33)
 
     # -- endpoints; BOLT 7: node_id_1 is the lexically lesser key ----------
-    a = rng.integers(0, nodes, channels)
-    b = (a + 1 + rng.integers(0, nodes - 1, channels)) % nodes
+    a, b = endpoints(rng, graph)
     pa, pb = pubs[a], pubs[b]
     diff = pa != pb
     first = diff.argmax(axis=1)
@@ -235,21 +255,32 @@ def _corrupt(rng, ca, cu, na, n_bad: int) -> dict:
     return {k: sorted(v) for k, v in bad.items()}, ca_sig
 
 
+def sha256_16(path: str) -> str:
+    """The first 16 hex digits of a file's sha256: what a run says of
+    the inputs it was fed."""
+    with open(path, "rb") as f:
+        return hashlib.file_digest(f, "sha256").hexdigest()[:16]
+
+
 def cached_store(cache_dir: str, config: str, graph: dict, seed: int, *,
                  signed: bool, bad_records: int) -> tuple[str, dict]:
     """(path, ground truth) of a configuration's store for a seed,
-    generated once per (config, seed, signed, bad_records) under
-    cache_dir.  Every run of a check brings a new seed, so only the
-    KEEP_STORES newest are kept."""
+    generated once per (config, graph, seed, signed, bad_records) under
+    cache_dir; the graph's digest is in the name, so a store made
+    before a size or a law's parameter was edited is never served.
+    Every run of a check brings a new seed, so only the KEEP_STORES
+    newest are kept."""
     os.makedirs(cache_dir, exist_ok=True)
-    stem = os.path.join(cache_dir, "store-%s-%d-%s-%d" % (
-        config, seed, "signed" if signed else "plain", bad_records))
+    digest = hashlib.sha256(
+        json.dumps(graph, sort_keys=True).encode()).hexdigest()[:8]
+    stem = os.path.join(cache_dir, "store-%s-%s-%d-%s-%d" % (
+        config, digest, seed, "signed" if signed else "plain",
+        bad_records))
     if os.path.isfile(stem + ".gs") and os.path.isfile(stem + ".json"):
         with open(stem + ".json", encoding="utf8") as f:
             return stem + ".gs", json.load(f)
-    truth = make_store(stem + ".gs.tmp", channels=graph["channels"],
-                       nodes=graph["nodes"], seed=seed, sign=signed,
-                       bad_records=bad_records)
+    truth = make_store(stem + ".gs.tmp", graph=graph, seed=seed,
+                       sign=signed, bad_records=bad_records)
     with open(stem + ".json", "w", encoding="utf8") as f:
         json.dump(truth, f)
     os.replace(stem + ".gs.tmp", stem + ".gs")

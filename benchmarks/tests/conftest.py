@@ -16,44 +16,28 @@ BENCH = os.path.dirname(HERE)
 ROOT = os.path.dirname(BENCH)
 sys.path.insert(0, BENCH)
 
-TINY = {
-    "crashboot": {
-        "graph": {"channels": 96, "nodes": 24},
-        "params": {"bad_records": 24, "sample_records": 9,
-                   "open_after_s": 0.2, "trace_after_s": 0.2,
-                   "trace_seconds": 0.5},
-        "env": {"LIGHTNING_TPU_VERIFY_BUCKET": "8"}, "argv": [],
-    },
-    "rpc_closed_loop": {
-        "graph": {"channels": 400, "nodes": 100},
-        "params": {"callers": 8, "think_mean_s": 0.05,
-                   "start_spread_s": 0.2, "queries": 300,
-                   "ramp_seconds": 0.5, "ready_programs": 0, "sample": 25,
-                   "trace_seconds": 0.5},
-        # host solvers: the CPU rehearsal proves the flow and the
-        # comparison, not the device path
-        "env": {}, "argv": ["--cpu", "--gossip-store", "gossip_store",
-                            "--rpc-file", "lightning-rpc"],
-    },
-}
+import controls     # noqa: E402
 
 
-@pytest.fixture
-def tree(tmp_path):
-    """A checkout in miniature: the benchmark's files copied, the
-    program linked, every configuration and cell cut to a tiny size."""
-    t = tmp_path / "t"
+def build_tree(t, add=None) -> str:
+    """A checkout in miniature at `t`: the benchmark's files copied, the
+    program linked, `add(t)` left to add files and entries of its own,
+    then every configuration and cell cut to its driver's tiny size."""
     t.mkdir()
     shutil.copytree(BENCH, t / "benchmarks", ignore=shutil.ignore_patterns(
         ".cache", "__pycache__", ".pytest_cache"))
     os.symlink(os.path.join(ROOT, "lightning_tpu"), t / "lightning_tpu")
     shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), t / "BENCHMARK.json")
+    if add is not None:
+        add(t)
     with open(t / "BENCHMARK.json", encoding="utf8") as f:
         bench = json.load(f)
     for cell in bench["workloads"]:
         wpath = t / "benchmarks" / "workloads" / (cell["name"] + ".json")
         w = json.loads(wpath.read_text())
-        tiny = TINY[w["driver"]]
+        # the tiny size travels with the traffic shape
+        tiny = controls.driver_module(w["driver"],
+                                      str(t / "benchmarks")).TINY
         w["params"].update(tiny["params"])
         w["env"], w["argv"] = tiny["env"], tiny["argv"]
         wpath.write_text(json.dumps(w))
@@ -64,6 +48,24 @@ def tree(tmp_path):
         c["graph"].update(tiny["graph"])
         cpath.write_text(json.dumps(c))
     return str(t)
+
+
+def cells_on(driver: str, root: str = ROOT) -> list[str]:
+    """The cells of a checkout that run on a traffic shape."""
+    with open(os.path.join(root, "BENCHMARK.json"), encoding="utf8") as f:
+        bench = json.load(f)
+    out = []
+    for w in bench["workloads"]:
+        with open(os.path.join(root, "benchmarks", "workloads",
+                               w["name"] + ".json"), encoding="utf8") as f:
+            if json.load(f)["driver"] == driver:
+                out.append(w["name"])
+    return out
+
+
+@pytest.fixture
+def tree(tmp_path):
+    return build_tree(tmp_path / "t")
 
 
 def rehearse(tree: str, cell: str, *, trace: int = 0, seconds: float = 3,
@@ -84,5 +86,5 @@ def rehearse(tree: str, cell: str, *, trace: int = 0, seconds: float = 3,
     assert proc.returncode == 0, proc.stderr[-3000:]
     last = proc.stdout.strip().splitlines()[-1]
     out = json.loads(last)
-    out["_stderr"] = proc.stderr
+    out["_stderr"], out["_stdout"] = proc.stderr, proc.stdout
     return out

@@ -17,11 +17,11 @@ SEEDS = (3, 2_147_483_659, 40_000_000_001)
 @pytest.mark.parametrize("seed", SEEDS)
 def test_crashboot_control_fails_through_check(tmp_path, seed):
     store = str(tmp_path / "gs")
-    truth = gen_store.make_store(store, channels=96, nodes=24, seed=seed,
-                                 sign=True, bad_records=24)
+    truth = gen_store.make_store(store, graph={"channels": 96, "nodes": 24},
+                                 seed=seed, sign=True, bad_records=24)
     # a flipped signature on each of a channel_announcement's positions
     assert sorted(truth["bad_ca_sig"].values()) == [0, 1, 2, 3]
-    looked_at, compared = controls.crashboot_control(
+    looked_at, compared = controls.driver_module("crashboot").control_on(
         store, truth, seed, {"sample_records": 9})
     got = {name: v for name, v, _ in compared}
     # the control misses the flips in the second, third and fourth
@@ -33,12 +33,12 @@ def test_crashboot_control_fails_through_check(tmp_path, seed):
 @pytest.mark.parametrize("seed", SEEDS)
 def test_rpc_control_fails_and_reference_passes(tmp_path, seed):
     store = str(tmp_path / "gs")
-    gen_store.make_store(store, channels=400, nodes=100, seed=seed,
-                         sign=False)
+    gen_store.make_store(store, graph={"channels": 400, "nodes": 100},
+                         seed=seed, sign=False)
     params = {"queries": 200, "amount_min_msat": 10**6,
               "amount_max_msat": 10**9}
-    looked_at, compared = controls.rpc_control("getroute", store, seed,
-                                               params, 40)
+    looked_at, compared = controls.driver_module(
+        "rpc_closed_loop").control_on("getroute", store, seed, params, 40)
     assert looked_at == 40 and compared[0][1] > 0
     # the reference's own answers pass the comparison they are put to
     answers = importlib.import_module("reference.answers_getroute")
@@ -74,8 +74,8 @@ def test_pacing_is_the_same_load_for_every_seed():
 def test_generator_is_deterministic(tmp_path):
     a, b = str(tmp_path / "a"), str(tmp_path / "b")
     for p in (a, b):
-        gen_store.make_store(p, channels=40, nodes=12, seed=2**31 + 11,
-                             sign=True, bad_records=3)
+        gen_store.make_store(p, graph={"channels": 40, "nodes": 12},
+                             seed=2**31 + 11, sign=True, bad_records=3)
     with open(a, "rb") as fa, open(b, "rb") as fb:
         assert fa.read() == fb.read()
     assert os.path.getsize(a) == 1 + 40 * 444 + 80 * 150 + 12 * 154

@@ -14,20 +14,10 @@ import sys
 
 import pytest
 
-from conftest import BENCH, ROOT, rehearse
+from conftest import ROOT, cells_on as _cells, rehearse
 
 with open(os.path.join(ROOT, "BENCHMARK.json"), encoding="utf8") as _f:
     _BENCH = json.load(_f)
-
-
-def _cells(driver: str) -> list[str]:
-    out = []
-    for w in _BENCH["workloads"]:
-        with open(os.path.join(BENCH, "workloads", w["name"] + ".json"),
-                  encoding="utf8") as f:
-            if json.load(f)["driver"] == driver:
-                out.append(w["name"])
-    return out
 
 
 @pytest.mark.parametrize("cell", _cells("crashboot"))
