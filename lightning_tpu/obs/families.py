@@ -48,6 +48,16 @@ ROUTE_QUEUE_WAIT = REGISTRY.histogram(
     "Time a route query waited in the queue, from its enqueue to the "
     "start of the flush that took it (one observation per query)",
     buckets=DURATION_BUCKETS)
+ROUTE_SEGMIN_STEPS = REGISTRY.gauge(
+    "clntpu_route_segmin_steps",
+    "Doubling steps of the route program's segmented minimum "
+    "(2^steps >= the graph's largest out-degree): a static size of the "
+    "program, as the last flush or warm-up used it")
+ROUTE_PATH_HOPS = REGISTRY.histogram(
+    "clntpu_route_path_hops",
+    "Hops of a route answered ok from the device path (one observation "
+    "per query; the host solver's answers are not counted)",
+    buckets=tuple(float(h) for h in range(1, 21)))
 # owner: daemon/jsonrpc.py's getroute command.  ANSWERED queries only
 # (ok or no-route) — TRY_AGAIN admission rejections are excluded, so
 # this is the same population tools/loadgen.py's post-hoc p99 and the

@@ -24,8 +24,10 @@ minimum: the edges stand on the device in source-major order
 rows and the minimum is ``steps`` doubling passes
 ``x[i] = min(x[i], x[i - 2^k])`` inside a run, read at each run's last
 row.  ``steps`` is the largest out-degree's power of two (5 on a
-uniform 25,000-channel graph, 11 on a hub of 2,000 channels), a static
-size of the program read off the planes.  The winner's amount, delay
+uniform 25,000-channel graph, 10 on the same counts with mainnet's
+degrees, whose largest hub holds 916 channels), a static size of the
+program read off the planes; the gauge ``clntpu_route_segmin_steps``
+carries it (doc/routing.md §steps).  The winner's amount, delay
 and edge ride the same selects; no scatter of any width is in the
 program.  After k sweeps a node's label is the cheapest ≤k-hop path to
 the destination — identical to dijkstra's settled labels whenever the
@@ -142,6 +144,8 @@ _M_QUERIES = _families.ROUTE_QUERIES
 _M_FALLBACK = _families.ROUTE_FALLBACK
 _M_QUEUE = _families.ROUTE_QUEUE
 _M_QUEUE_WAIT = _families.ROUTE_QUEUE_WAIT
+_M_SEGMIN_STEPS = _families.ROUTE_SEGMIN_STEPS
+_M_PATH_HOPS = _families.ROUTE_PATH_HOPS
 
 # fallback reasons (label values — observable in tests/doc/routing.md)
 R_BELOW_OCCUPANCY = "below_occupancy"
@@ -503,6 +507,7 @@ def solve_batch(planes: RoutePlanes, queries: list[RouteQuery],
     # (doc/perf.md)
     _attr.note_program("route", (planes.n_pad, planes.e_pad, batch,
                                  max_hops, order.steps))
+    _M_SEGMIN_STEPS.set(order.steps)
     kern = _jit_route(planes.n_pad, max_hops, order.steps)
     pack_ns = reconstruct_ns = 0
     for start in range(0, len(queries), batch):
@@ -579,6 +584,7 @@ def solve_batch(planes: RoutePlanes, queries: list[RouteQuery],
                             planes, via[i], int(src[i]), int(dst[i]),
                             q.amount_msat, q.final_cltv, q.riskfactor,
                             int(dist_src[i]), max_hops)
+                        _M_PATH_HOPS.observe(len(route))
                         out[start + i] = ("ok", route, src_info)
                     except Exception as e:
                         log.warning("route reconstruction diverged (%s); "
@@ -639,6 +645,7 @@ def warmup(batch: int = ROUTE_BATCH, n_pad: int = 64, e_pad: int = 256,
     clntpu_retrace_total{program="route"} (doc/perf.md)."""
     with _attr.warmup_scope(), enable_x64():
         _attr.note_program("route", (n_pad, e_pad, batch, max_hops, steps))
+        _M_SEGMIN_STEPS.set(steps)
         np.asarray(_jit_route(n_pad, max_hops, steps)(
             *program_operands(batch, n_pad, e_pad, jnp.zeros))[0])
 

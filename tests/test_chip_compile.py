@@ -26,7 +26,7 @@ HBM_BYTES = 16 * 1024 ** 3      # one v5e chip
 
 # under about a minute each in the sandbox; the rest trace for tens of
 # seconds and compile for minutes (EC scans, Pallas engines)
-QUICK = ("route", "mcf")
+QUICK = ("route", "route_hubs", "mcf")
 SLOW = tuple(n for n in cc.PROGRAMS if n not in QUICK)
 PALLAS = tuple(n for n in cc.PROGRAMS if n.startswith("pallas"))
 
@@ -83,4 +83,4 @@ def test_program_table_covers_every_selectable_engine():
             assert S.resolve_dual_mul(engine) is not None
     assert len(PALLAS) == 6
     assert {"fused_verify_mb4", "fused_verify_mb8", "sign_simple",
-            "route", "mcf"} <= set(cc.PROGRAMS)
+            "route", "route_hubs", "mcf"} <= set(cc.PROGRAMS)

@@ -7,8 +7,9 @@ and meter as documented (doc/routing.md).
 
 All graphs here pad to the SAME quantized planes shape (n_pad 64,
 e_pad 256) and every batch uses Q=8, so the suite compiles the route
-program exactly once (tests/conftest's read-only jax cache serves it
-after the out-of-band warmup).
+program once per step count its graphs have (3, 4, 5; the hub case of
+the label tests has a shape of its own); the power-law graphs at the
+end of the file stay in that family and add one key, `steps` 6.
 """
 from __future__ import annotations
 
@@ -813,3 +814,190 @@ def test_route_program_holds_no_scatter():
             e32, e32, e64, e64, e64, e64, e64,
             jax.ShapeDtypeStruct((Q, 256), "bool"), b32, b32, b64, b64, b64)
     assert "stablehlo.scatter" in old.as_text()   # the check can see one
+
+
+# ---------------------------------------------------------------------------
+# A power-law graph: one hub among nodes of degree 2-8 (the shape of the
+# benchmark's `mainnet-tenth-hubs`, at the file's shared planes shape)
+
+
+def _hub_net(tmp_path, hub_degree, *, bridge=False):
+    """A gossmap of 100 channels over 47 nodes: one hub of exactly
+    `hub_degree` channels, one to each of its neighbours, and the other
+    channels' ends drawn with weights that follow P(k) ~ k^-2.1 on 2..8
+    (the law's quantiles, as benchmarks/gen/endpoints_powerlaw.py takes
+    them), parallel channels allowed, every channel updated in both
+    directions.  `bridge`: the other nodes are two parts joined by no
+    channel, so the hub is the only way across.  Returns (gossmap, hub
+    index, (part A, part B))."""
+    g = _net(tmp_path, 100, 48, seed=31)
+    n = g.n_nodes
+    assert hub_degree < n <= 48
+    rng = np.random.default_rng(31)
+    k = np.arange(2, 9, dtype=np.float64)
+    cdf = np.cumsum(k ** -2.1)
+    cdf /= cdf[-1]
+    others = rng.permutation(n)
+    hub, others = int(others[0]), others[1:]
+    weight = 2.0 + np.searchsorted(cdf, (np.arange(n - 1) + 0.5) / (n - 1))
+    parts = (others[0::2], others[1::2])
+    a = np.full(g.n_channels, hub)
+    b = np.resize(others, g.n_channels)     # the hub's neighbours first
+    for c in range(hub_degree, g.n_channels):
+        pool = np.arange(c % 2, n - 1, 2) if bridge else np.arange(n - 1)
+        x, y = rng.choice(pool, 2, replace=False,
+                          p=weight[pool] / weight[pool].sum())
+        a[c], b[c] = others[x], others[y]
+    g.node1[:], g.node2[:] = np.minimum(a, b), np.maximum(a, b)
+    g.htlc_max_msat[:, :] = 0
+    g._build_adjacency()
+    return g, hub, parts
+
+
+def _q(g, a, b, amount, **kw):
+    return RD.RouteQuery(bytes(g.node_ids[a]), bytes(g.node_ids[b]),
+                         int(amount), **kw)
+
+
+def _hub_queries(g, hub, parts, placement, rng):
+    """Q queries with the hub where `placement` puts it."""
+    amounts = (10 ** rng.uniform(3, 8, Q)).astype(np.int64)
+    others = np.concatenate(parts)
+    ends = rng.choice(others, Q, replace=False)
+    if placement == "payee":        # every lane pays the hub
+        return [_q(g, v, hub, amt) for v, amt in zip(ends, amounts)]
+    if placement == "payer":
+        return [_q(g, hub, v, amt) for v, amt in zip(ends, amounts)]
+    # across: payer in one part, payee in the other
+    return [_q(g, rng.choice(parts[i % 2]), rng.choice(parts[1 - i % 2]),
+               amt) for i, amt in enumerate(amounts)]
+
+
+def _service_results(g, queries, *, warm=False):
+    """The queries through one RouteService flush, as solve_batch's
+    result tuples (for `_assert_parity`)."""
+    async def scenario():
+        svc = RD.RouteService(lambda: g, flush_ms=20.0, batch=Q,
+                              host_max=1)
+        if warm:
+            await svc.warmup()
+        svc.start()
+        try:
+            got = await asyncio.gather(
+                *(svc.getroute(q.source, q.destination, q.amount_msat,
+                               with_source=True) for q in queries),
+                return_exceptions=True)
+        finally:
+            await svc.close()
+        out = []
+        for res in got:
+            if isinstance(res, DJ.NoRoute):
+                out.append(("noroute", str(res)))
+            else:
+                assert not isinstance(res, BaseException), res
+                out.append(("ok", *res))
+        return out
+
+    return _run(scenario())
+
+
+def _metric(name: str, **labels) -> float:
+    """A counter's or gauge's value at exactly these labels."""
+    from lightning_tpu import obs
+
+    fam = obs.snapshot()["metrics"].get(name, {})
+    return sum(s["value"] for s in fam.get("samples", ())
+               if s["labels"] == labels)
+
+
+@pytest.mark.parametrize("placement,bridge", [
+    ("payee", False), ("payer", False), ("across", True)])
+def test_power_law_graph_parity_by_hub_placement(tmp_path, placement,
+                                                 bridge):
+    """One flush of Q lanes through RouteService on the hub graph: the
+    hub as every lane's payee (at different amounts), as every lane's
+    payer, and as the only bridge between payer and payee; each priced
+    as dijkstra prices it.  The steps gauge reads what the planes have,
+    the hop histogram what the routes returned hold."""
+    g, hub, parts = _hub_net(tmp_path, 24, bridge=bridge)
+    steps = RD.edge_order(RoutePlanes.build(g)).steps
+    assert steps == 5                   # the hub's 24 channels, not 8
+    rng = np.random.default_rng(17)
+    queries = _hub_queries(g, hub, parts, placement, rng)
+    n0 = _hist_count("clntpu_route_path_hops")
+    sum0 = _hist_sum("clntpu_route_path_hops")
+    dev0 = _metric("clntpu_route_queries_total", path="device",
+                   outcome="ok")
+    results = _service_results(g, queries)
+    _assert_parity(g, queries, results)
+    routes = [r[1] for r in results if r[0] == "ok"]
+    assert len(routes) >= Q - 1
+    # all of them from the device path, none re-solved on the host
+    assert _metric("clntpu_route_queries_total", path="device",
+                   outcome="ok") - dev0 == len(routes)
+    assert _metric("clntpu_route_segmin_steps") == steps
+    assert _hist_count("clntpu_route_path_hops") - n0 == len(routes)
+    assert _hist_sum("clntpu_route_path_hops") - sum0 == \
+        sum(len(r) for r in routes)
+    if placement == "across":
+        hub_id = bytes(g.node_ids[hub])
+        assert all(hub_id in [h.node_id for h in r[:-1]] for r in routes)
+
+
+@pytest.mark.parametrize("hub_degree,steps", [(16, 4), (17, 5), (32, 5),
+                                              (33, 6)])
+def test_steps_rule_at_its_edge(tmp_path, hub_degree, steps):
+    """`2^steps >= largest out-degree`, at equality and one past it: a
+    run of exactly 2^k rows takes k doubling passes, one row more takes
+    k + 1, and both price as dijkstra does, the hub on either end."""
+    g, hub, parts = _hub_net(tmp_path, hub_degree)
+    planes = RoutePlanes.build(g)
+    assert RD.edge_order(planes).steps == steps
+    rng = np.random.default_rng(hub_degree)
+    for placement in ("payee", "payer"):
+        queries = _hub_queries(g, hub, parts, placement, rng)
+        results = RD.solve_batch(planes, queries, batch=Q)
+        assert sum(r[0] == "ok" for r in results) >= Q - 1
+        _assert_parity(g, queries, results)
+    assert _metric("clntpu_route_segmin_steps") == steps
+
+
+def test_hub_crossing_a_power_of_two_retraces_once(tmp_path):
+    """The hazard, pinned as it is: the hub's 17th channel gets its
+    first channel_update in the hub's direction, the largest out-degree
+    crosses 2^4, `steps` goes 4 -> 5 and the next flush runs a program
+    the warm-up never saw: answered correctly, and
+    clntpu_retrace_total{program="route"} moves once."""
+    from lightning_tpu.obs import attribution
+
+    def retraces():
+        return _metric("clntpu_retrace_total", program="route")
+
+    g, hub, parts = _hub_net(tmp_path, 17)
+    # the hub's last channel, as yet without an update from the hub
+    c = int(np.nonzero((g.node1 == hub) | (g.node2 == hub))[0][-1])
+    d = 0 if g.node1[c] == hub else 1
+    ts = int(g.timestamps[d, c])
+    g.timestamps[d, c] = 0
+    g._build_adjacency()
+    assert RD.edge_order(RoutePlanes.build(g)).steps == 4
+    rng = np.random.default_rng(23)
+    queries = _hub_queries(g, hub, parts, "payer", rng)
+    attribution.reset_for_tests()       # this test's keys are first sights
+    r0 = retraces()
+    _assert_parity(g, queries, _service_results(g, queries, warm=True))
+    assert _metric("clntpu_route_segmin_steps") == 4
+    assert retraces() == r0             # the warmed program
+    assert g.apply_channel_update(
+        int(g.scids[c]), d, timestamp=ts + 1, disabled=False,
+        cltv_delta=6, htlc_min_msat=0, htlc_max_msat=0,
+        fee_base_msat=1, fee_ppm=1)
+    results = _service_results(g, queries)
+    _assert_parity(g, queries, results)
+    assert _metric("clntpu_route_segmin_steps") == 5
+    assert retraces() == r0 + 1
+    # the new channel is the cheapest way out of the hub for its peer
+    peer = int(g.node2[c] if d == 0 else g.node1[c])
+    direct = _service_results(g, [_q(g, hub, peer, 1_000)] * 2)
+    assert [h.scid for h in direct[0][1]] == [int(g.scids[c])]
+    assert retraces() == r0 + 1         # seen now: silent

@@ -96,18 +96,23 @@ def _derive(sh):
 # the smoke graph's doubling steps: uniform endpoints give a largest
 # out-degree of about 25, under 2^5
 SMOKE_ROUTE_STEPS = 5
+# the same counts with mainnet's degrees (the benchmark's
+# `mainnet-tenth-hubs`: the largest hub holds 916 channels, under 2^10)
+HUBS_ROUTE_STEPS = 10
 
 
-def _route(sh):
-    from lightning_tpu.routing import device as RD
-    from lightning_tpu.routing import planes as RP
+def _route(steps):
+    def build(sh):
+        from lightning_tpu.routing import device as RD
+        from lightning_tpu.routing import planes as RP
 
-    n_pad = RP._pow2_pad(SMOKE_NODES, RP._MIN_NODE_PAD)
-    e_pad = RP._pow2_pad(2 * SMOKE_CHANNELS, RP._MIN_EDGE_PAD)
-    return (RD._jit_route(n_pad, RD.DEFAULT_MAX_HOPS, SMOKE_ROUTE_STEPS),
-            RD.program_operands(
-                RD.ROUTE_BATCH, n_pad, e_pad,
-                lambda shape, dtype: _sds(shape, dtype, sh)))
+        n_pad = RP._pow2_pad(SMOKE_NODES, RP._MIN_NODE_PAD)
+        e_pad = RP._pow2_pad(2 * SMOKE_CHANNELS, RP._MIN_EDGE_PAD)
+        return (RD._jit_route(n_pad, RD.DEFAULT_MAX_HOPS, steps),
+                RD.program_operands(
+                    RD.ROUTE_BATCH, n_pad, e_pad,
+                    lambda shape, dtype: _sds(shape, dtype, sh)))
+    return build
 
 
 def _mcf(sh):
@@ -162,7 +167,8 @@ PROGRAMS = {
     "sign_simple": (_sign_simple, False),
     "sign_grind": (_sign_grind, False),
     "derive_pubkeys": (_derive, False),
-    "route": (_route, True),
+    "route": (_route(SMOKE_ROUTE_STEPS), True),
+    "route_hubs": (_route(HUBS_ROUTE_STEPS), True),
     "mcf": (_mcf, True),
     "pallas_prep": (_pallas_prep, False),
     "pallas": (_pallas_dual_mul("dual_mul_pallas"), False),
