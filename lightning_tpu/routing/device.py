@@ -10,15 +10,18 @@ becomes ONE XLA program over Q concurrent queries.
 
 Kernel shape: ``max_hops`` Jacobi relaxation sweeps in a ``lax.scan``,
 written batch-first (no ``vmap``).  The labels are one
-``[n_pad, 3, lanes]`` table of (cost, amount, delay), the lanes
-(queries) its minor axis.  Each sweep fetches, for every edge, the row
-of its RECEIVING node — all lanes' labels of the sweep before, one
-indexed row per edge, the only indexed operation over edges — prices
-the edge in dense ``[lanes, edges]`` arrays with the exact integer
-cost model of dijkstra.py (compounding msat fees + CLN risk cost; the
-two divisions by a constant as an exact multiply-high, ``_div_const``),
-and folds the candidates per FORWARDING node with a dense segmented
-minimum: the edges stand on the device in source-major order
+``[n_pad, 2, lanes]`` table of (cost, amount), the lanes (queries) its
+minor axis; a label carries no delay, because no output of the program
+reads one: the answer's delays are summed on the host, exactly, by
+``_reconstruct`` from the query's ``final_cltv`` and the planes.  Each
+sweep fetches, for every edge, the row of its RECEIVING node — all
+lanes' labels of the sweep before, one indexed row per edge, the only
+indexed operation over edges — prices the edge in dense
+``[lanes, edges]`` arrays with the exact integer cost model of
+dijkstra.py (compounding msat fees + CLN risk cost; the two divisions
+by a constant as an exact multiply-high, ``_div_const``), and folds
+the candidates per FORWARDING node with a dense segmented minimum: the
+edges stand on the device in source-major order
 (``edge_order``: a stable sort of the RoutePlanes edges by
 ``edge_src``, padding rows last), so a node's out-edges are one run of
 rows and the minimum is ``steps`` doubling passes
@@ -27,8 +30,8 @@ row.  ``steps`` is the largest out-degree's power of two (5 on a
 uniform 25,000-channel graph, 10 on the same counts with mainnet's
 degrees, whose largest hub holds 916 channels), a static size of the
 program read off the planes; the gauge ``clntpu_route_segmin_steps``
-carries it (doc/routing.md §steps).  The winner's amount, delay
-and edge ride the same selects; no scatter of any width is in the
+carries it (doc/routing.md §steps).  The winner's amount and edge
+ride the same selects; no scatter of any width is in the
 program.  After k sweeps a node's label is the cheapest ≤k-hop path to
 the destination — identical to dijkstra's settled labels whenever the
 hop cap doesn't bind (LN paths are ~5 hops against a cap of 20).
@@ -198,11 +201,10 @@ def _make_single(n_nodes: int, max_hops: int, steps: int):
     segmented minimum takes (`EdgeOrder.steps`)."""
 
     def single(edge_src, edge_dst, base, ppm, cd, hmin, hmax, orig,
-               seg_last, edge_ok, src, dst, amount, final_cltv,
-               riskfactor):
+               seg_last, edge_ok, src, dst, amount, riskfactor):
         # labels start at the destination (a select, not a scatter):
-        # one [n_nodes, 3, lanes] table of (cost, amount, delay), a row
-        # of it all lanes' labels of one node
+        # one [n_nodes, 2, lanes] table of (cost, amount), a row of it
+        # all lanes' labels of one node
         at_dst = jnp.arange(n_nodes, dtype=jnp.int32)[:, None] == dst
         dist0 = jnp.where(at_dst, jnp.int64(0), jnp.int64(INF_COST))
         if dist0.dtype != jnp.int64:
@@ -210,8 +212,7 @@ def _make_single(n_nodes: int, max_hops: int, steps: int):
                 "route kernel traced outside an x64 scope — msat math "
                 "would silently truncate to int32")
         table0 = jnp.stack(
-            [dist0, jnp.where(at_dst, amount, jnp.int64(0)),
-             jnp.where(at_dst, final_cltv, jnp.int64(0))], axis=1)
+            [dist0, jnp.where(at_dst, amount, jnp.int64(0))], axis=1)
         via0 = jnp.full((n_nodes, src.shape[0]), -1, jnp.int32)
         # the dense edge-level arrays are [lanes, edges], the edge axis
         # minor (a TPU pads a minor axis of 64 lanes to 128: measured,
@@ -233,7 +234,7 @@ def _make_single(n_nodes: int, max_hops: int, steps: int):
             table, via, ovf = carry
             # the sweep's one indexed operation over edges: all lanes'
             # labels of each edge's receiving node, one row per edge
-            d_v, a_v, y_v = table[edge_dst].transpose(1, 2, 0)
+            d_v, a_v = table[edge_dst].transpose(1, 2, 0)
             ok = edge_ok & (d_v < INF_COST)
             # the HTLC carried over u→v is a_v (what v receives) —
             # channel_update limits apply to it (dijkstra.py:107)
@@ -245,13 +246,13 @@ def _make_single(n_nodes: int, max_hops: int, steps: int):
             risk = 1 + _div_const(a_v * cdr, _RISK_DENOM)
             # what the forwarding node's labels become if this edge wins
             new = jnp.stack([jnp.where(ok, d_v + fee + risk, INF_COST),
-                             a_v + fee, y_v + cd])
+                             a_v + fee])
             idx = idx0
             # per-source minimum as a prefix minimum inside each run of
-            # equal edge_src; amount, delay and edge ride the same
-            # selects.  Tie-break: lowest RoutePlanes edge index among
-            # the winning cost — the order is a stable sort, so inside
-            # a run that is the earlier row, which `<=` keeps
+            # equal edge_src; amount and edge ride the same selects.
+            # Tie-break: lowest RoutePlanes edge index among the
+            # winning cost — the order is a stable sort, so inside a
+            # run that is the earlier row, which `<=` keeps
             for k in range(steps):
                 new_k = jnp.roll(new, 1 << k, axis=2)
                 take = same[k] & (new_k[0] <= new[0])
@@ -517,7 +518,6 @@ def solve_batch(planes: RoutePlanes, queries: list[RouteQuery],
             src = np.zeros(batch, np.int32)
             dst = np.zeros(batch, np.int32)
             amount = np.ones(batch, np.int64)
-            cltv = np.zeros(batch, np.int64)
             rf = np.ones(batch, np.int64)
             for i, q in enumerate(chunk):
                 try:
@@ -549,7 +549,6 @@ def solve_batch(planes: RoutePlanes, queries: list[RouteQuery],
                     out[start + i] = ("fallback", R_MAX_HOPS)
                     continue
                 amount[i] = q.amount_msat
-                cltv[i] = q.final_cltv
                 rf[i] = q.riskfactor
                 # rows in the device's edge order (edge_order)
                 ok_mat[i] = (
@@ -557,12 +556,11 @@ def solve_batch(planes: RoutePlanes, queries: list[RouteQuery],
                     if q.excluded_scids else edge_ok)
         pack_ns += sp.duration_ns
         h2d += (ok_mat.nbytes + src.nbytes + dst.nbytes
-                + amount.nbytes + cltv.nbytes + rf.nbytes)
+                + amount.nbytes + rf.nbytes)
         with trace.span("route/device"), enable_x64():
             dist_src, via, ovf = kern(
                 *plane_args, jnp.asarray(ok_mat), jnp.asarray(src),
-                jnp.asarray(dst), jnp.asarray(amount), jnp.asarray(cltv),
-                jnp.asarray(rf))
+                jnp.asarray(dst), jnp.asarray(amount), jnp.asarray(rf))
             dist_src = np.asarray(dist_src)
             via = np.asarray(via)
             ovf = np.asarray(ovf)
@@ -630,7 +628,7 @@ def program_operands(batch: int, n_pad: int, e_pad: int, make) -> tuple:
     b32, b64 = make((batch,), jnp.int32), make((batch,), jnp.int64)
     return (e32, e32, e64, e64, e64, e64, e64, e32,
             make((n_pad,), jnp.int32), make((batch, e_pad), jnp.bool_),
-            b32, b32, b64, b64, b64)
+            b32, b32, b64, b64)
 
 
 def warmup(batch: int = ROUTE_BATCH, n_pad: int = 64, e_pad: int = 256,

@@ -163,6 +163,8 @@ def _both_ways(a, b):
 
 
 def _lanes(rng, n_real, lanes=Q, amount=None, rf=None):
+    """Per-lane operands in the oracle's order: src, dst, amount,
+    final_cltv, riskfactor.  The program takes all but final_cltv."""
     src = rng.integers(0, n_real, lanes).astype(np.int32)
     dst = ((src + 1 + rng.integers(0, n_real - 1, lanes))
            % n_real).astype(np.int32)
@@ -289,18 +291,17 @@ _LABEL_CASES = {
 }
 
 
-@pytest.mark.parametrize("case", list(_LABEL_CASES))
-def test_labels_equal_the_scatter_form(tmp_path, case):
-    """`(dist[src], via, ovf)` of the dense program, label for label
-    equal to the program it replaced (tests/route_scatter_oracle.py),
-    `via` in RoutePlanes edge indices for every node of every lane."""
+def _oracle_and_program(planes, ok, lanes):
+    """Both programs on one batch: the oracle with all five per-lane
+    operands, the dense program without `final_cltv` (it has no such
+    operand).  Holds the three outputs equal and returns them."""
     import jax.numpy as jnp
     from jax import enable_x64
 
     import route_scatter_oracle as oracle
 
-    planes, ok, lanes = _LABEL_CASES[case](tmp_path)
     order = RD.edge_order(planes)
+    src, dst, amount, _, rf = lanes
     with enable_x64():
         want = oracle.jit_scatter_route(planes.n_pad, RD.DEFAULT_MAX_HOPS)(
             *(jnp.asarray(getattr(planes, name)) for name in _EDGE_PLANES),
@@ -308,16 +309,68 @@ def test_labels_equal_the_scatter_form(tmp_path, case):
         plane_args, _, _ = RD._device_plane_args(planes)
         got = RD._jit_route(planes.n_pad, RD.DEFAULT_MAX_HOPS, order.steps)(
             *plane_args, jnp.asarray(ok[:, order.perm]),
-            *map(jnp.asarray, lanes))
+            *map(jnp.asarray, (src, dst, amount, rf)))
     for name, w, g in zip(("dist_src", "via", "ovf"), want, got):
         w, g = np.asarray(w), np.asarray(g)
         assert (w.shape, w.dtype) == (g.shape, g.dtype), name
         assert np.array_equal(w, g), (name, np.argwhere(w != g)[:5])
-    dist_src, via, ovf = (np.asarray(x) for x in want)
+    return tuple(np.asarray(x) for x in want)
+
+
+@pytest.mark.parametrize("case", list(_LABEL_CASES))
+def test_labels_equal_the_scatter_form(tmp_path, case):
+    """`(dist[src], via, ovf)` of the dense program, label for label
+    equal to the program it replaced (tests/route_scatter_oracle.py),
+    `via` in RoutePlanes edge indices for every node of every lane."""
+    planes, ok, lanes = _LABEL_CASES[case](tmp_path)
+    dist_src, via, ovf = _oracle_and_program(planes, ok, lanes)
     # the case exercises what it names
     assert (dist_src < RD.INF_COST).sum() >= Q // 2 or case == "overflow"
     assert (via >= 0).any()
     assert ovf.any() == (case == "overflow")
+
+
+@pytest.mark.parametrize("seed", [3, 29])
+def test_lanes_that_differ_only_in_final_cltv(tmp_path, seed):
+    """A label carries no delay: lanes of one batch that differ only in
+    `final_cltv` (9, 18, 144) get one `dist_src` and one `via` from the
+    program, which has no such operand, as from the oracle, which is
+    handed the three values; `solve_batch` still returns each query its
+    own delays, the host Dijkstra's, summed by `_reconstruct`."""
+    g = _net(tmp_path, 100, 40, seed)
+    planes = RoutePlanes.build(g)
+    rng = np.random.default_rng(seed)
+    pairs = []
+    while len(pairs) < 3:
+        a, b = (int(x) for x in rng.choice(g.n_nodes, 2, replace=False))
+        q = _q(g, a, b, rng.integers(1_000, 10_000_000))
+        if _host(g, q)[0] == "ok":
+            pairs.append((a, b, q.amount_msat))
+    groups = [(0, 1, 2), (3, 4, 5), (6, 7)]
+    cltvs = [9, 18, 144, 9, 18, 144, 9, 144]
+    of_lane = [pairs[i] for i, grp in enumerate(groups) for _ in grp]
+    src, dst, amount = (np.array(col) for col in zip(*of_lane))
+    lanes = (src.astype(np.int32), dst.astype(np.int32),
+             amount.astype(np.int64), np.array(cltvs, np.int64),
+             np.full(Q, 10, np.int64))
+    dist_src, via, ovf = _oracle_and_program(
+        planes, np.tile(planes.edge_enabled, (Q, 1)), lanes)
+    assert not ovf.any() and (dist_src < RD.INF_COST).all()
+    for first, *rest in groups:
+        for i in rest:
+            assert dist_src[i] == dist_src[first]
+            assert np.array_equal(via[i], via[first])
+    queries = [_q(g, a, b, amt, final_cltv=cltv, riskfactor=10)
+               for (a, b, amt), cltv in zip(of_lane, cltvs)]
+    results = RD.solve_batch(planes, queries, batch=Q)
+    _assert_parity(g, queries, results)
+    for grp in groups:
+        # (amount, delay) at the source: the host's, and a group's
+        # delays apart by exactly what its lanes' final_cltv are
+        assert [results[i][2] for i in grp] == \
+            [_host(g, queries[i])[2] for i in grp]
+        over = {results[i][2][1] - cltvs[i] for i in grp}
+        assert len(over) == 1 and over.pop() > 0
 
 
 def test_excluded_scids_and_unreachable(tmp_path):
@@ -791,6 +844,21 @@ def test_route_program_carries_named_scopes():
     text = _lowered_toy_program().as_text(debug_info=True)
     assert "route_relax" in text and "route_extract" in text
     assert "module @jit_single" in text
+
+
+def test_route_program_carries_two_label_columns():
+    """The loop carries a `[n_pad, 2, lanes]` table of (cost, amount)
+    and the doubling passes a `[2, lanes, e_pad]` stack; no int64 array
+    of the program has an axis of three, so a delay column (which no
+    output reads: `_reconstruct` sums the delays on the host) cannot
+    come back unnoticed."""
+    import re
+
+    text = _lowered_toy_program().as_text()
+    loops = [ln for ln in text.splitlines() if "stablehlo.while" in ln]
+    assert any(f"tensor<64x2x{Q}xi64>" in ln for ln in loops)
+    assert f"tensor<2x{Q}x256xi64>" in text
+    assert not re.search(r"tensor<(\d+x)*3x(\d+x)*i64>", text)
 
 
 def test_route_program_holds_no_scatter():
