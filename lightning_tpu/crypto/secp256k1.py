@@ -634,48 +634,14 @@ def _jit_verify_resolved(impl_name: str, prep_name: str):
                                      prep_impl=prep))
 
 
-def _jit_verify_from_bytes(impl_name: str | None = None,
-                           prep_name: str | None = None):
-    """Like _jit_verify but taking RAW BYTES for sig/pubkey operands
-    (z stays limbs — it typically comes straight from the hash kernel):
-    the byte→limb unpack runs on-device (F.from_bytes_be_dev), cutting
-    both host CPU (the numpy unpack was a top store-replay cost) and
-    host→device traffic (97 B vs 240 B per signature)."""
-    return _jit_verify_from_bytes_resolved(
-        *_resolve_engine_names(impl_name, prep_name))
-
-
 @functools.lru_cache(maxsize=1)
 def _jit_gather_rows():
-    """Device-side row gather (z limbs by per-signature row index).
-
-    Deliberately its OWN tiny jit program, NOT fused into the EC verify
-    program: its z_rows operand shape varies with the number of hash
-    buckets (K·bucket rows), and fusing it would recompile the whole
-    multi-minute EC program for every distinct K — a compile storm on
-    the live ingest path (see gossip.verify.warmup's postmortem).  As a
-    standalone take() the per-K compile is sub-second, the EC program
-    stays shape-static, and the hash→verify handoff is device-resident
-    either way (the previous host readback + re-upload of z between the
-    phases was a full sync point and ~30% of the measured e2e
-    store-replay wall clock)."""
+    """Device-side row gather (z limbs by per-signature row index): the
+    mesh replay's handoff between its hash stage and the sharded EC
+    program (gossip.verify._mesh_device_fn), which keeps the z plane on
+    the device.  The single-device replay gathers inside its fused
+    program instead."""
     return jax.jit(lambda z_rows, idx: jnp.take(z_rows, idx, axis=0))
-
-
-@functools.lru_cache(maxsize=16)
-def _jit_verify_from_bytes_resolved(impl_name: str, prep_name: str):
-    impl = resolve_dual_mul(impl_name)
-    prep = resolve_prep(prep_name)
-
-    def kern(z, sig_bytes, pub_bytes):
-        r = F.from_bytes_be_dev(sig_bytes[:, :32])
-        s = F.from_bytes_be_dev(sig_bytes[:, 32:])
-        qx = F.from_bytes_be_dev(pub_bytes[:, 1:])
-        parity = (pub_bytes[:, 0] & 1).astype(jnp.uint32)
-        return ecdsa_verify_kernel(z, r, s, qx, parity,
-                                   dual_mul_impl=impl, prep_impl=prep)
-
-    return jax.jit(kern)
 
 
 def ecdsa_verify_batch(msg_hashes: np.ndarray, sigs64: np.ndarray,

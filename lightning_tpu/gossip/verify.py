@@ -129,10 +129,9 @@ def _note_shape(program: str, key: tuple) -> None:
 
 
 def gossip_hash_kernel(blocks, n_blocks):
-    """sha256d(signed region) → z limbs.  Still a standalone jit program
-    for the unfused fallback path (LIGHTNING_TPU_REPLAY_FUSED=0), the
-    mesh hash stage, and bench isolation; the default replay path runs
-    the fused bucket program below instead."""
+    """sha256d(signed region) → z limbs.  A standalone jit program for
+    the mesh hash stage and tools/profile_verify.py; the single-device
+    replay runs the fused bucket program below instead."""
     digest = H.sha256d_blocks(blocks, n_blocks)
     return H.digest_words_to_limbs(digest)
 
@@ -147,14 +146,9 @@ def fused_verify_kernel(blocks, n_blocks, roi, sig_bytes, pub_bytes,
     """ONE device program per bucket: sha256d(signed regions) → z-row
     gather by local row index → byte→limb unpack → batched ECDSA verify.
 
-    Replaces the previous 3-program chain (_jit_hash → _jit_gather_rows
-    → _jit_verify_from_bytes) on the default path.  Fusing became
-    possible once buckets were made self-contained (a bucket's
-    signatures reference only the bucket's own rows, so the gather's
-    operand shape is the static (bucket, NLIMBS) — the old chain kept
-    the gather separate precisely because its z plane scaled with the
-    GLOBAL hash-bucket count K and would have recompiled the
-    multi-minute EC program per K).  A cold XLA:CPU compile of this
+    Buckets are self-contained (a bucket's signatures reference only
+    the bucket's own rows), so the gather's operand shape is the static
+    (bucket, NLIMBS).  A cold XLA:CPU compile of this
     program takes ~4 min at full opt — warmup() covers both quantized
     block widths, and the persistent cache serves every later process.
     """
@@ -199,12 +193,9 @@ def warmup(bucket: int = DEFAULT_BUCKET) -> None:
     Call from startup — idempotent and cheap once the jit caches are
     warm.
 
-    The default path needs exactly TWO programs per bucket: the fused
+    The replay needs exactly TWO programs per bucket: the fused
     sha256d+gather+verify program at both quantized SHA block widths
     (the bucket planner guarantees those are the only live shapes).
-    The unfused 3-program chain is warmed only when the fallback is
-    selected (LIGHTNING_TPU_REPLAY_FUSED=0) — eagerly tracing programs
-    the process will never dispatch costs seconds per warmup call.
 
     Runs inside attribution.warmup_scope(): the shapes compiled here
     are EXPECTED first-sights, and the scope's exit arms the retrace
@@ -217,35 +208,14 @@ def warmup(bucket: int = DEFAULT_BUCKET) -> None:
 def _warmup_inner(bucket: int) -> None:
     nb = jnp.ones((bucket,), jnp.int32)
     idx = jnp.zeros((bucket,), jnp.int32)
-    fused_on = _os.environ.get("LIGHTNING_TPU_REPLAY_FUSED", "1") != "0"
-    if fused_on:
-        for mb in (4, MAX_BLOCKS):
-            _note_shape("fused", (bucket, mb))
-            # fresh operand arrays EVERY call: the production program
-            # donates blocks/sigs/pubs on accelerators, so a reused
-            # array would be a deleted buffer on the second iteration
-            np.asarray(_jit_fused()(
-                jnp.zeros((bucket, mb, 16), jnp.uint32), nb, idx,
-                jnp.zeros((bucket, 64), jnp.uint8),
-                jnp.zeros((bucket, 33), jnp.uint8)))
-    else:
-        # the fallback 3-program chain — selected precisely to AVOID
-        # the fused program's compile, so don't warm the fused one
-        blocks = jnp.zeros((bucket, MAX_BLOCKS, 16), jnp.uint32)
-        _note_shape("hash", (bucket, MAX_BLOCKS))
-        z = _jit_hash()(blocks, nb)
-        _note_shape("hash", (bucket, 4))
-        _jit_hash()(blocks[:, :4], nb)   # the quantized small-row shape
-        _note_shape("gather", (int(z.shape[0]), bucket))
-        z = S._jit_gather_rows()(z, idx)
-        # multi-bucket flushes (M > bucket) gather from a K·bucket z
-        # plane; warm K=2 so the first such live flush doesn't compile
-        z2 = jnp.concatenate([z, z])
-        _note_shape("gather", (int(z2.shape[0]), bucket))
-        S._jit_gather_rows()(z2, idx)
-        _note_shape("verify", (bucket,))
-        np.asarray(S._jit_verify_from_bytes()(
-            z, jnp.zeros((bucket, 64), jnp.uint8),
+    for mb in (4, MAX_BLOCKS):
+        _note_shape("fused", (bucket, mb))
+        # fresh operand arrays EVERY call: the production program
+        # donates blocks/sigs/pubs on accelerators, so a reused
+        # array would be a deleted buffer on the second iteration
+        np.asarray(_jit_fused()(
+            jnp.zeros((bucket, mb, 16), jnp.uint32), nb, idx,
+            jnp.zeros((bucket, 64), jnp.uint8),
             jnp.zeros((bucket, 33), jnp.uint8)))
     # if flushes would route through the mesh (>1 usable device and not
     # opted out), warm THAT path's programs too — hash at both widths,
@@ -253,11 +223,8 @@ def _warmup_inner(bucket: int) -> None:
     # prepared buckets through the real dispatcher (metrics suppressed:
     # warmup buckets are not replay dispatches); otherwise the first
     # multi-device flush pays the multi-minute cold compile this
-    # function exists to keep off the live path.  The unfused fallback
-    # never reaches the mesh (verify_items routes it first), so skip.
-    if (fused_on
-            and _os.environ.get("LIGHTNING_TPU_MESH_VERIFY", "auto")
-            != "off"):
+    # function exists to keep off the live path.
+    if _os.environ.get("LIGHTNING_TPU_MESH_VERIFY", "auto") != "off":
         mesh_fn = _mesh_device_fn(bucket, count_metrics=False)
         if mesh_fn is not None:
             for mb in (4, MAX_BLOCKS):
@@ -1073,64 +1040,6 @@ def _run_pipeline(items: VerifyItems, roi: np.ndarray, bucket: int,
     return out, len(chunks)
 
 
-def _verify_items_unfused(items: VerifyItems, roi: np.ndarray,
-                          bucket: int) -> tuple[np.ndarray, int]:
-    """The pre-pipeline 3-program chain (hash buckets → device-resident
-    z concat → per-signature gather + verify).  Kept as the
-    LIGHTNING_TPU_REPLAY_FUSED=0 fallback: it needs no fused-program
-    compile, which matters on a backend whose persistent cache has only
-    the old programs.  Same device-resident z handoff, same single
-    readback."""
-    N, M = len(items), items.rows.shape[0]
-    zs = []
-    staged_bytes = 0
-    for start in range(0, M, bucket):
-        end = min(start + bucket, M)
-        sl = slice(start, end)
-        mb = int(items.n_blocks[sl].max(initial=0))
-        mb = 4 if 0 < mb <= 4 else MAX_BLOCKS
-        blocks = _bytes_to_blocks(
-            S._pad_rows(items.rows[sl], bucket)[:, :mb * 64], mb)
-        _note_shape("hash", (bucket, mb))
-        staged_bytes += blocks.nbytes + bucket * 4
-        zs.append(_jit_hash()(
-            jnp.asarray(blocks),
-            jnp.asarray(S._pad_rows(items.n_blocks[sl],
-                                    bucket).astype(np.int32)),
-        ))
-    z_rows = zs[0] if len(zs) == 1 else jnp.concatenate(zs)
-
-    out = np.zeros(N, bool)
-    gather = S._jit_gather_rows()
-    kern = S._jit_verify_from_bytes()
-    _note_shape("gather", (int(z_rows.shape[0]), bucket))
-    _note_shape("verify", (bucket,))
-    pending = []
-    for start in range(0, N, bucket):
-        end = min(start + bucket, N)
-        sl = slice(start, end)
-        z = gather(z_rows,
-                   jnp.asarray(S._pad_rows(roi[sl].astype(np.int32),
-                                           bucket)))
-        ok = kern(
-            z,
-            jnp.asarray(S._pad_rows(items.sigs[sl], bucket)),
-            jnp.asarray(S._pad_rows(items.pubkeys[sl], bucket)),
-        )
-        staged_bytes += bucket * (4 + 64 + 33)
-        _M_R_BUCKETS.labels("unfused").inc()
-        pending.append((sl, end - start, ok))
-    for sl, n_real, ok in pending:
-        out[sl] = np.asarray(ok)[:n_real]
-
-    verify_lanes = ((N + bucket - 1) // bucket) * bucket
-    hash_lanes = ((M + bucket - 1) // bucket) * bucket
-    _M_LANES.labels("verify").inc(verify_lanes)
-    _M_LANES.labels("hash").inc(hash_lanes)
-    _M_DEVICE_BYTES.inc(staged_bytes)
-    return out, (N + bucket - 1) // bucket
-
-
 def verify_items(items: VerifyItems, bucket: int = DEFAULT_BUCKET, *,
                  depth: int | None = None, device_fn=None,
                  corr=None,
@@ -1160,9 +1069,6 @@ def verify_items(items: VerifyItems, bucket: int = DEFAULT_BUCKET, *,
     just their bucket host-side, and a hung producer thread trips the
     LIGHTNING_TPU_DEADLINE_VERIFY_S deadline into inline prep — so a
     replay COMPLETES, bit-identically, under any single-path failure.
-    (The LIGHTNING_TPU_REPLAY_FUSED=0 legacy chain is supervised
-    coarsely: breaker-open or a raising chain re-checks the whole
-    replay on the host oracle, without per-bucket bisection.)
 
     ``corr`` (a trace.Carrier or list of them, minted at the enqueue
     point — ingest submit, the store-replay span) rides every prep /
@@ -1175,9 +1081,7 @@ def verify_items(items: VerifyItems, bucket: int = DEFAULT_BUCKET, *,
     ``dispatch_map`` (caller-allocated int64 (N,), conventionally
     filled with -1) receives, per SIGNATURE index, the dispatch_id of
     the flight record whose bucket verified it — the per-item
-    provenance link doc/journeys.md stitches journeys with.  The
-    legacy unfused chain has one coarse record covering the whole
-    replay, so every lane maps to it."""
+    provenance link doc/journeys.md stitches journeys with."""
     N = len(items)
     if N == 0:
         return np.zeros(0, bool)
@@ -1189,55 +1093,9 @@ def verify_items(items: VerifyItems, bucket: int = DEFAULT_BUCKET, *,
     tag_ok = (items.pubkeys[:, 0] == 2) | (items.pubkeys[:, 0] == 3)
 
     with trace.profile_session():
-        if (device_fn is None
-                and _os.environ.get("LIGHTNING_TPU_REPLAY_FUSED",
-                                    "1") == "0"):
-            # the legacy chain has no per-bucket dispatcher to wrap, so
-            # its supervision is coarse: breaker-open short-circuits the
-            # whole replay to the host oracle, and a raising chain falls
-            # back the same way (no bisect — all rows re-check host-side)
-            # — one coarse flight record covers the whole replay
-            n_buckets = (N + bucket - 1) // bucket
-            brk = _breaker.get("verify")
-            with _flight.dispatch(
-                    "verify", corr_ids=_flight.corr_ids(corrs),
-                    shape=(bucket, MAX_BLOCKS), n_real=N,
-                    lanes=n_buckets * bucket,
-                    breaker_state=brk.state) as frec:
-                if dispatch_map is not None:
-                    dispatch_map[:] = frec["dispatch_id"]
-                with trace.span("verify/dispatch", corr=corrs,
-                                dispatch_id=frec["dispatch_id"]):
-                    if not brk.allow():
-                        frec["outcome"] = "host_breaker"
-                        _M_R_BUCKETS.labels("host_breaker").inc(n_buckets)
-                        out = _host_verify_selected(items, roi,
-                                                    np.arange(N))
-                    else:
-                        try:
-                            _fault.fire("dispatch", "verify")
-                            out, n_buckets = _verify_items_unfused(
-                                items, roi, bucket)
-                        except Exception as e:
-                            brk.record_failure()
-                            _quarantine.note("verify", type(e).__name__, N)
-                            # recovered on the host oracle — "error" is
-                            # reserved for unrecovered failures
-                            frec["outcome"] = "host"
-                            frec["error"] = type(e).__name__
-                            log.warning(
-                                "unfused verify chain failed (%s); "
-                                "re-checking all %d rows on the host",
-                                e, N)
-                            out = _host_verify_selected(items, roi,
-                                                        np.arange(N))
-                        else:
-                            brk.record_success()
-                            frec["outcome"] = "ok"
-        else:
-            out, n_buckets = _run_pipeline(items, roi, bucket, depth,
-                                           device_fn, corrs=corrs,
-                                           dispatch_map=dispatch_map)
+        out, n_buckets = _run_pipeline(items, roi, bucket, depth,
+                                       device_fn, corrs=corrs,
+                                       dispatch_map=dispatch_map)
 
     # oversized rows: the device hashed garbage for them; their host
     # sha256d was computed at extraction — verify those few serially.

@@ -2,11 +2,11 @@
 flight-ring attribution corpus — hand-built rings with KNOWN stage
 splits must yield the exact expected breakdown, bottleneck name, and
 speedup-if-removed projection — plus the post-warmup retrace detector
-and the BENCH_HISTORY.jsonl schema + regression gate.
+and tools/perf_report.py's CLI.
 
 Deliberately jax-free end to end (obs/attribution.py is an obs-package
-module; bench.py's top-level imports are stdlib): the whole file runs
-in milliseconds and sorts early in tier-1 without displacing dots.
+module): the whole file runs in milliseconds and sorts early in tier-1
+without displacing dots.
 """
 from __future__ import annotations
 
@@ -22,7 +22,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))) + "/tools")
 
-import bench  # noqa: E402
 import perf_report  # noqa: E402
 from lightning_tpu.obs import attribution, families, flight  # noqa: E402
 from lightning_tpu.utils import events  # noqa: E402
@@ -330,176 +329,6 @@ def test_sample_device_memory_never_imports_jax(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# BENCH_HISTORY.jsonl schema + seeding
-
-
-def _entry(rec, legacy=False, **over):
-    e = {"v": bench.HISTORY_VERSION, "appended_at": "2026-08-04T00:00:00",
-         "source": "test", "record": rec}
-    if legacy:
-        e["legacy"] = True
-    e.update(over)
-    return e
-
-
-def _hw_line(value=100_000.0, **over):
-    line = {"metric": bench.METRIC, "unit": bench.UNIT,
-            "value": value,
-            "vs_baseline": round(value / bench.BASELINE_CPU_OPS, 3),
-            "platform": "tpu", "engine": "pallas_fbj+pp",
-            "bucket": 16384, "measurement": "live",
-            "measured_at": "2026-08-01",
-            "kernel_only": {"throughput": 200_000.0,
-                            "ms_per_call": 81.55}}
-    line.update(over)
-    return line
-
-
-def test_history_line_schema():
-    assert bench.check_history_line(_entry(_hw_line())) == []
-    assert bench.check_history_line(_entry({"metric": bench.METRIC,
-                                            "unit": bench.UNIT,
-                                            "value": 3.2},
-                                           legacy=True)) == []
-    # wrapper violations
-    assert bench.check_history_line(_entry(_hw_line(), v=2))
-    assert bench.check_history_line(_entry(_hw_line(), appended_at=""))
-    assert bench.check_history_line(_entry("not a dict"))
-    # a non-legacy record is held to the full bench-line contract
-    bad = _hw_line()
-    del bad["measurement"]
-    assert any("measurement" in p
-               for p in bench.check_history_line(_entry(bad)))
-    # legacy is exempt from the contract but never from the core
-    assert bench.check_history_line(_entry({"metric": bench.METRIC},
-                                           legacy=True))
-
-
-def test_append_history_gates_on_schema(tmp_path):
-    path = str(tmp_path / "hist.jsonl")
-    assert bench.append_history(_hw_line(), path=path)
-    # schema-violating record must NOT be written
-    assert not bench.append_history({"metric": bench.METRIC},
-                                    path=path)
-    entries = bench.load_history(path)
-    assert len(entries) == 1
-    assert entries[0]["record"]["value"] == 100_000.0
-
-
-def test_load_history_raises_on_corrupt_line(tmp_path):
-    path = str(tmp_path / "hist.jsonl")
-    with open(path, "w") as f:
-        f.write(json.dumps(_entry(_hw_line())) + "\n")
-        f.write("{broken\n")
-    with pytest.raises(ValueError):
-        bench.load_history(path)
-
-
-def test_committed_history_is_schema_clean():
-    """The seeded BENCH_HISTORY.jsonl artifact must validate — the
-    regression gate runs against it from day one."""
-    path = os.path.join(REPO, "BENCH_HISTORY.jsonl")
-    entries = bench.load_history(path)
-    assert entries, "history must be seeded"
-    # the satellite contract: a REAL-hardware baseline is present
-    hw = [e for e in entries
-          if e["record"].get("platform") not in ("cpu", "cpu-fallback")
-          and isinstance(e["record"].get("value"), (int, float))]
-    assert hw, "history must carry a hardware baseline"
-    assert any(e["source"].startswith("seed:BENCH_r")
-               for e in entries), "BENCH_rNN artifacts must be seeded"
-
-
-# ---------------------------------------------------------------------------
-# the regression gate
-
-
-def test_compare_records_flags_throughput_and_latency():
-    base = _hw_line()
-    regressed = _hw_line(value=50_000.0,
-                         kernel_only={"throughput": 120_000.0,
-                                      "ms_per_call": 120.0})
-    regs = perf_report.compare_records(base, regressed, 0.10)
-    assert any("throughput" in r for r in regs)
-    assert any("ms/call" in r for r in regs)
-    assert perf_report.compare_records(base, _hw_line(value=95_000.0),
-                                       0.10) == []
-
-
-def test_compare_gate_exits_nonzero_on_seeded_regression(tmp_path):
-    """The acceptance criterion: a seeded synthetic regression in the
-    history makes `perf_report.py --compare` exit non-zero."""
-    path = str(tmp_path / "hist.jsonl")
-
-    def add(value, day):
-        line = _hw_line(value=value, measured_at=f"2026-08-{day:02d}")
-        line["vs_baseline"] = round(value / bench.BASELINE_CPU_OPS, 3)
-        assert bench.append_history(line, source="t", path=path)
-
-    add(100_000.0, 1)
-    add(40_000.0, 2)
-    assert perf_report.run_compare(path, 0.10) == 1
-    # the regressed record is in the history but must NOT become the
-    # baseline (no ratchet-down): a still-regressed follow-up keeps
-    # failing against the best of the recent window
-    add(41_000.0, 3)
-    assert perf_report.run_compare(path, 0.10) == 1
-    # a recovered run within tolerance of the best passes again
-    add(97_000.0, 4)
-    assert perf_report.run_compare(path, 0.10) == 0
-
-
-def test_compare_ignores_platformless_legacy_baselines(tmp_path):
-    """A pre-contract legacy seed without a platform key must never
-    serve as the hardware baseline."""
-    path = str(tmp_path / "hist.jsonl")
-    entry = {"v": bench.HISTORY_VERSION,
-             "appended_at": "2026-08-01T00:00:00", "source": "seed:x",
-             "legacy": True,
-             "record": {"metric": bench.METRIC, "unit": bench.UNIT,
-                        "value": 3.2}}
-    assert bench.check_history_line(entry) == []
-    with open(path, "w") as f:
-        f.write(json.dumps(entry) + "\n")
-    assert bench.append_history(_hw_line(), source="t", path=path)
-    # 100k hardware vs the 3.2 platform-less record: no hardware
-    # baseline exists → nothing to gate, not a 31000x "improvement"
-    # against a cpu-era number
-    assert perf_report.run_compare(path, 0.10) == 0
-
-
-def test_compare_skips_replayed_candidates(tmp_path):
-    path = str(tmp_path / "hist.jsonl")
-    assert bench.append_history(_hw_line(), source="t", path=path)
-    replay = _hw_line(measurement="replayed:bench_last_tpu.json")
-    replay["fallback_run"] = {"value": 39.6, "platform": "cpu-fallback"}
-    assert bench.append_history(replay, source="t", path=path)
-    # the replayed record carries no new measurement: candidate stays
-    # the live one, nothing to gate, rc 0
-    assert perf_report.run_compare(path, 0.10) == 0
-
-
-def test_compare_hardware_never_gates_against_cpu(tmp_path):
-    path = str(tmp_path / "hist.jsonl")
-    cpu = {"metric": bench.METRIC, "unit": bench.UNIT, "value": 39.6,
-           "vs_baseline": 0.001, "platform": "cpu-fallback",
-           "measurement": "live", "engine": "glv", "bucket": 64}
-    assert bench.append_history(cpu, source="t", path=path)
-    hw = _hw_line()
-    assert bench.append_history(hw, source="t", path=path)
-    # 100k vs a 39.6 cpu record is not a comparison; no hardware
-    # baseline exists yet → gate passes with a note
-    assert perf_report.run_compare(path, 0.10) == 0
-
-
-def test_compare_rejects_corrupt_history(tmp_path):
-    path = str(tmp_path / "hist.jsonl")
-    with open(path, "w") as f:
-        f.write('{"v": 99}\n')
-    assert perf_report.run_compare(path, 0.10) == 2
-
-
-# ---------------------------------------------------------------------------
 # the perf-smoke CLI (the run_suite.sh pass, end to end)
 
 
@@ -514,10 +343,44 @@ def test_perf_report_selfcheck_cli():
     assert "perf selfcheck ok" in r.stdout
 
 
-def test_bench_selfcheck_validates_history_files(tmp_path):
-    path = str(tmp_path / "hist.jsonl")
-    assert bench.append_history(_hw_line(), path=path)
-    assert bench.run_selfcheck([path]) == 0
-    with open(path, "a") as f:
-        f.write('{"v": 99}\n')
-    assert bench.run_selfcheck([path]) == 1
+def test_perf_report_has_no_compare_mode():
+    r = subprocess.run(
+        [sys.executable, os.path.join(REPO, "tools", "perf_report.py"),
+         "--compare"],
+        capture_output=True, text=True, timeout=120)
+    assert r.returncode == 2, r.stdout + r.stderr
+    assert "unrecognized arguments: --compare" in r.stderr
+
+
+def test_perf_report_renders_without_a_kernel_rate(tmp_path, monkeypatch,
+                                                   capsys):
+    """The roofline is what --kernel-rate gives and otherwise absent;
+    the CLI reads the capture it is handed and no file of the repo."""
+    snap = tmp_path / "snap.json"
+    snap.write_text(json.dumps({
+        "metrics": {},
+        "dispatch_log": [_rec(n=8) for _ in range(4)],
+        "dispatches": {"families": {"verify": {"total": 4}}},
+    }))
+    opened = []
+    real_open = open
+
+    def spy(path, *a, **kw):
+        opened.append(os.path.abspath(path))
+        return real_open(path, *a, **kw)
+
+    monkeypatch.setattr("builtins.open", spy)
+
+    def run(*extra):
+        monkeypatch.setattr(sys, "argv", ["perf_report", "--capture",
+                                          str(snap), *extra])
+        assert perf_report.main() == 0
+        return capsys.readouterr().out
+
+    plain = run()
+    assert "family verify: 4 dispatches" in plain
+    assert "roofline" not in plain
+    assert "roofline:" in run("--kernel-rate", "1000")
+    assert str(snap) in opened
+    assert not [p for p in opened
+                if p != str(snap) and p.startswith(REPO + os.sep)]

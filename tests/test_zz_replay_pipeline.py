@@ -1,6 +1,6 @@
 """Streaming replay-pipeline tests: bucket planner invariants, the
 host-prep/device-compute overlap contract (clntpu_replay_* metrics),
-the device-resident z handoff, and fused-vs-unfused parity.
+the device-resident z handoff, and parity with the host oracle.
 
 Named test_zz_* to sort LAST: the overlap test drives a 25k-row
 synthetic replay and the tier-1 runner has a hard wall-clock budget —
@@ -18,14 +18,18 @@ any backend").
 """
 from __future__ import annotations
 
+import functools
+import hashlib
 import time
 
 import numpy as np
-
-import functools
+import pytest
 
 from lightning_tpu import obs
+from lightning_tpu.crypto import ref_python as ref
+from lightning_tpu.crypto import secp256k1 as S
 from lightning_tpu.gossip import verify
+from lightning_tpu.obs import attribution
 
 
 @functools.lru_cache(maxsize=1)
@@ -266,26 +270,121 @@ def test_z_handoff_stays_on_device():
 
 
 # ---------------------------------------------------------------------------
-# fused path parity with the unfused 3-program chain
+# the fused pipeline's verdicts against the host oracle
 
 
-def test_fused_matches_unfused(monkeypatch):
-    n = 27  # shared batch shape across the zz device tests (see above)
+def _row_hash(row: np.ndarray, n_blocks: int) -> bytes:
+    """sha256d of the signed region a sha-padded row holds (its length
+    is the 64-bit bit count that closes block n_blocks-1)."""
+    bitlen = int.from_bytes(
+        row[n_blocks * 64 - 8: n_blocks * 64].tobytes(), "big")
+    msg = row[: bitlen // 8].tobytes()
+    return hashlib.sha256(hashlib.sha256(msg).digest()).digest()
+
+
+def _oracle(items: verify.VerifyItems) -> np.ndarray:
+    """S._host_verify over hashlib's hashes: exact-int ECDSA that
+    shares nothing with the device programs."""
+    z = np.stack([
+        np.frombuffer(_row_hash(items.rows[r], int(items.n_blocks[r])),
+                      np.uint8) for r in items.row_of_item])
+    return S._host_verify(z, items.sigs, items.pubkeys)
+
+
+_SHARED_ROW = 12    # items 12..15 all sign row 12, as a
+                    # channel_announcement's four signatures share one
+
+
+@functools.lru_cache(maxsize=1)
+def _shared_row_batch():
+    """The shared batch with four signatures over one row by four keys
+    (signed on the host: no device program beyond the file's own)."""
     rows, nb, sigs, pubs = _signed_batch27()
-    sigs = sigs.copy()
-    sigs[5, 10] ^= 0x40  # corrupt exactly one signature
-    items = verify.VerifyItems(rows, nb, sigs, pubs,
-                               np.arange(n, dtype=np.int64))
+    sigs, pubs = sigs.copy(), pubs.copy()
+    roi = np.arange(27, dtype=np.int64)
+    z = _row_hash(rows[_SHARED_ROW], int(nb[_SHARED_ROW]))
+    for k in range(4):
+        key = 0x5eed0000 + k
+        r, s_ = ref.ecdsa_sign(z, key)
+        i = _SHARED_ROW + k
+        roi[i] = _SHARED_ROW
+        sigs[i] = np.frombuffer(
+            r.to_bytes(32, "big") + s_.to_bytes(32, "big"), np.uint8)
+        pubs[i] = np.frombuffer(
+            ref.pubkey_serialize(ref.pubkey_create(key)), np.uint8)
+    return rows, nb, sigs, pubs, roi
 
-    ok_fused = verify.verify_items(items, bucket=8)
-    monkeypatch.setenv("LIGHTNING_TPU_REPLAY_FUSED", "0")
-    ok_unfused = verify.verify_items(items, bucket=8)
 
-    assert ok_fused.dtype == np.bool_ and ok_unfused.dtype == np.bool_
-    assert (ok_fused == ok_unfused).all()
-    expected = np.ones(n, bool)
-    expected[5] = False
-    assert (ok_fused == expected).all()
+def _case_items(case: str) -> tuple[verify.VerifyItems, list[int]]:
+    """(items, the signature indices that must fail) for a named case."""
+    if case.startswith("shared_row"):
+        rows, nb, sigs, pubs, roi = _shared_row_batch()
+    else:
+        rows, nb, sigs, pubs = _signed_batch27()
+        roi = np.arange(27, dtype=np.int64)
+    rows, sigs, pubs = rows.copy(), sigs.copy(), pubs.copy()
+    bad: list[int] = []
+    if case == "bad_sig":
+        sigs[5, 10] ^= 0x40
+        bad = [5]
+    elif case == "bad_msg_byte":
+        rows[7, 3] ^= 0x01
+        bad = [7]
+    elif case == "bad_pubkey_tag":
+        pubs[9, 0] = 0x04
+        bad = [9]
+    elif case.startswith("shared_row_flip"):
+        pos = int(case[-1])
+        sigs[_SHARED_ROW + pos, 40] ^= 0x01
+        bad = [_SHARED_ROW + pos]
+    return verify.VerifyItems(rows, nb, sigs, pubs,
+                              np.arange(27, dtype=np.int64),
+                              row_of_item=roi), bad
+
+
+@pytest.mark.parametrize("case,depth", [
+    ("clean", 2), ("bad_sig", 2), ("bad_msg_byte", 2),
+    ("bad_pubkey_tag", 2), ("shared_row_clean", 2),
+    ("shared_row_flip0", 2), ("shared_row_flip1", 2),
+    ("shared_row_flip2", 2), ("shared_row_flip3", 2), ("bad_sig", 0),
+])
+def test_fused_matches_host_oracle(case, depth):
+    items, bad = _case_items(case)
+    ok = verify.verify_items(items, bucket=8, depth=depth)
+    assert ok.dtype == np.bool_
+    expected = np.ones(27, bool)
+    expected[bad] = False
+    assert (ok == expected).all()
+    assert (ok == _oracle(items)).all()
+
+
+# ---------------------------------------------------------------------------
+# warm-up compiles the programs the replay dispatches, and no other
+
+
+@pytest.mark.parametrize("dead_name_set", [False, True])
+def test_warmup_notes_only_the_fused_shapes(monkeypatch, dead_name_set):
+    if dead_name_set:
+        monkeypatch.setenv("LIGHTNING_TPU_REPLAY_FUSED", "0")
+    bucket = 8
+    noted = []
+    monkeypatch.setattr(verify, "_note_shape",
+                        lambda program, key: noted.append((program, key)))
+    monkeypatch.setattr(
+        verify, "_jit_fused",
+        lambda: lambda blocks, nb, roi, sigs, pubs: np.zeros(bucket, bool))
+    monkeypatch.setattr(
+        S, "_jit_sign", lambda: lambda z, d, ks: (np.zeros(1, np.uint32),))
+    # one device: no mesh to warm
+    monkeypatch.setattr(verify, "_mesh_device_fn", lambda *a, **kw: None)
+    # warmup() arms the process's retrace detector: put it back after
+    monkeypatch.setattr(attribution, "_armed", attribution._armed)
+
+    verify.warmup(bucket)
+
+    assert noted == [("fused", (bucket, 4)),
+                     ("fused", (bucket, verify.MAX_BLOCKS)),
+                     ("sign", (S.SIGN_BUCKET,))]
 
 
 # ---------------------------------------------------------------------------

@@ -26,10 +26,8 @@ Subcommands:
       the dispatches NEW since the previous snapshot — the "which
       dispatch blew up that counter delta" view.
 
-The diff output is the "what did this flush/bench actually do" view:
+The diff output is the "what did this flush actually do" view:
 two snapshots bracket a workload and the delta is attributable to it.
-`bench.py --metrics` embeds the same diff in its emitted JSON line so
-offline bench rounds and live scrapes finally share one vocabulary.
 
 Captures carry the getmetrics `perf` section (the stage-attribution
 report, doc/perf.md) — capture --local computes it in-process, and

@@ -1,13 +1,15 @@
 #!/usr/bin/env python
-"""Perf observatory CLI (doc/perf.md): stage attribution + the
-bench-regression gate.
+"""Perf observatory CLI (doc/perf.md): stage attribution of a running
+daemon, a saved capture or this process, and the model's selfcheck.
+Regressions of speed are caught by the driver's check of every PR in
+every cell of BENCHMARK.json, not here.
 
 Subcommands / modes:
 
   --rpc <unix-socket> [--family F] [--kernel-rate R]
       Call `getperf` on a running daemon and render the report.  The
-      kernel roofline defaults to the best measured kernel rate in
-      bench_last_tpu.json (sweep_best, falling back to kernel_only).
+      kernel roofline is what --kernel-rate gives (items/s); without it
+      the report carries no roofline line.
 
   --capture snapshot.json
       Render the report OFFLINE from a saved obs_snapshot capture that
@@ -27,18 +29,8 @@ Subcommands / modes:
       hand-computed speedup-if-removed, and reconciles ring vs counter
       sums within the stated epsilon.  Jax-free and fast.
 
-  --compare [--history BENCH_HISTORY.jsonl] [--tolerance 0.10]
-      The regression gate: for every metric in the bench trajectory,
-      compare the newest measurement against the most recent prior
-      baseline of the same platform class (hardware compares against
-      the last REAL-hardware baseline, never against a cpu-fallback)
-      and exit non-zero when throughput dropped — or kernel
-      ms-per-call rose — beyond the noise tolerance.  Replayed
-      records (measurement "replayed:*") are skipped as candidates:
-      they carry no new measurement.
-
 All output is deterministic text (or --json); exit codes: 0 ok,
-1 selfcheck/regression failure, 2 usage/data error.
+1 selfcheck failure, 2 usage/data error.
 """
 from __future__ import annotations
 
@@ -49,29 +41,6 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-# the gate's stated noise tolerance: 10% keeps the gate quiet on
-# run-to-run noise and loud on real regressions
-DEFAULT_TOLERANCE = 0.10
-
-
-def load_kernel_rate() -> float | None:
-    """The best measured kernel-alone rate (sigs/s) from
-    bench_last_tpu.json — the roofline the e2e pipeline is compared
-    against (sweep_best is the tuned number; kernel_only the last
-    e2e-round measurement)."""
-    try:
-        with open(os.path.join(REPO, "bench_last_tpu.json")) as f:
-            last = json.load(f)
-    except Exception:
-        return None
-    for key in ("sweep_best", "kernel_only"):
-        thr = (last.get(key) or {}).get("throughput")
-        if thr:
-            return float(thr)
-    return None
 
 
 # ---------------------------------------------------------------------------
@@ -261,126 +230,6 @@ def run_selfcheck(inflate: str = "dispatch", as_json: bool = False) -> int:
 
 
 # ---------------------------------------------------------------------------
-# The regression gate
-
-
-# how many prior same-class candidates the gate scans for its
-# baseline: comparing only against the IMMEDIATELY previous record
-# would let a regression that slipped into the history become the next
-# baseline (the gate would fire exactly once, and sub-tolerance drift
-# could compound forever) — gating against the best of the recent
-# window keeps the bar where the last good measurement put it
-BASELINE_WINDOW = 5
-
-
-def _platform_class(rec: dict) -> str:
-    p = rec.get("platform")
-    if not p:
-        # pre-contract legacy seeds may lack the key entirely; they
-        # must never serve as (or gate against) a hardware baseline
-        return "unknown"
-    return "cpu" if p in ("cpu", "cpu-fallback") else "hardware"
-
-
-def _is_candidate(rec: dict) -> bool:
-    if "error" in rec or not isinstance(rec.get("value"), (int, float)):
-        return False
-    return not str(rec.get("measurement", "live")).startswith("replayed")
-
-
-def compare_records(base: dict, cand: dict, tolerance: float) -> list[str]:
-    """Regressions of `cand` against `base` beyond the tolerance
-    (empty = clean).  Throughput-shaped values regress downward;
-    latency-shaped values regress upward."""
-    regressions = []
-    bv, cv = base.get("value"), cand.get("value")
-    if bv and cv is not None and cv < bv * (1 - tolerance):
-        regressions.append(
-            f"throughput {cv:.1f} < baseline {bv:.1f} "
-            f"(-{(1 - cv / bv):.1%}, tolerance {tolerance:.0%})")
-    bk = base.get("kernel_only") or {}
-    ck = cand.get("kernel_only") or {}
-    bkt, ckt = bk.get("throughput"), ck.get("throughput")
-    if bkt and ckt and ckt < bkt * (1 - tolerance):
-        regressions.append(
-            f"kernel throughput {ckt:.1f} < baseline {bkt:.1f} "
-            f"(-{(1 - ckt / bkt):.1%})")
-    bkm, ckm = bk.get("ms_per_call"), ck.get("ms_per_call")
-    if bkm and ckm and ckm > bkm * (1 + tolerance):
-        regressions.append(
-            f"kernel ms/call {ckm:.2f} > baseline {bkm:.2f} "
-            f"(+{(ckm / bkm - 1):.1%})")
-    # stage-latency gate: rounds run with --metrics embed the
-    # clntpu_replay_* stage sums; compare per-item stage cost
-    for stage in ("prep", "prep_stall", "dispatch", "readback"):
-        name = f"clntpu_replay_{stage}_seconds_total"
-        bs = _stage_per_item(base, name)
-        cs = _stage_per_item(cand, name)
-        if bs and cs and cs > bs * (1 + tolerance):
-            regressions.append(
-                f"stage {stage} {cs * 1e6:.2f}us/item > baseline "
-                f"{bs * 1e6:.2f}us/item (+{(cs / bs - 1):.1%})")
-    return regressions
-
-
-def _stage_per_item(rec: dict, counter: str) -> float | None:
-    fam = (rec.get("metrics") or {}).get(counter)
-    n = rec.get("n_sigs")
-    if not fam or not n:
-        return None
-    total = sum(s.get("delta", s.get("value", 0.0))
-                for s in fam.get("samples", ()))
-    return total / n if total else None
-
-
-def run_compare(history_path: str, tolerance: float,
-                metric: str | None = None) -> int:
-    import bench
-
-    try:
-        entries = bench.load_history(history_path)
-    except (OSError, ValueError) as e:
-        print(f"compare: {e}", file=sys.stderr)
-        return 2
-    by_metric: dict[str, list[dict]] = {}
-    for e in entries:
-        rec = e["record"]
-        m = rec.get("metric")
-        if m and (metric is None or m == metric):
-            by_metric.setdefault(m, []).append(rec)
-    if metric is not None and metric not in by_metric:
-        print(f"compare: no history for metric {metric!r}",
-              file=sys.stderr)
-        return 2
-    any_regression = False
-    for m, recs in sorted(by_metric.items()):
-        cands = [r for r in recs if _is_candidate(r)]
-        if not cands:
-            print(f"{m}: no measurable candidate (errors/replays only)")
-            continue
-        cand = cands[-1]
-        cls = _platform_class(cand)
-        baselines = [r for r in cands[:-1] if _platform_class(r) == cls]
-        if not baselines:
-            print(f"{m}: no prior {cls} baseline — nothing to gate "
-                  f"(candidate {cand.get('value')})")
-            continue
-        base = max(baselines[-BASELINE_WINDOW:],
-                   key=lambda r: r.get("value") or 0.0)
-        regs = compare_records(base, cand, tolerance)
-        if regs:
-            any_regression = True
-            print(f"{m} [{cls}]: REGRESSION vs baseline "
-                  f"{base.get('measured_at', '?')}")
-            for r in regs:
-                print(f"  {r}")
-        else:
-            print(f"{m} [{cls}]: ok ({cand.get('value')} vs baseline "
-                  f"{base.get('value')}, tolerance {tolerance:.0%})")
-    return 1 if any_regression else 0
-
-
-# ---------------------------------------------------------------------------
 
 
 def main() -> int:
@@ -395,41 +244,25 @@ def main() -> int:
     p.add_argument("--inflate", default="dispatch",
                    help="selfcheck: which critical stage to inflate "
                         "(stall|dispatch|readback)")
-    p.add_argument("--compare", action="store_true",
-                   help="bench-regression gate over the history")
-    p.add_argument("--history", default=None,
-                   help="history path (default: repo "
-                        "BENCH_HISTORY.jsonl / $BENCH_HISTORY)")
-    p.add_argument("--tolerance", type=float, default=DEFAULT_TOLERANCE,
-                   help="relative noise tolerance for --compare "
-                        f"(default {DEFAULT_TOLERANCE})")
-    p.add_argument("--metric", default=None,
-                   help="--compare: gate only this metric")
     p.add_argument("--family", default=None,
                    help="--rpc: restrict to one dispatch family")
     p.add_argument("--kernel-rate", type=float, default=None,
-                   help="roofline items/s (default: bench_last_tpu.json)")
+                   help="roofline items/s (default: no roofline line)")
     p.add_argument("--json", action="store_true",
                    help="emit the raw report JSON instead of text")
     args = p.parse_args()
 
     if args.selfcheck:
         return run_selfcheck(args.inflate, as_json=args.json)
-    if args.compare:
-        import bench
 
-        return run_compare(args.history or bench.HISTORY_PATH,
-                           args.tolerance, args.metric)
-
-    kernel_rate = args.kernel_rate or load_kernel_rate()
     if args.rpc:
         from tools.obs_snapshot import rpc_call
 
         params: dict = {}
         if args.family:
             params["family"] = args.family
-        if kernel_rate:
-            params["kernel_rate"] = kernel_rate
+        if args.kernel_rate:
+            params["kernel_rate"] = args.kernel_rate
         report = rpc_call(args.rpc, "getperf", params)
     elif args.capture:
         from lightning_tpu.obs import attribution
@@ -437,14 +270,13 @@ def main() -> int:
         with open(args.capture) as f:
             snap = json.load(f)
         report = attribution.report_from_snapshot(
-            snap, kernel_rate=kernel_rate)
+            snap, kernel_rate=args.kernel_rate)
     elif args.local:
         from lightning_tpu.obs import attribution
 
-        report = attribution.report_local(kernel_rate=kernel_rate)
+        report = attribution.report_local(kernel_rate=args.kernel_rate)
     else:
-        p.error("need one of --rpc/--capture/--local/"
-                "--selfcheck/--compare")
+        p.error("need one of --rpc/--capture/--local/--selfcheck")
     print(json.dumps(report, indent=1) if args.json else render(report))
     return 0
 

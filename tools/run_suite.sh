@@ -94,13 +94,10 @@ rm -f "$SARIF_OUT"
 # and must name exactly that stage as the bottleneck, reproduce the
 # hand-computed speedup-if-removed projection, and reconcile the
 # flight-ring sums against the clntpu_replay_* counters within the
-# stated epsilon; then the bench-regression gate validates
-# BENCH_HISTORY.jsonl end to end.  Jax-free, seconds of budget.
+# stated epsilon.  Jax-free, seconds of budget.
 echo "perf-smoke pass (tools/perf_report.py --selfcheck)"
 timeout 300 python tools/perf_report.py --selfcheck \
   || { echo "perf selfcheck failed"; exit 1; }
-timeout 300 python tools/perf_report.py --compare \
-  || { echo "perf compare gate failed"; exit 1; }
 
 # Incident-smoke pass (doc/incidents.md): the black-box recorder is
 # driven with a jax-free fault-shaped mini workload — correlated flight
@@ -140,7 +137,7 @@ LIGHTNING_TPU_DEADLINE_INGEST_S=240 \
 # recover to healthy after disarm.  The black-box recorder rides the
 # same drive (doc/incidents.md): the fault phase must freeze exactly
 # one breaker-open bundle with the verify family and failing
-# dispatches inside, validated + rendered by incident_report.py, and
+# dispatches inside, validated + rendered by tools/incident_report.py, and
 # recovery must add none.  Pins the same jax config as the soak-lite
 # pass so the warmed verify programs are reused.
 echo "health-smoke pass (tools/health_smoke.py)"
