@@ -52,14 +52,56 @@ from .store import StoreIndex
 
 log = logging.getLogger("lightning_tpu.gossip.verify")
 
-# Default verify bucket: fixed batch shape so one compiled program serves
-# any store size (remainder padded with dummy always-False rows that are
-# masked out host-side).  Overridable for big-batch TPU runs via
-# LIGHTNING_TPU_VERIFY_BUCKET.
+# Verify buckets: a fixed batch shape, so one compiled program serves
+# any number of signatures (the remainder is padded with dummy
+# always-False rows that are masked out host-side).  The one knob is
+# LIGHTNING_TPU_VERIFY_BUCKET; where it is unset there are two
+# defaults, because two needs conflict.  A live flush (GossipIngest,
+# Gossipd, warmup(), and S.VERIFY_BUCKET for hsmd's checks) carries 256
+# signatures or fewer and waits on its own dispatch, so its bucket is a
+# question of latency and stays DEFAULT_BUCKET = 64.  A boot replay
+# (verify_store) is one stream of every signature in the store, so its
+# bucket is a question of throughput: replay_bucket() below.  Neither
+# is derived from the other and no rule adapts one to the other.
 import os as _os
 
 DEFAULT_BUCKET = int(_os.environ.get("LIGHTNING_TPU_VERIFY_BUCKET", str(S.VERIFY_BUCKET)))
 MAX_BLOCKS = 8  # 512-byte signed regions cover all standard gossip msgs
+
+# Lanes of one dispatch of a boot replay on a TPU.  A constant of the
+# platform and not of the store: a node with a small store pads one
+# dispatch, and builds or loads the very program a full store runs.
+# The sweep behind it (one v5e, `glv` + `xla`, PERF.md §5, PR 33): ms
+# an execution of the fused program alone, and signatures a second of
+# a crash boot over 156,000 signatures (benchmark cell
+# mainnet-tenth.crashboot):
+#
+#    lanes   ms alone   sigs/s in the cell
+#       64     13.0         5,023
+#      256     15.5       not run
+#      512     17.9        28,714
+#    1,024     31.6        32,073
+#    2,048     57.3       not run (32,900 by the curve)
+#    4,096     88.2       not run (41,000 by the curve)
+#
+# Up to 512 lanes an execution is bound by the issue of its ops and
+# lanes are nearly free; from there on it is linear in its lanes.
+# 1,024 is the best of the buckets the benchmark's 0.1 s traced
+# sub-window can read (it needs two whole dispatches); the larger ones
+# wait for that window to be sized in dispatches (ROADMAP S1).
+REPLAY_BUCKET_TPU = 1024
+
+
+def replay_bucket() -> int:
+    """Lanes per dispatch of a boot replay that was given no bucket:
+    LIGHTNING_TPU_VERIFY_BUCKET where it is set, else the platform's
+    constant (64, the live path's, on any backend that was not swept).
+    Asked when a replay starts, not at import: jax.default_backend()
+    starts the backend."""
+    env = _os.environ.get("LIGHTNING_TPU_VERIFY_BUCKET", "")
+    if env:
+        return int(env)
+    return REPLAY_BUCKET_TPU if jax.default_backend() == "tpu" else 64
 
 # -- observability (doc/observability.md) ----------------------------------
 _M_FLUSH_SECONDS = obs.histogram(
@@ -1128,11 +1170,14 @@ class StoreVerifyResult:
     na_valid: np.ndarray
 
 
-def verify_store(idx: StoreIndex, bucket: int = DEFAULT_BUCKET) -> StoreVerifyResult:
+def verify_store(idx: StoreIndex, bucket: int | None = None) -> StoreVerifyResult:
     """Replay-verify a full store: every signature on every alive gossip
     message (the reference's store *load* skips re-verification; its
     *ingest* path verifies serially — this is the ingest cost model run at
-    load scale, the BASELINE.md target workload)."""
+    load scale, the BASELINE.md target workload).  Without a `bucket`
+    the dispatches carry replay_bucket() lanes."""
+    if bucket is None:
+        bucket = replay_bucket()
     alive = idx.select(idx.alive())
     ca = alive.select(alive.types == wire.MSG_CHANNEL_ANNOUNCEMENT)
     na = alive.select(alive.types == wire.MSG_NODE_ANNOUNCEMENT)

@@ -505,3 +505,147 @@ def test_replay_sort_and_stream_spans():
     assert sort["start_ns"] + sort["duration_ns"] <= s0
     assert one["replay/readback"][0]["start_ns"] >= s1
     assert stream["attributes"] == {"buckets": 8}
+
+
+# ---------------------------------------------------------------------------
+# lanes per dispatch: a boot replay's bucket comes from the platform,
+# the live path's stays DEFAULT_BUCKET
+
+
+@pytest.mark.parametrize("env,backend,expected", [
+    ("8", "cpu", 8),            # the environment wins on any platform
+    ("8", "tpu", 8),
+    (None, "cpu", 64),
+    (None, "tpu", "REPLAY_BUCKET_TPU"),
+    (None, "gpu", 64),          # only the platform that was swept
+])
+def test_replay_bucket_rule(monkeypatch, env, backend, expected):
+    """replay_bucket() by environment and platform, and verify_store
+    hands exactly that down (an explicit bucket= still wins)."""
+    import jax
+
+    from lightning_tpu.gossip import store as gstore
+
+    if env is None:
+        monkeypatch.delenv("LIGHTNING_TPU_VERIFY_BUCKET", raising=False)
+    else:
+        monkeypatch.setenv("LIGHTNING_TPU_VERIFY_BUCKET", env)
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if expected == "REPLAY_BUCKET_TPU":
+        expected = verify.REPLAY_BUCKET_TPU
+        assert expected > 64 and expected & (expected - 1) == 0
+    assert verify.replay_bucket() == expected
+
+    seen = []
+
+    def items_spy(items, bucket, **kw):
+        seen.append(bucket)
+        return np.zeros(len(items), bool)
+
+    monkeypatch.setattr(verify, "verify_items", items_spy)
+    verify.verify_store(gstore._empty_index())
+    verify.verify_store(gstore._empty_index(), bucket=16)
+    assert seen == [expected, 16]
+
+
+@functools.lru_cache(maxsize=1)
+def _live_defaults_without_the_knob() -> dict:
+    """The live path's defaults as a process without
+    LIGHTNING_TPU_VERIFY_BUCKET sees them (this process was started
+    with 8, tests/conftest.py), and whether importing the modules
+    started a jax backend."""
+    import json
+    import os
+    import subprocess
+    import sys
+
+    code = (
+        "import inspect, json\n"
+        "from lightning_tpu.crypto import secp256k1 as S\n"
+        "from lightning_tpu.gossip import verify, ingest, gossipd\n"
+        "from jax._src import xla_bridge\n"
+        "d = lambda f, a='bucket': inspect.signature(f).parameters[a].default\n"
+        "print(json.dumps({\n"
+        " 'S.VERIFY_BUCKET': S.VERIFY_BUCKET,\n"
+        " 'verify.DEFAULT_BUCKET': verify.DEFAULT_BUCKET,\n"
+        " 'verify.warmup': d(verify.warmup),\n"
+        " 'verify.verify_items': d(verify.verify_items),\n"
+        " 'GossipIngest': d(ingest.GossipIngest.__init__),\n"
+        " 'Gossipd': d(gossipd.Gossipd.__init__),\n"
+        " 'verify_store': d(verify.verify_store),\n"
+        " 'backend_started': xla_bridge.backends_are_initialized()}))\n")
+    env = {k: v for k, v in os.environ.items()
+           if k != "LIGHTNING_TPU_VERIFY_BUCKET"}
+    env["JAX_PLATFORMS"] = "cpu"
+    out = subprocess.run(
+        [sys.executable, "-c", code], env=env, text=True, check=True,
+        capture_output=True, timeout=300,
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    return json.loads(out.stdout.strip().splitlines()[-1])
+
+
+@pytest.mark.parametrize("name,expected", [
+    ("S.VERIFY_BUCKET", 64), ("verify.DEFAULT_BUCKET", 64),
+    ("verify.warmup", 64), ("verify.verify_items", 64),
+    ("GossipIngest", 64),
+    ("Gossipd", None),              # None there means DEFAULT_BUCKET
+    ("verify_store", None),         # None there means replay_bucket()
+    ("backend_started", False),     # the rule is not asked at import
+])
+def test_live_path_defaults_stay_64(name, expected):
+    assert _live_defaults_without_the_knob()[name] == expected
+
+
+# the shared-row batch's signatures by layout: which of the 27 a replay
+# carries, in an order that puts the four signatures over row 12 where
+# the bucket of 8 cuts them (or, for `padded`, leaves one dispatch with
+# a pad lane).  The rows stay whole; only the signatures are selected.
+_LAYOUTS = {
+    "padded": (range(10, 17), None),     # 7 signatures: one dispatch
+    "cut_1_3": (range(5, 27), 1),        # 7 singles, then row 12
+    "cut_2_2": (range(6, 27), 2),
+    "cut_3_1": (range(7, 27), 3),
+}
+
+
+@pytest.mark.parametrize("flip", [None, 0, 1, 2, 3])
+@pytest.mark.parametrize("layout", list(_LAYOUTS))
+def test_replay_bucket_cuts_and_pads_match_host_oracle(layout, flip):
+    """A replay whose bucket is larger than its store (one padded
+    dispatch) and replays whose bucket cuts a channel_announcement's
+    four signatures 1/3, 2/2 and 3/1 across two dispatches: with a bad
+    signature planted on each of the four positions in turn, the
+    verdicts equal the host oracle's bit for bit, the dispatches are
+    the planner's and the lane counter moves by bucket x dispatches."""
+    bucket = 8
+    sel, first_part = _LAYOUTS[layout]
+    sel = np.asarray(sel)
+    rows, nb, sigs, pubs, roi = _shared_row_batch()
+    sigs = sigs.copy()
+    if flip is not None:
+        sigs[_SHARED_ROW + flip, 40] ^= 0x01
+    items = verify.VerifyItems(rows, nb, sigs[sel], pubs[sel],
+                               np.arange(len(sel), dtype=np.int64),
+                               row_of_item=roi[sel])
+    plan = verify._plan_buckets(np.sort(roi[sel]), bucket)
+    if first_part is None:
+        assert len(plan) == 1 and len(sel) < bucket
+    else:
+        # the first cut falls inside the run of four over the shared row
+        assert plan[0][1] == bucket and plan[1][0] == bucket
+        shared = np.nonzero(np.sort(roi[sel]) == _SHARED_ROW)[0]
+        assert (shared < bucket).sum() == first_part
+        assert (shared >= bucket).sum() == 4 - first_part
+
+    s0 = obs.snapshot()
+    ok = verify.verify_items(items, bucket=bucket, depth=2)
+    s1 = obs.snapshot()
+
+    expected = np.ones(len(sel), bool)
+    if flip is not None:
+        expected[list(sel).index(_SHARED_ROW + flip)] = False
+    assert (ok == expected).all()
+    assert (ok == _oracle(items)).all()
+    assert _counter(s1, "clntpu_replay_buckets_total") \
+        - _counter(s0, "clntpu_replay_buckets_total") == len(plan)
+    assert _lanes(s1, "verify") - _lanes(s0, "verify") == bucket * len(plan)

@@ -56,10 +56,11 @@ def _fused(mb):
         from lightning_tpu.gossip import verify
 
         # the daemon's default engines, donate=True: the program it
-        # builds off-CPU
+        # builds off-CPU, at the bucket a boot replay runs on a TPU
+        # (not verify.replay_bucket(): here jax reports the CPU)
         return (verify._jit_fused_resolved(
             *S._resolve_engine_names(None, None), True),
-            _verify_args(verify.DEFAULT_BUCKET, mb, sh))
+            _verify_args(verify.REPLAY_BUCKET_TPU, mb, sh))
     return build
 
 
