@@ -53,6 +53,11 @@ ROUTE_SEGMIN_STEPS = REGISTRY.gauge(
     "Doubling steps of the route program's segmented minimum "
     "(2^steps >= the graph's largest out-degree): a static size of the "
     "program, as the last flush or warm-up used it")
+ROUTE_SEGMIN_BLOCK_PASSES = REGISTRY.gauge(
+    "clntpu_route_segmin_block_passes",
+    "Doubling passes the route program's segmented minimum runs over "
+    "the winners of aligned 32-row blocks (max(steps - 5, 0)): whether "
+    "its second level engages, as the last flush or warm-up used it")
 ROUTE_PROGRAM_BYTES = REGISTRY.gauge(
     "clntpu_route_program_bytes",
     "Device bytes the compiler reports for the route program at the live "

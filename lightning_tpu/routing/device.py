@@ -30,7 +30,14 @@ row.  ``steps`` is the largest out-degree's power of two (5 on a
 uniform 25,000-channel graph, 10 on the same counts with mainnet's
 degrees, whose largest hub holds 916 channels), a static size of the
 program read off the planes; the gauge ``clntpu_route_segmin_steps``
-carries it (doc/routing.md §steps).  The winner's amount and edge
+carries it (doc/routing.md §steps).  The minimum is two-level where
+``steps`` > ``FOLD_STEPS`` (5): the first five passes run over every
+row, the rest over the last rows of aligned 32-row blocks alone, and a
+node reads its last row and, where its run reaches back past its
+block, the block before; ties go to the earlier window, so the
+tie-break below is unchanged (``_make_single``; the gauge
+``clntpu_route_segmin_block_passes`` carries ``steps`` − 5).  At
+``steps`` ≤ 5 the program is one level.  The winner's amount and edge
 ride the same selects; no scatter of any width is in the
 program.  After k sweeps a node's label is the cheapest ≤k-hop path to
 the destination — identical to dijkstra's settled labels whenever the
@@ -148,6 +155,7 @@ _M_FALLBACK = _families.ROUTE_FALLBACK
 _M_QUEUE = _families.ROUTE_QUEUE
 _M_QUEUE_WAIT = _families.ROUTE_QUEUE_WAIT
 _M_SEGMIN_STEPS = _families.ROUTE_SEGMIN_STEPS
+_M_SEGMIN_BLOCK_PASSES = _families.ROUTE_SEGMIN_BLOCK_PASSES
 _M_PATH_HOPS = _families.ROUTE_PATH_HOPS
 _M_PROGRAM_BYTES = _families.ROUTE_PROGRAM_BYTES
 
@@ -196,10 +204,52 @@ def _div_const(x, d: int):
     return (high >> (shift - 64)).astype(jnp.int64)
 
 
+# The segmented minimum's fold point: the first FOLD_STEPS doubling
+# passes run over every edge row, the rest over the last rows of
+# aligned blocks of 2^FOLD_STEPS = 32 rows (`_make_single` says why 32).
+FOLD_STEPS = 5
+_BLOCK = 1 << FOLD_STEPS
+
+
+def segmin_block_passes(steps: int) -> int:
+    """Doubling passes the route program runs over block winners at
+    `steps` (the gauge ``clntpu_route_segmin_block_passes``)."""
+    return max(steps - FOLD_STEPS, 0)
+
+
 def _make_single(n_nodes: int, max_hops: int, steps: int):
     """The whole batch's backward sweep; closed over the static node
     count, the sweep budget and the number of doubling steps the
-    segmented minimum takes (`EdgeOrder.steps`)."""
+    segmented minimum takes (`EdgeOrder.steps`).
+
+    The segmented minimum is two-level where `steps` > FOLD_STEPS.
+    Level 1 is the first FOLD_STEPS passes over all rows: row i then
+    holds the earliest minimum of its run over rows
+    [max(run start, i - 31), i], so block b's last row, 32b + 31, holds
+    that of its run's part of block b.  Level 2 is the other
+    `steps - FOLD_STEPS` passes over those block-end rows alone, the
+    run boundaries taken from `edge_src` at the block ends: block b
+    then holds the earliest minimum of its run over
+    [run start, 32b + 31], since the first block whose end lies in a
+    run starts at or after the run's first row.  A node whose last row
+    j lies in block bj reads the level-1 row j, which covers its run
+    back to max(run start, j - 31) ≥ 32(bj - 1), and, where its run
+    reaches into block bj - 1, level 2's block bj - 1, which covers the
+    run up to row 32bj - 1; the block wins on `<=`.  It holds the
+    earlier rows, and where the two windows overlap it holds the
+    overlap too, so the earliest row among equal costs wins, as in a
+    single level of `steps` passes: the tie-break is unchanged.
+
+    Why blocks of 32 rows.  Timed alone on one TPU v5e at 64 lanes, an
+    execution at (`n_pad`, `e_pad`, `steps`) = (65,536, 524,288, 14)
+    takes 913 ms with them, 860 ms with blocks of 16 and 976 ms with
+    blocks of 64 (1,824 ms in one level); at (8,192, 65,536, 10) 74.3,
+    70.9 and 78.2 ms (100.2 in one level).  Blocks of 16 are 5–6 %
+    faster on those two graphs, but with one constant they put a second
+    level at `steps` 5 as well, the uniform graph's, which was not
+    timed; 32 is the smallest fold at which that graph runs the
+    one-level program pass for pass, and a threshold apart from the
+    fold would be a second constant."""
 
     def single(edge_src, edge_dst, base, ppm, cd, hmin, hmax, orig,
                seg_last, edge_ok, src, dst, amount, riskfactor):
@@ -225,11 +275,25 @@ def _make_single(n_nodes: int, max_hops: int, steps: int):
         # edge i and edge i - 2^k leave the same node: static per
         # topology revision, so it stays outside the sweeps
         at = jnp.arange(edge_src.shape[0])
+        level1 = min(steps, FOLD_STEPS)
         same = [(at >= 1 << k) & (edge_src == jnp.roll(edge_src, 1 << k))
-                for k in range(steps)]
+                for k in range(level1)]
         has_out = (seg_last >= 0)[:, None]
         seg_at = jnp.maximum(seg_last, 0)
         idx0 = jnp.broadcast_to(orig, edge_ok.shape)
+        if steps > FOLD_STEPS:
+            # the same, over the block-end rows, and which nodes' runs
+            # reach back into the block before their last row's
+            blk_src = edge_src[_BLOCK - 1::_BLOCK]
+            blk_at = jnp.arange(blk_src.shape[0])
+            same2 = [(blk_at >= 1 << k)
+                     & (blk_src == jnp.roll(blk_src, 1 << k))
+                     for k in range(steps - FOLD_STEPS)]
+            blk_of = seg_at // _BLOCK
+            prev_blk = jnp.maximum(blk_of - 1, 0)
+            spans = ((blk_of >= 1)
+                     & (edge_src[jnp.maximum(blk_of * _BLOCK - 1, 0)]
+                        == edge_src[seg_at]))[:, None]
 
         def sweep(carry, _):
             table, via, ovf = carry
@@ -254,16 +318,35 @@ def _make_single(n_nodes: int, max_hops: int, steps: int):
             # Tie-break: lowest RoutePlanes edge index among the
             # winning cost — the order is a stable sort, so inside a
             # run that is the earlier row, which `<=` keeps
-            for k in range(steps):
+            for k in range(level1):
                 new_k = jnp.roll(new, 1 << k, axis=2)
                 take = same[k] & (new_k[0] <= new[0])
                 new = jnp.where(take, new_k, new)
                 idx = jnp.where(take, jnp.roll(idx, 1 << k, axis=1), idx)
             # each node's winner stands at the last of its edges
-            win = new.transpose(2, 0, 1)[seg_at]
+            rows = new.transpose(2, 0, 1)
+            win = rows[seg_at]
+            if steps > FOLD_STEPS:
+                with jax.named_scope("route_segmin_blocks"):
+                    # level 2 over the block ends, taken from the
+                    # row-major form: a stride over its major axis
+                    blk = rows[_BLOCK - 1::_BLOCK]
+                    blk_idx = idx.T[_BLOCK - 1::_BLOCK]
+                    for k in range(steps - FOLD_STEPS):
+                        blk_k = jnp.roll(blk, 1 << k, axis=0)
+                        take = same2[k][:, None] & (blk_k[:, 0] <= blk[:, 0])
+                        blk = jnp.where(take[:, None], blk_k, blk)
+                        blk_idx = jnp.where(
+                            take, jnp.roll(blk_idx, 1 << k, axis=0), blk_idx)
+                    before = blk[prev_blk]
+                    use_blk = spans & (before[:, 0] <= win[:, 0])
+                    win = jnp.where(use_blk[:, None], before, win)
             improved = has_out & (win[:, 0] < table[:, 0])
             table = jnp.where(improved[:, None], win, table)
-            via = jnp.where(improved, idx.T[seg_at], via)
+            won = idx.T[seg_at]
+            if steps > FOLD_STEPS:
+                won = jnp.where(use_blk, blk_idx[prev_blk], won)
+            via = jnp.where(improved, won, via)
             return (table, via, ovf), None
 
         # the phases carry jax.named_scope names (doc/tracing.md): they
@@ -510,7 +593,8 @@ def solve_batch(planes: RoutePlanes, queries: list[RouteQuery],
     ``io_acct`` (when given) accumulates the host<->device operand
     bytes this call staged under keys ``h2d_bytes``/``d2h_bytes``, and
     the pack and reconstruct stages' seconds under ``pack_s`` /
-    ``reconstruct_s``, and the program's step count under ``steps`` —
+    ``reconstruct_s``, and the program's step count under ``steps``
+    and its passes over block winners under ``block_passes`` —
     RouteService folds them into the flush's flight record; the
     clntpu_transfer_bytes_total{family="route"} counters are metered
     here either way (doc/perf.md).
@@ -539,6 +623,7 @@ def solve_batch(planes: RoutePlanes, queries: list[RouteQuery],
     _attr.note_program("route", (planes.n_pad, planes.e_pad, batch,
                                  max_hops, order.steps))
     _M_SEGMIN_STEPS.set(order.steps)
+    _M_SEGMIN_BLOCK_PASSES.set(segmin_block_passes(order.steps))
     kern = _route_executable(planes.n_pad, planes.e_pad, batch, max_hops,
                              order.steps)
     pack_ns = reconstruct_ns = 0
@@ -629,6 +714,7 @@ def solve_batch(planes: RoutePlanes, queries: list[RouteQuery],
         io_acct["reconstruct_s"] = (io_acct.get("reconstruct_s", 0.0)
                                     + reconstruct_ns / 1e9)
         io_acct["steps"] = order.steps
+        io_acct["block_passes"] = segmin_block_passes(order.steps)
     return out
 
 
@@ -677,6 +763,7 @@ def warmup(batch: int = ROUTE_BATCH, n_pad: int = 64, e_pad: int = 256,
     with _attr.warmup_scope(), enable_x64():
         _attr.note_program("route", (n_pad, e_pad, batch, max_hops, steps))
         _M_SEGMIN_STEPS.set(steps)
+        _M_SEGMIN_BLOCK_PASSES.set(segmin_block_passes(steps))
         exe = _route_executable(n_pad, e_pad, batch, max_hops, steps)
         np.asarray(exe(*program_operands(batch, n_pad, e_pad, jnp.zeros))[0])
 
@@ -999,6 +1086,7 @@ class RouteService:
                 rec["d2h_bytes"] = io_acct.get("d2h_bytes", 0)
                 # the program's third static size, beside the lanes
                 rec["steps"] = io_acct.get("steps")
+                rec["block_passes"] = io_acct.get("block_passes")
                 # the stage clocks of solve_batch's spans: what is left
                 # of the flush's wall time stays under dispatch_ms
                 rec["prep_ms"] = round(

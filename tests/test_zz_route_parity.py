@@ -281,6 +281,105 @@ def _case_overflow(tmp_path):
             _lanes(rng, n, amount=amount))
 
 
+def _block_planes(degrees, dst, rng, **params):
+    """Planes whose device order lays node u's out-edges out as one run
+    of `degrees[u]` rows from row sum(degrees[:u]) on, so a case puts
+    runs where it wants them against the segmented minimum's aligned
+    32-row blocks (`RD.FOLD_STEPS`)."""
+    n = len(degrees)
+    src = np.repeat(np.arange(n), degrees)
+    planes = _raw_planes(n, src, dst, rng=rng, **params)
+    assert np.array_equal(RD.edge_order(planes).seg_last[:n],
+                          np.cumsum(degrees) - 1)
+    return planes
+
+
+def _random_far_ends(rng, degrees, *, avoid=None):
+    """Each edge's receiving node, drawn from the nodes but its own and
+    `avoid`."""
+    n = len(degrees)
+    src = np.repeat(np.arange(n), degrees)
+    pool = np.setdiff1d(np.arange(n), [] if avoid is None else [avoid])
+    dst = rng.choice(pool, len(src))
+    clash = dst == src
+    while clash.any():
+        dst[clash] = rng.choice(pool, clash.sum())
+        clash = dst == src
+    return dst
+
+
+# rows 0-4, 5-35 (starts mid-block, crosses into the next), 36-67 (32),
+# 68-100 (33), 101-163 (63), 164-227 (64), 228-292 (65), 293-383 (ends
+# on a block end), 384-447 (two whole blocks); then 191 runs of 2: the
+# hub case's key, (n_pad, e_pad, steps) = (256, 1024, 7)
+_BLOCK_RUNS = [5, 31, 32, 33, 63, 64, 65, 91, 64] + [2] * 191
+
+
+def _case_block_runs(tmp_path):
+    """Runs of 31, 32, 33, 63, 64 and 65 rows against the aligned
+    blocks, at `steps` 7: passes 6 and 7 run over the block winners."""
+    rng = np.random.default_rng(11)
+    degrees = np.array(_BLOCK_RUNS)
+    planes = _block_planes(degrees, _random_far_ends(rng, degrees), rng)
+    seg_last = RD.edge_order(planes).seg_last
+    assert RD.edge_order(planes).steps == 7
+    assert (seg_last[1] // 32, (seg_last[1] - 30) // 32) == (1, 0)
+    assert seg_last[7] % 32 == 31 and seg_last[8] == 447
+    return planes, np.tile(planes.edge_enabled, (Q, 1)), _lanes(rng, 200)
+
+
+def _case_block_runs_6(tmp_path):
+    """The same at the power-law family's key (64, 256, 6): runs of 40
+    (rows 2-41), 33 (42-74) and 64 rows (75-138, neither end on a block
+    edge)."""
+    rng = np.random.default_rng(14)
+    degrees = np.array([2, 40, 33, 64] + [2] * 56)
+    planes = _block_planes(degrees, _random_far_ends(rng, degrees), rng)
+    assert RD.edge_order(planes).steps == 6
+    return planes, np.tile(planes.edge_enabled, (Q, 1)), _lanes(rng, 60)
+
+
+def _case_block_padding(tmp_path):
+    """The longest run last among the real edges (rows 447-546, from a
+    block's last row to the middle of a block) and 477 padding rows
+    behind it."""
+    rng = np.random.default_rng(13)
+    degrees = np.array([3] * 149 + [100])
+    planes = _block_planes(degrees, _random_far_ends(rng, degrees), rng)
+    assert RD.edge_order(planes).steps == 7
+    assert (planes.e_real, planes.e_pad) == (547, 1024)
+    return planes, np.tile(planes.edge_enabled, (Q, 1)), _lanes(rng, 150)
+
+
+# the block-ties case: hub 7's 91 edges (rows 293-383, one to each of
+# nodes 9-99 in that order) and the four of its neighbours with an edge
+# to the payee, at rows 303 (block 9), 313 (block 9), 353 and 368
+# (block 11, the run's last block)
+_TIES_HUB, _TIES_PAYEE, _TIES_FAN = 7, 199, (19, 29, 69, 84)
+
+
+def _case_block_ties(tmp_path):
+    """One long run whose equal-cost candidates lie in different
+    blocks: uniform fees, and the hub reaches the payee through any of
+    four neighbours at the same cost, so the earliest RoutePlanes index
+    among them must win across the second level's blocks."""
+    rng = np.random.default_rng(12)
+    degrees = np.array(_BLOCK_RUNS)
+    dst = _random_far_ends(rng, degrees, avoid=_TIES_PAYEE)
+    first = np.cumsum(degrees) - degrees
+    dst[first[_TIES_HUB]:first[_TIES_HUB] + 91] = np.arange(9, 100)
+    dst[first[list(_TIES_FAN)]] = _TIES_PAYEE
+    e = len(dst)
+    planes = _block_planes(degrees, dst, rng, base=np.full(e, 1000),
+                           ppm=np.full(e, 100), cltv=np.full(e, 6))
+    src = rng.choice(np.arange(9, 199), Q, replace=False).astype(np.int32)
+    src[:2] = _TIES_HUB
+    lanes = _lanes(rng, 200, amount=np.full(Q, 123_456))
+    dst_lane = np.where(np.arange(Q) < 6, _TIES_PAYEE, (src + 50) % 199)
+    return (planes, np.tile(planes.edge_enabled, (Q, 1)),
+            (src, dst_lane.astype(np.int32)) + lanes[2:])
+
+
 _LABEL_CASES = {
     "corpus-3": _case_corpus(3), "corpus-11": _case_corpus(11),
     "corpus-29": _case_corpus(29), "hub": _case_hub, "ties": _case_ties,
@@ -288,6 +387,8 @@ _LABEL_CASES = {
     "one-padding-row": _case_padding(255),
     "padding": _case_padding(150),
     "two-riskfactors": _case_two_riskfactors, "overflow": _case_overflow,
+    "block-runs": _case_block_runs, "block-runs-steps-6": _case_block_runs_6,
+    "block-padding": _case_block_padding, "block-ties": _case_block_ties,
 }
 
 
@@ -328,6 +429,25 @@ def test_labels_equal_the_scatter_form(tmp_path, case):
     assert (dist_src < RD.INF_COST).sum() >= Q // 2 or case == "overflow"
     assert (via >= 0).any()
     assert ovf.any() == (case == "overflow")
+
+
+def test_block_ties_the_earliest_edge_wins(tmp_path):
+    """The block-ties case, read out: for every lane paying the payee
+    the hub's `via` is its edge to node 19, the lowest RoutePlanes index
+    of four at one cost, which stands in the run's first block; the
+    other three lie in that block and in the run's last, whose row the
+    node reads first."""
+    planes, ok, lanes = _case_block_ties(tmp_path)
+    order = RD.edge_order(planes)
+    hub_edges = np.nonzero(planes.edge_src[:planes.e_real] == _TIES_HUB)[0]
+    fan = hub_edges[np.isin(planes.edge_dst[hub_edges], _TIES_FAN)]
+    assert [order.inv[e] // 32 for e in fan] == [9, 9, 11, 11]
+    assert order.seg_last[_TIES_HUB] // 32 == 11
+    _, via, _ = _oracle_and_program(planes, ok, lanes)
+    pays = lanes[1] == _TIES_PAYEE
+    assert pays.sum() == 6
+    assert (via[pays, _TIES_HUB] == fan.min()).all()
+    assert planes.edge_dst[fan.min()] == _TIES_FAN[0]
 
 
 @pytest.mark.parametrize("seed", [3, 29])
@@ -861,6 +981,21 @@ def test_route_program_carries_two_label_columns():
     assert not re.search(r"tensor<(\d+x)*3x(\d+x)*i64>", text)
 
 
+def test_steps_5_program_runs_no_second_level():
+    """Up to `FOLD_STEPS` the segmented minimum is one level: the
+    lowered `steps`-5 program holds no op of the second level (none
+    under its scope `route_segmin_blocks`, no `[e_pad / 32, 2, lanes]`
+    array of block winners), so a graph of short runs runs the passes
+    it ran before the second level existed; at `steps` 6 both are
+    there."""
+    assert RD.FOLD_STEPS == 5 and RD.segmin_block_passes(5) == 0
+    blocks = f"tensor<{256 // 32}x2x{Q}xi64>"
+    for steps, engaged in ((5, False), (6, True)):
+        text = _lowered_toy_program(steps).as_text(debug_info=True)
+        assert ("route_segmin_blocks" in text) == engaged
+        assert (blocks in text) == engaged
+
+
 def test_route_program_holds_no_scatter():
     """A sweep's only indexed operations are row fetches: the per-source
     minimum is a dense segmented reduction, so nothing in the program
@@ -1028,6 +1163,7 @@ def test_steps_rule_at_its_edge(tmp_path, hub_degree, steps):
         assert sum(r[0] == "ok" for r in results) >= Q - 1
         _assert_parity(g, queries, results)
     assert _metric("clntpu_route_segmin_steps") == steps
+    assert _metric("clntpu_route_segmin_block_passes") == max(steps - 5, 0)
 
 
 def test_hub_crossing_a_power_of_two_retraces_once(tmp_path):
@@ -1055,6 +1191,7 @@ def test_hub_crossing_a_power_of_two_retraces_once(tmp_path):
     r0 = retraces()
     _assert_parity(g, queries, _service_results(g, queries, warm=True))
     assert _metric("clntpu_route_segmin_steps") == 4
+    assert _metric("clntpu_route_segmin_block_passes") == 0
     assert retraces() == r0             # the warmed program
     assert g.apply_channel_update(
         int(g.scids[c]), d, timestamp=ts + 1, disabled=False,
@@ -1063,9 +1200,35 @@ def test_hub_crossing_a_power_of_two_retraces_once(tmp_path):
     results = _service_results(g, queries)
     _assert_parity(g, queries, results)
     assert _metric("clntpu_route_segmin_steps") == 5
+    assert _metric("clntpu_route_segmin_block_passes") == 0
     assert retraces() == r0 + 1
     # the new channel is the cheapest way out of the hub for its peer
     peer = int(g.node2[c] if d == 0 else g.node1[c])
     direct = _service_results(g, [_q(g, hub, peer, 1_000)] * 2)
     assert [h.scid for h in direct[0][1]] == [int(g.scids[c])]
     assert retraces() == r0 + 1         # seen now: silent
+
+
+@pytest.mark.parametrize("steps", [5, 6])
+def test_block_passes_gauge_after_warmup_and_flush(tmp_path, steps):
+    """`clntpu_route_segmin_block_passes` reads max(steps - 5, 0) after
+    a warm-up at the key and after a flush of a graph whose hub sets
+    `steps` (5 in the uniform cell, 9 and 5 in the hub cells), and the
+    flush's `route` flight record carries it beside `steps`."""
+    from lightning_tpu.obs import flight
+
+    RD.warmup(Q, 64, 256, steps=11 - steps)         # the other value
+    assert _metric("clntpu_route_segmin_block_passes") == \
+        RD.segmin_block_passes(11 - steps)
+    RD.warmup(Q, 64, 256, steps=steps)
+    assert _metric("clntpu_route_segmin_block_passes") == steps - 5
+    g, hub, parts = _hub_net(tmp_path, {5: 32, 6: 33}[steps])
+    RD.warmup(Q, 64, 256, steps=11 - steps)
+    flight.reset_for_tests()
+    queries = _hub_queries(g, hub, parts, "payee",
+                           np.random.default_rng(steps))
+    _assert_parity(g, queries, _service_results(g, queries))
+    assert _metric("clntpu_route_segmin_steps") == steps
+    assert _metric("clntpu_route_segmin_block_passes") == steps - 5
+    rec = flight.recent("route")[-1]
+    assert (rec["steps"], rec["block_passes"]) == (steps, steps - 5)

@@ -18,9 +18,12 @@ ones in tier-1 (it imports PROGRAMS from here); the minutes-long scan
 programs are run from this script by hand.
 
 The route program at the benchmark's configurations (temporaries, 64
-lanes): 454,580,736 and 456,306,176 bytes at 25,000 channels
-(`route`, `route_hubs`), 4,410,341,376 at the repo's mainnet preset
-of 250,000 (`route_mainnet_hubs`, `steps` 14).  Measured once and
+lanes): 454,580,736 and 453,983,744 bytes at 25,000 channels
+(`route`, `steps` 5, one-level segmented minimum; `route_hubs`,
+`steps` 10, five passes over 32-row block winners), 4,411,954,176 at
+the repo's mainnet preset of 250,000 (`route_mainnet_hubs`, `steps`
+14, nine such passes); the one-level program before the block passes
+took 456,306,176 and 4,410,341,376 at the two hub keys.  Measured once and
 kept out of the table (PR 34): at the source's million channels
 (240,000 nodes, `n_pad` 262,144, `e_pad` 2,097,152, `steps` 16) the
 compiler refuses the 64-lane program, "Used 16.78G of 15.75G hbm",
